@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops, ref
+from repro.launch.device import enable_compile_cache
 
 
 def _bench(fn, args, repeats: int) -> float:
@@ -102,6 +103,7 @@ def bench_decode_kernel(repeats: int = 20) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=None, help="write the JSON report here")
     ap.add_argument("--merge-into", default=None,

@@ -46,6 +46,7 @@ import sys
 
 import numpy as np
 
+from repro.launch.device import enable_compile_cache
 from repro.serve import Engine, EngineConfig, build_model_and_params
 from repro.serve.traffic import (
     MarkovModulatedArrivals,
@@ -131,6 +132,7 @@ def bench_loadtest(n_requests: int = 48, seed: int = 7) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small workload for the CI lane")

@@ -10,11 +10,14 @@ import argparse
 import sys
 import time
 
+from repro.launch.device import enable_compile_cache
+
 SUITES = ("fig3", "engine", "policy_overhead", "moe_dispatch",
           "kernel_bench", "serve_modes")
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of: " + ",".join(SUITES))

@@ -41,6 +41,7 @@ import jax
 import numpy as np
 
 from repro.data import synthetic_requests
+from repro.launch.device import enable_compile_cache
 from repro.serve import (
     Engine,
     EngineConfig,
@@ -418,12 +419,12 @@ def bench_sharded(
     segment_len: int = 8,
 ) -> dict:
     """Mesh-sharded vs single-device serving, same workload (DESIGN.md
-    §9). Runs inside the ``--sharded-child`` subprocess, which the parent
-    launches with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-    so virtual CPU devices exist; ``tp`` is the largest mesh that keeps
-    whole KV heads per shard. Virtual devices share the same silicon, so
-    the tok/s ratio measures partitioning OVERHEAD, not speedup — the
-    gated claims are bit-identity and the absolute throughputs."""
+    §9), in this process over ``jax.devices()``: ``tp`` is the largest
+    mesh that keeps whole KV heads per shard. On CPU, launch with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so virtual
+    devices exist. Virtual devices share the same silicon, so the tok/s
+    ratio measures partitioning OVERHEAD, not speedup — the gated claims
+    are bit-identity and the absolute throughputs."""
     from repro.serve import ParallelConfig
 
     max_seq = prompt_len + max_new + 15
@@ -459,24 +460,6 @@ def bench_sharded(
         "single_device_tok_s": round(tps_1, 2),
         "bit_identical": bool(identical),
     }
-
-
-def _sharded_via_subprocess(arch: str) -> dict:
-    """Run ``bench_sharded`` in a child that sees 8 virtual CPU devices.
-    XLA reads ``XLA_FLAGS`` once at jax import, long before argparse —
-    only a fresh interpreter can widen the platform."""
-    import subprocess
-
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__),
-         "--sharded-child", "--arch", arch],
-        env=env, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"--sharded-child failed ({proc.returncode}):\n{proc.stderr}")
-    return json.loads(proc.stdout)
 
 
 def run() -> list:
@@ -517,6 +500,7 @@ def run() -> list:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batched", action="store_true",
                     help="run the continuous-batching throughput comparison")
@@ -535,10 +519,9 @@ def main() -> None:
                          "(CI-gated trajectory like --chunked)")
     ap.add_argument("--sharded", action="store_true",
                     help="run the mesh-sharded vs single-device comparison "
-                         "(spawns a subprocess with 8 virtual CPU devices; "
-                         "tp = largest mesh dividing the arch's KV heads)")
-    ap.add_argument("--sharded-child", action="store_true",
-                    help=argparse.SUPPRESS)  # internal: runs bench_sharded
+                         "over this process's devices (tp = largest mesh "
+                         "dividing the arch's KV heads; on CPU set XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=8)")
     ap.add_argument("--json", default=None, help="write the JSON report here")
     ap.add_argument("--arch", default="stablelm-1.6b")
     ap.add_argument("--slots", type=int, default=8)
@@ -548,10 +531,6 @@ def main() -> None:
     ap.add_argument("--write-mode", default="direct",
                     choices=("direct", "staged", "adaptive"))
     args = ap.parse_args()
-
-    if args.sharded_child:
-        print(json.dumps(bench_sharded(arch=args.arch), indent=2))
-        return
 
     if (args.batched or args.chunked or args.prefix or args.sharded
             or args.spec):
@@ -574,7 +553,7 @@ def main() -> None:
         if args.spec:
             report["spec"] = bench_spec(arch=args.arch)
         if args.sharded:
-            report["sharded"] = _sharded_via_subprocess(args.arch)
+            report["sharded"] = bench_sharded(arch=args.arch)
     else:
         report = {name: {"value": val, "unit": unit}
                   for name, val, unit in run()}

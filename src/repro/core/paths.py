@@ -385,7 +385,9 @@ class ResolvedPlan:
 
     layout        "paged" | "lanes" (the ``auto`` resolution applied)
     attention     "fused" | "reference" (the ``auto`` resolution applied)
-    drain_kernel  tri-state forwarded to ``kvcache.paged.drain_ring``
+    drain_kernel  whether ``kvcache.paged.drain_ring`` runs the
+                  ``staged_scatter`` kernel (``None`` in the config is
+                  resolved here by ``kvcache.paged._auto_drain_kernel``)
     path/decision the negotiated WritePath + assembled DecisionModule
     sched         the resolved AdmissionPolicy instance (admission-order
                   plane; ``serve.traffic.sched`` registry)
@@ -403,7 +405,7 @@ class ResolvedPlan:
 
     layout: str
     attention: str
-    drain_kernel: Optional[bool]
+    drain_kernel: bool
     path: WritePath
     decision: DecisionModule
     sched: Any
@@ -588,9 +590,14 @@ def validate(cfg, *, paged_capable: bool = True,
         path_name, getattr(cfg, "policy", None), n_regions=n_regions,
         hot_threshold=getattr(cfg, "hot_threshold", 4), layout=layout,
         chunked=chunked)
+    drain_kernel = attn_cfg.drain_kernel
+    if drain_kernel is None:
+        from ..kvcache.paged import _auto_drain_kernel
+
+        drain_kernel = _auto_drain_kernel()
     return ResolvedPlan(
         layout=layout, attention=attention,
-        drain_kernel=attn_cfg.drain_kernel, path=path, decision=decision,
+        drain_kernel=drain_kernel, path=path, decision=decision,
         sched=sched, memory=memory, parallel=parallel, mesh=mesh,
         n_blocks=n_blocks, max_pages=max_pages, n_regions=n_regions,
         spec=spec, draft_cfg=draft_cfg)

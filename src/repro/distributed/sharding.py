@@ -31,15 +31,17 @@ from math import prod
 from typing import Any, Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AbstractMesh, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..compat import make_abstract_mesh  # noqa: F401  (re-export: the
-# version-agnostic AbstractMesh constructor lives next to the rules that
-# consume it — tests and launch code build abstract meshes through here)
 from ..configs.base import ModelConfig
 
 _KNOWN_AXES = ("pod", "data", "model")
+
+
+def make_abstract_mesh(axis_sizes, axis_names) -> AbstractMesh:
+    """``AbstractMesh((16, 16), ("data", "model"))`` from any sequences."""
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 @dataclasses.dataclass(frozen=True)

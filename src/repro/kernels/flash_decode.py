@@ -71,65 +71,82 @@ def _paged_kernel(
     # scalar prefetch
     tab_ref,               # int32 [B, P] physical block ids (clamped >= 0)
     # inputs
-    q_ref,                 # [1, C, 1, D]
-    k_ref,                 # [1, ps, 1, D] one physical KV block
-    v_ref,                 # [1, ps, 1, D]
-    m_ref,                 # int32 [1, C, ps] view-validity for this block
+    q_ref,                 # [1, Hkv, G*C, D] queries, grouped by KV head
+    k_ref,                 # [1, ps, Hkv, D] one physical KV block, all heads
+    v_ref,                 # [1, ps, Hkv, D]
+    m_ref,                 # int32 [1, 1, G*C, ps] view-validity for this block
     *rest,                 # (+ ring refs) then o_ref, then scratch
-    ps, n_pages, scale, ring,
+    n_pages, scale, ring,
 ):
-    """Grid (B, Hq, P): walk the page table for one (slot, head) pair.
+    """Grid (B, 2, P): two sweeps of one slot's page table.
 
-    Each step scores one physical block straight out of the pool (the
-    BlockSpec below indexes the pool through the scalar-prefetched table —
-    no gathered copy ever lands in HBM) and stashes scores/values in VMEM.
-    The LAST step appends the staging-ring lanes as a second KV source and
-    runs ONE full-width softmax + weighted sum, replicating the jnp
-    reference's op ORDER exactly: fused and reference outputs agree to
-    fp32 ulp precision (~1e-7 abs) and emit identical greedy tokens. They
-    are not bit-identical — XLA tiles the per-page [C, ps] score dots
-    differently from the reference's full-width einsum, which is enough to
-    reassociate the fp32 sums (see DESIGN.md §7 for the parity contract
-    and the online-rescaling trade-off).
+    Each step reads one physical block straight out of the pool (the
+    BlockSpecs index the pool through the scalar-prefetched table — no
+    gathered copy ever lands in HBM) and scores it for every head at once.
+    Sweep 0 folds the scores into the per-row softmax max and denominator;
+    sweep 1 recomputes them, normalizes, and accumulates the weighted
+    values. The staging-ring lanes join both sweeps at the last page as a
+    second KV source. Normalizing before the value product keeps the jnp
+    reference's op ORDER (fp32 logits -> mask -> softmax -> cast -> weighted
+    sum), so fused and reference outputs agree to fp32 ulp precision and
+    emit identical greedy tokens (DESIGN.md §7). Nothing in VMEM grows with
+    the context length.
     """
     if ring:
-        rk_ref, rv_ref, rm_ref, o_ref, s_ref, vb_ref = rest
+        rk_ref, rv_ref, rm_ref, o_ref, mx_ref, l_ref, acc_ref = rest
     else:
-        o_ref, s_ref, vb_ref = rest
+        o_ref, mx_ref, l_ref, acc_ref = rest
+    sweep = pl.program_id(1)
     j = pl.program_id(2)
+    q = q_ref[0]             # [Hkv, GC, D]
 
-    q = q_ref[0, :, 0]       # [C, D]
-    k = k_ref[0, :, 0]       # [ps, D]
-    v = v_ref[0, :, 0]
-    ok = m_ref[0] != 0       # [C, ps]
+    def scores(k, ok):       # k [T, Hkv, D], ok [GC|1, T] -> [Hkv, GC, T]
+        s = jnp.einsum("hcd,thd->hct", q, k,
+                       preferred_element_type=jnp.float32)
+        s = s.astype(q.dtype).astype(jnp.float32) * scale
+        return jnp.where(ok[None], s, _NEG_INF)
 
-    s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [C, ps]
-    s_ref[:, pl.ds(j * ps, ps)] = jnp.where(ok, s, _NEG_INF)
-    vb_ref[pl.ds(j * ps, ps), :] = v.astype(vb_ref.dtype)
+    def fold_stats(s):       # online max + softmax denominator
+        m_prev = mx_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        l_ref[...] = (l_ref[...] * jnp.exp(m_prev - m_new)
+                      + jnp.sum(jnp.exp(s - m_new), axis=-1, keepdims=True))
+        mx_ref[...] = m_new
 
-    @pl.when(j == n_pages - 1)
-    def _finalize():
-        scores = s_ref[...]      # [C, P*ps] fp32
-        vals = vb_ref[...]       # [P*ps, D]
+    def fold_values(s, v):   # normalized probs, then the weighted sum
+        p = (jnp.exp(s - mx_ref[...]) / l_ref[...]).astype(v.dtype)
+        acc_ref[...] += jnp.einsum("hct,thd->hcd", p, v,
+                                   preferred_element_type=jnp.float32)
+
+    def ring_scores():
+        return scores(rk_ref[0], rm_ref[0] != 0)
+
+    last = j == n_pages - 1
+    s = scores(k_ref[0], m_ref[0, 0] != 0)
+
+    @pl.when((sweep == 0) & (j == 0))
+    def _init():
+        mx_ref[...] = jnp.full_like(mx_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(sweep == 0)
+    def _stats():
+        fold_stats(s)
         if ring:
-            rk = rk_ref[0, :, 0]        # [R, D]
-            rv = rv_ref[0, :, 0]
-            rok = rm_ref[0] != 0        # [R]
-            sr = lax.dot_general(
-                q, rk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                    # [C, R]
-            scores = jnp.concatenate(
-                [scores, jnp.where(rok[None, :], sr, _NEG_INF)], axis=1)
-            vals = jnp.concatenate([vals, rv.astype(vals.dtype)], axis=0)
-        probs = jax.nn.softmax(scores, axis=-1)          # fp32
-        out = lax.dot_general(
-            probs.astype(vals.dtype), vals, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        o_ref[0, :, 0] = out.astype(o_ref.dtype)
+            @pl.when(last)
+            def _ring_stats():
+                fold_stats(ring_scores())
+
+    @pl.when(sweep == 1)
+    def _values():
+        fold_values(s, v_ref[0])
+
+        @pl.when(last)
+        def _finalize():
+            if ring:
+                fold_values(ring_scores(), rv_ref[0])
+            o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
 def flash_decode_paged(
@@ -147,61 +164,79 @@ def flash_decode_paged(
     """Fused paged-attention decode: page-table walk + ring overlay + SDPA.
 
     The scalar-prefetched ``blocks`` table drives the pool BlockSpecs, so
-    each grid step reads its [ps, D] KV tile directly from the physical
-    pool; undrained staging-ring lanes join the same softmax as a second
-    source. Nothing is gathered or overlaid in HBM first — the read-side
-    twin of ``staged_scatter``. Returns [B, C, Hq, D].
+    each grid step reads its [ps, Hkv, D] KV block directly from the
+    physical pool; undrained staging-ring lanes join the same softmax as a
+    second source. Nothing is gathered or overlaid in HBM first — the
+    read-side twin of ``staged_scatter``. Returns [B, C, Hq, D].
+
+    TPU tiling: every block's last two dims are whole array dims (heads x
+    head_dim, query rows x page rows), so any page size, head count and
+    head_dim tile legally. Queries and the mask are regrouped here (GQA
+    group folded into the query rows); the kernel writes [B, Hkv, G*C, D]
+    and the wrapper restores [B, C, Hq, D].
     """
     b, c, hq, d = q.shape
     ps, hkv = pages_k.shape[1], pages_k.shape[2]
     n_pages = blocks.shape[1]
     assert hq % hkv == 0
     group = hq // hkv
+    gc = group * c
     assert view_ok.shape == (b, c, n_pages * ps), (view_ok.shape, n_pages, ps)
     ring = ring_k is not None
     if ring:
         r = ring_k.shape[1]
         assert ring_ok is not None and ring_ok.shape == (b, r)
 
-    grid = (b, hq, n_pages)
+    qg = (q.reshape(b, c, hkv, group, d).transpose(0, 2, 3, 1, 4)
+          .reshape(b, hkv, gc, d))
+    mask = (view_ok.reshape(b, c, n_pages, ps).transpose(0, 2, 1, 3)
+            .astype(jnp.int32))
+    mask = jnp.tile(mask, (1, 1, group, 1))            # [B, P, G*C, ps]
+
+    kv_spec = pl.BlockSpec((1, ps, hkv, d),
+                           lambda b_, s, j, tab: (tab[b_, j], 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, c, 1, d), lambda b_, h, j, tab: (b_, 0, h, 0)),
-        pl.BlockSpec((1, ps, 1, d),
-                     lambda b_, h, j, tab: (tab[b_, j], 0, h // group, 0)),
-        pl.BlockSpec((1, ps, 1, d),
-                     lambda b_, h, j, tab: (tab[b_, j], 0, h // group, 0)),
-        pl.BlockSpec((1, c, ps), lambda b_, h, j, tab: (b_, 0, j)),
+        pl.BlockSpec((1, hkv, gc, d), lambda b_, s, j, tab: (b_, 0, 0, 0)),
+        kv_spec,
+        # values are only read by sweep 1; sweep 0 parks on the first
+        # page so the pipeline fetches it once for both sweeps
+        pl.BlockSpec((1, ps, hkv, d),
+                     lambda b_, s, j, tab: (tab[b_, j * s], 0, 0, 0)),
+        pl.BlockSpec((1, 1, gc, ps), lambda b_, s, j, tab: (b_, j, 0, 0)),
     ]
-    args = [q, pages_k, pages_v, view_ok.astype(jnp.int32)]
+    args = [qg, pages_k, pages_v, mask]
     if ring:
+        lane_spec = pl.BlockSpec((1, r, hkv, d),
+                                 lambda b_, s, j, tab: (b_, 0, 0, 0))
         in_specs += [
-            pl.BlockSpec((1, r, 1, d),
-                         lambda b_, h, j, tab: (b_, 0, h // group, 0)),
-            pl.BlockSpec((1, r, 1, d),
-                         lambda b_, h, j, tab: (b_, 0, h // group, 0)),
-            pl.BlockSpec((1, r), lambda b_, h, j, tab: (b_, 0)),
+            lane_spec, lane_spec,
+            pl.BlockSpec((1, 1, r), lambda b_, s, j, tab: (b_, 0, 0)),
         ]
-        args += [ring_k, ring_v, ring_ok.astype(jnp.int32)]
+        args += [ring_k, ring_v, ring_ok.astype(jnp.int32).reshape(b, 1, r)]
 
     fn = pl.pallas_call(
         functools.partial(
-            _paged_kernel, ps=ps, n_pages=n_pages, scale=d ** -0.5, ring=ring,
+            _paged_kernel, n_pages=n_pages, scale=d ** -0.5, ring=ring,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(b, 2, n_pages),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (1, c, 1, d), lambda b_, h, j, tab: (b_, 0, h, 0)),
+                (1, hkv, gc, d), lambda b_, s, j, tab: (b_, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((c, n_pages * ps), jnp.float32),
-                pltpu.VMEM((n_pages * ps, d), pages_v.dtype),
+                pltpu.VMEM((hkv, gc, 1), jnp.float32),   # running max
+                pltpu.VMEM((hkv, gc, 1), jnp.float32),   # denominator
+                pltpu.VMEM((hkv, gc, d), jnp.float32),   # weighted sum
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, gc, d), q.dtype),
         interpret=interpret,
+        name="flash_decode_paged",
     )
-    return fn(blocks, *args)
+    out = fn(blocks, *args)
+    return (out.reshape(b, hkv, group, c, d).transpose(0, 3, 1, 2, 4)
+            .reshape(b, c, hq, d))
 
 
 def flash_decode_paged_sharded(
@@ -229,10 +264,6 @@ def flash_decode_paged_sharded(
     inputs (``blocks``, ``view_ok``, ``ring_ok``) are replicated;
     KV-carrying tensors split on their head axis.
     """
-    try:
-        from jax.shard_map import shard_map  # jax >= 0.6
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     tp = mesh.shape[axis]
@@ -257,13 +288,8 @@ def flash_decode_paged_sharded(
     def shard_fn(*xs):
         return flash_decode_paged(*xs, interpret=interpret)
 
-    try:
-        # pallas_call has no replication rule — skip the rep check
-        # (kwarg renamed check_rep -> check_vma across jax versions)
-        sm = shard_map(shard_fn, mesh=mesh, in_specs=tuple(in_specs),
-                       out_specs=h_spec, check_rep=False)
-    except TypeError:
-        sm = shard_map(shard_fn, mesh=mesh, in_specs=tuple(in_specs),
+    # pallas_call has no replication rule — skip the check
+    sm = jax.shard_map(shard_fn, mesh=mesh, in_specs=tuple(in_specs),
                        out_specs=h_spec, check_vma=False)
     return sm(*args)
 

@@ -1,8 +1,8 @@
 """ops — jit'd public wrappers around the Pallas kernels.
 
 Each wrapper:
-* dispatches to the Pallas kernel (compiled on TPU, ``interpret=True`` when
-  the backend is CPU — the container validates kernels in interpret mode);
+* dispatches to the Pallas kernel: compiled by Mosaic on a TPU, run with
+  ``interpret=True`` only when JAX's backend is the CPU (the test lane);
 * can be forced to the pure-jnp oracle with ``impl='ref'`` (used by tests
   and as a paranoid fallback);
 * is shape/dtype polymorphic within the kernels' documented constraints.

@@ -1,9 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 Each ``*_ref`` is the semantic ground truth: tests sweep shapes/dtypes and
-assert_allclose the kernel (interpret mode on CPU, compiled on TPU) against
-these. They are also the CPU fallback used by ops.py where Pallas interpret
-mode would be needlessly slow.
+assert_allclose the kernel against these (interpret mode in the CPU tests,
+compiled on the TPU by ``chip_smoke.py``). ops.py also serves the oracle on
+a CPU backend for kernels whose interpret mode would be needlessly slow.
 """
 from __future__ import annotations
 

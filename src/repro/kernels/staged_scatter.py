@@ -11,8 +11,10 @@ TPU adaptation notes (DESIGN.md §2):
 * destination row indices arrive via ``PrefetchScalarGridSpec`` so the DMA
   engine knows the target block BEFORE the grid step runs (the RNIC "knows
   the translation" — by construction, not by cache luck);
-* payload rows are tiled to (1, BW) VMEM blocks with BW a multiple of 128
-  lanes;
+* payload rows are tiled to (1, 1, BW) VMEM blocks over a free [rows, 1, W]
+  view, BW a multiple of 128 lanes: a block's last two dims are then
+  (whole unit axis, lane-aligned width), which the TPU tiling accepts for
+  any row count;
 * ``input_output_aliases`` updates the destination in place (the drain is
   an update, not a copy of the whole memory);
 * the kernel body is an UNCONDITIONAL copy: invalid entries are handled in
@@ -69,20 +71,23 @@ def staged_scatter(
     rows_eff = jnp.where(valid_s, rows_s, fill_row).astype(jnp.int32)
     stage_eff = jnp.where(valid_s[:, None], stage_s, fill_data[None, :])
 
+    row_block = pl.BlockSpec((1, 1, bw), lambda i, j, rows: (rows[i], 0, j))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # rows_eff
         grid=(n, w // bw),
         in_specs=[
-            pl.BlockSpec((1, bw), lambda i, j, rows: (i, j)),        # staging
-            pl.BlockSpec((1, bw), lambda i, j, rows: (rows[i], j)),  # dest (aliased)
+            pl.BlockSpec((1, 1, bw), lambda i, j, rows: (i, 0, j)),  # staging
+            row_block,                                              # dest (aliased)
         ],
-        out_specs=pl.BlockSpec((1, bw), lambda i, j, rows: (rows[i], j)),
+        out_specs=row_block,
     )
     fn = pl.pallas_call(
         _drain_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(dest.shape, dest.dtype),
+        out_shape=jax.ShapeDtypeStruct((r, 1, w), dest.dtype),
         input_output_aliases={2: 0},  # dest (operand 2, counting prefetch) -> out
         interpret=interpret,
+        name="staged_scatter",
     )
-    return fn(rows_eff, stage_eff, dest)
+    out = fn(rows_eff, stage_eff.reshape(n, 1, w), dest.reshape(r, 1, w))
+    return out.reshape(r, w)

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from ..core import ring as R
 
@@ -705,7 +706,8 @@ def _auto_drain_kernel() -> bool:
     return jax.default_backend() != "cpu"
 
 
-def drain_ring(cache: PagedKV, use_kernel: Optional[bool] = None) -> PagedKV:
+def drain_ring(cache: PagedKV, use_kernel: Optional[bool] = None,
+               shardings=None) -> PagedKV:
     """Bulk-copy all staged entries into the pool, empty the ring.
 
     Per layer, ALL slots' entries flatten into ONE entry list (``core.ring.
@@ -723,26 +725,40 @@ def drain_ring(cache: PagedKV, use_kernel: Optional[bool] = None) -> PagedKV:
     drain's routing inputs (``ring_pos``, page table) are replicated, so
     every shard computes the SAME destination rows and scatters only its
     own head slice: a per-shard ``staged_scatter``, no cross-shard
-    traffic (the paper's keep-the-unload-local contract under TP)."""
+    traffic (the paper's keep-the-unload-local contract under TP). The
+    jnp scatter gets that from GSPMD; the kernel is run per shard
+    explicitly (``shard_map`` over the cache ``shardings``, the serve
+    scheduler's placement dict), since a Mosaic kernel is never
+    partitioned by the compiler."""
     if use_kernel is None:
         use_kernel = _auto_drain_kernel()
-    l, b, r, h, dh = cache["ring_k"].shape
-    n_phys = pool_rows(cache)
     # resolve logical -> physical per ring column, then flatten lanes
     phys = jax.vmap(lambda rows: logical_to_physical(cache, rows),
                     in_axes=1, out_axes=1)(cache["ring_pos"])
     rows, ok = R.merge_lanes(ring_state(cache), phys)
-    # logical_to_physical maps every invalid row to exactly n_phys, which
-    # scatter_rows drops — no re-clamp needed
+    # logical_to_physical maps every invalid row to exactly the pool's
+    # row count, which scatter_rows drops — no re-clamp needed
 
-    def drain_layer(pages_l, staging_l):
-        flat = pages_l.reshape(n_phys, h * dh)
-        out = R.scatter_rows(flat, staging_l.reshape(b * r, h * dh),
-                             rows, ok, use_kernel=use_kernel)
-        return out.reshape(pages_l.shape)
+    def drain_planes(pages, staging, rows, ok):
+        _, nb, ps, h, dh = pages.shape
+        _, b, r, _, _ = staging.shape
 
-    new_k = jax.vmap(drain_layer)(cache["pages_k"], cache["ring_k"])
-    new_v = jax.vmap(drain_layer)(cache["pages_v"], cache["ring_v"])
+        def drain_layer(pages_l, staging_l):
+            flat = pages_l.reshape(nb * ps, h * dh)
+            out = R.scatter_rows(flat, staging_l.reshape(b * r, h * dh),
+                                 rows, ok, use_kernel=use_kernel)
+            return out.reshape(pages_l.shape)
+
+        return jax.vmap(drain_layer)(pages, staging)
+
+    if use_kernel and shardings is not None:
+        pool = shardings["pages_k"]
+        drain_planes = jax.shard_map(
+            drain_planes, mesh=pool.mesh,
+            in_specs=(pool.spec, shardings["ring_k"].spec, P(), P()),
+            out_specs=pool.spec, check_vma=False)
+    new_k = drain_planes(cache["pages_k"], cache["ring_k"], rows, ok)
+    new_v = drain_planes(cache["pages_v"], cache["ring_v"], rows, ok)
     return dict(
         cache,
         pages_k=new_k,
@@ -756,6 +772,7 @@ def maybe_drain(
     cache: PagedKV,
     use_kernel: Optional[bool] = None,
     incoming_pos: Optional[jnp.ndarray] = None,
+    shardings=None,
 ) -> Tuple[PagedKV, jnp.ndarray]:
     """Fixed-shape conditional drain: ring full OR incoming logical rows
     conflict with pending entries. Returns (cache, drained bool)."""
@@ -764,7 +781,7 @@ def maybe_drain(
         due = due | ring_conflicts(cache, incoming_pos)
     cache = lax.cond(
         due,
-        lambda c: drain_ring(c, use_kernel=use_kernel),
+        lambda c: drain_ring(c, use_kernel=use_kernel, shardings=shardings),
         lambda c: dict(c),
         cache,
     )

@@ -26,7 +26,6 @@ import traceback
 
 import jax
 
-from ..compat import cost_analysis_dict, use_abstract_mesh
 from ..configs import ALL_SHAPES, ARCHS, get_config, get_shape, shape_applicable
 from . import cells as C
 from . import roofline as R
@@ -61,7 +60,7 @@ def run_cell(arch: str, shape_name: str, *, probes: bool = True,
         step, args, meta = C.build_cell(cfg, shape, mesh,
                                         dispatch_mode=dispatch_mode)
         args = tuple(a for a in args if a is not None)
-        with mesh, use_abstract_mesh(mesh.abstract_mesh):
+        with mesh, jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
             lowered = jax.jit(step).lower(*args)
             compiled = lowered.compile()
         ma = compiled.memory_analysis()
@@ -76,7 +75,7 @@ def run_cell(arch: str, shape_name: str, *, probes: bool = True,
         # raw (scan-body-once) cost numbers for reference; exact totals come
         # from the probes below
         rec[mesh_kind]["cost_raw"] = {
-            k: float(v) for k, v in cost_analysis_dict(compiled).items()
+            k: float(v) for k, v in compiled.cost_analysis().items()
             if k in ("flops", "bytes accessed")
         }
         rec[mesh_kind]["collectives_raw"] = R.collective_bytes(compiled.as_text())

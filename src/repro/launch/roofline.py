@@ -1,11 +1,12 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Hardware model (TPU v5e): 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI. Per (arch x shape) on the single-pod mesh we derive:
+Hardware model: the target chip's published peaks (``launch.device.PEAKS``,
+TPU v5e by default: 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s per ICI link).
+Per (arch x shape) on the single-pod mesh we derive:
 
-    compute_s    = FLOPs_per_device / 197e12
-    memory_s     = bytes_per_device / 819e9
-    collective_s = collective_bytes_per_device / 50e9
+    compute_s    = FLOPs_per_device / peak FLOP/s
+    memory_s     = bytes_per_device / HBM bytes/s
+    collective_s = collective_bytes_per_device / ICI link bytes/s
 
 ``compiled.cost_analysis()`` is PER-DEVICE on an SPMD module (verified: a
 512-way-sharded einsum reports global/512 flops), so no further division by
@@ -34,13 +35,9 @@ from typing import Any, Dict
 
 import jax
 
-from ..compat import cost_analysis_dict, use_abstract_mesh
 from ..configs.base import ModelConfig, ShapeSpec
 from . import cells as C
-
-PEAK_FLOPS = 197e12   # bf16 / chip
-HBM_BW = 819e9        # B/s
-ICI_BW = 50e9         # B/s/link
+from .device import V5E, device_peaks
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -81,7 +78,7 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
 
 
 def compiled_metrics(compiled) -> Dict[str, float]:
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     return {
         "flops": float(ca.get("flops", 0.0)),
@@ -107,7 +104,7 @@ def _lower_metrics(cfg, shape, mesh, *, microbatches=None, dispatch_mode="staged
         dispatch_mode=dispatch_mode,
     )
     args = tuple(a for a in args if a is not None)
-    with mesh, use_abstract_mesh(mesh.abstract_mesh):
+    with mesh, jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         compiled = jax.jit(step).lower(*args).compile()
     return compiled_metrics(compiled)
 
@@ -204,10 +201,11 @@ def probe_cell(
 
 
 def roofline_terms(metrics: Dict[str, float], cfg: ModelConfig,
-                   shape: ShapeSpec) -> Dict[str, Any]:
-    compute_s = metrics["flops"] / PEAK_FLOPS
-    memory_s = metrics["bytes"] / HBM_BW
-    coll_s = metrics["coll_bytes"] / ICI_BW
+                   shape: ShapeSpec, device_kind: str = V5E) -> Dict[str, Any]:
+    peaks = device_peaks(device_kind)
+    compute_s = metrics["flops"] / peaks.flops_bf16
+    memory_s = metrics["bytes"] / peaks.hbm_bw
+    coll_s = metrics["coll_bytes"] / peaks.ici_link_bw
     dominant = max(
         ("compute", compute_s), ("memory", memory_s), ("collective", coll_s),
         key=lambda kv: kv[1],

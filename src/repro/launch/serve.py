@@ -11,8 +11,9 @@ One front door (``repro.serve.Engine``), two workload shapes:
 
 The write path and routing policy are registry names
 (``repro.core.paths`` / ``repro.core.policy``); sampling is per-request
-``SamplingParams``. Reduced configs on CPU; production shardings under a
-mesh.
+``SamplingParams``. The model is built at its published widths (random
+weights from a seed); ``--reduced`` swaps in the tiny smoke widths the CPU
+recipes use.
 """
 from __future__ import annotations
 
@@ -28,11 +29,16 @@ from ..models import media_spec, needs_media
 from ..models.sampling import SamplingParams
 from ..serve import Engine, EngineConfig, build_model_and_params
 from ..serve.scheduler import paged_capable
+from .device import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny smoke widths (CPU recipes) instead of the "
+                         "arch's published widths")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen-len", type=int, default=32)
@@ -69,7 +75,8 @@ def main() -> None:
                          "prompt of this length (mixed workload)")
     args = ap.parse_args()
 
-    cfg, model, params = build_model_and_params(args.arch, args.max_seq)
+    cfg, model, params = build_model_and_params(args.arch, args.max_seq,
+                                                reduced=args.reduced)
 
     path = args.path
     if path != "direct" and not paged_capable(model):

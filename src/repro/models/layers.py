@@ -21,7 +21,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..compat import get_abstract_mesh
 from ..configs.base import GELU, LAYERNORM, RMSNORM, SQUARED_RELU, SWIGLU, ModelConfig
 
 # ---------------------------------------------------------------------------
@@ -168,8 +167,8 @@ def _tp_head_pad(h: int) -> int:
     outputs are sliced off, so the math is exact). Costs h_pad/h extra
     attention FLOPs; buys head-sharded score tensors.
     """
-    mesh = get_abstract_mesh()
-    if mesh is None or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" not in mesh.axis_names:
         return 0
     m = mesh.shape["model"]
     if h % m == 0:
@@ -370,6 +369,7 @@ def fused_paged_attention(
     ring_ok: Optional[jnp.ndarray] = None,  # bool [B, R]
     use_rope: bool = True,
     impl: str = "auto",
+    mesh=None,
 ) -> jnp.ndarray:
     """Decode attention through the ``flash_decode_paged`` read kernel.
 
@@ -379,14 +379,20 @@ def fused_paged_attention(
     Projections (``project_q``) and the output einsum are shared with the
     jnp cores — fused and reference differ ONLY in the attention core,
     which the kernel holds to ulp-level fp32 parity (identical greedy
-    tokens; DESIGN.md §7).
+    tokens; DESIGN.md §7). Under a serving ``mesh`` the pool is
+    head-sharded and the kernel runs once per shard (``shard_map``): a
+    Mosaic kernel is never partitioned by the compiler.
     """
-    from ..kernels import flash_decode_paged
+    from ..kernels import flash_decode_paged, flash_decode_paged_sharded
 
     dtype = x.dtype
     q = project_q(cfg, p, x, positions, use_rope)   # [B, C, Hq, Dh]
-    out = flash_decode_paged(q, pages_k, pages_v, blocks, view_ok,
-                             ring_k, ring_v, ring_ok, impl=impl)
+    if mesh is not None:
+        out = flash_decode_paged_sharded(mesh, q, pages_k, pages_v, blocks,
+                                         view_ok, ring_k, ring_v, ring_ok)
+    else:
+        out = flash_decode_paged(q, pages_k, pages_v, blocks, view_ok,
+                                 ring_k, ring_v, ring_ok, impl=impl)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dtype))
 
 
@@ -532,8 +538,8 @@ def shard_batch(x: jnp.ndarray) -> jnp.ndarray:
     rematerialization (replicating [B, S, D] per layer). One constraint at
     the residual stream's source pins the whole scan to batch sharding.
     """
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     size = 1
@@ -581,8 +587,8 @@ def lm_logits(cfg: ModelConfig, p: dict, x: jnp.ndarray) -> jnp.ndarray:
     w = p["tok"] if cfg.tie_embeddings else p["head"]
     v = w.shape[0]
     vp = 0
-    mesh = get_abstract_mesh()
-    if mesh is not None and "model" in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" in mesh.axis_names:
         m = mesh.shape["model"]
         if v % m:
             vp = (v + m - 1) // m * m

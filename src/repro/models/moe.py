@@ -31,7 +31,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import get_abstract_mesh
 from ..configs.base import ModelConfig
 from . import layers as L
 
@@ -43,8 +42,8 @@ DISPATCH_MODES = ("direct", "staged", "adaptive")
 def _constrain(x: jnp.ndarray, *spec) -> jnp.ndarray:
     """with_sharding_constraint that no-ops outside a mesh context and
     drops axes that don't divide the corresponding dim."""
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     fixed = []
     for dim, s in zip(x.shape, spec):
@@ -60,8 +59,8 @@ def _constrain(x: jnp.ndarray, *spec) -> jnp.ndarray:
 def buf_constraint(buf: jnp.ndarray, n_experts: int) -> jnp.ndarray:
     """Expert-buffer sharding: EP over "model" when E divides it, else the
     capacity dim over "data" (keeps dispatch scatters shard-local-ish)."""
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return buf
     if "model" in mesh.axis_names and n_experts % mesh.shape["model"] == 0:
         return _constrain(buf, "model", None, None)
@@ -281,8 +280,8 @@ def moe_ffn_layer(
     # dispatch buffers shard EP-style instead of replicating. Padded experts
     # never receive assignments (router logits only span the real E).
     n_experts = cfg.n_experts
-    mesh = get_abstract_mesh()
-    if mesh is not None and "model" in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" in mesh.axis_names:
         m = mesh.shape["model"]
         if n_experts % m:
             n_experts = (n_experts + m - 1) // m * m

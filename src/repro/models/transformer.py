@@ -410,6 +410,7 @@ class DecoderLM:
         unload_mask: Optional[jnp.ndarray] = None,
         attention: str = "reference",
         plan: Optional[PG.StepPlan] = None,
+        mesh=None,
     ) -> Tuple[jnp.ndarray, Params]:
         """One decode step against a PAGED KV pool (``repro.kvcache.paged``).
 
@@ -432,7 +433,8 @@ class DecoderLM:
         reference is the kernel's oracle; DESIGN.md §7 has the parity
         contract). ``plan`` threads per-segment
         hoisted page-table products (``PG.step_plan``); when None it is
-        derived here.
+        derived here. ``mesh`` is the serving mesh of a head-sharded pool
+        (the fused kernel then runs per shard).
 
         The per-slot attention view is gathered from the pool through the
         page table each step — values are identical to the dense cache
@@ -487,7 +489,8 @@ class DecoderLM:
                 a = L.fused_paged_attention(
                     cfg, p["attn"], hn, pos[:, None], pk, pv,
                     plan.blocks, view_ok[:, None, :],
-                    rk if ring else None, rv if ring else None, ring_ok)
+                    rk if ring else None, rv if ring else None, ring_ok,
+                    mesh=mesh)
             else:
                 ak = PG.gather_view(pk, view_ids)
                 av = PG.gather_view(pv, view_ids)
@@ -536,6 +539,7 @@ class DecoderLM:
         attention: str = "reference",
         plan: Optional[PG.StepPlan] = None,
         all_logits: bool = False,
+        mesh=None,
     ) -> Tuple[jnp.ndarray, Params]:
         """One MIXED-PHASE step against the paged pool: each slot processes
         a [C]-token slab — a prefill chunk (``n_valid`` prompt tokens from
@@ -611,7 +615,8 @@ class DecoderLM:
                 a = L.fused_paged_attention(
                     cfg, p["attn"], hn, positions, pk, pv,
                     plan.blocks, view_ok,
-                    rk if ring else None, rv if ring else None, ring_lane_ok)
+                    rk if ring else None, rv if ring else None, ring_lane_ok,
+                    mesh=mesh)
             else:
                 ak = PG.gather_view(pk, view_ids)
                 av = PG.gather_view(pv, view_ids)

@@ -533,14 +533,16 @@ class BatchedServeEngine:
             if ring:
                 cache, drained = PG.maybe_drain(
                     cache, use_kernel=dk,
-                    incoming_pos=jnp.where(active, st.pos, -1))
+                    incoming_pos=jnp.where(active, st.pos, -1),
+                    shardings=cache_shardings)
                 logits, cache = model.decode_step_paged(
                     params, cache, st.token, st.pos, active,
-                    unload_mask=unload, attention=attn, plan=plan)
+                    unload_mask=unload, attention=attn, plan=plan,
+                    mesh=mesh)
             elif paged:
                 logits, cache = model.decode_step_paged(
                     params, cache, st.token, st.pos, active,
-                    attention=attn, plan=plan)
+                    attention=attn, plan=plan, mesh=mesh)
             else:
                 # retired slots never write: redirect their scatter rows
                 # to the out-of-range drop sentinel (SSM recurrent state
@@ -598,7 +600,8 @@ class BatchedServeEngine:
                 # segment boundary: the host may retire slots and free
                 # their blocks next — the ring must not hold entries that
                 # would later drain into reallocated blocks
-                cache = PG.drain_ring(cache, use_kernel=dk)
+                cache = PG.drain_ring(cache, use_kernel=dk,
+                                      shardings=cache_shardings)
             if mesh is not None:
                 # pin the pool/ring back to their head shards and the
                 # telemetry carries to replicated — the segment boundary
@@ -665,14 +668,16 @@ class BatchedServeEngine:
             if ring:
                 cache, drained = PG.maybe_drain(
                     cache, use_kernel=dk,
-                    incoming_pos=jnp.where(active & ~is_pf, st.pos, -1))
+                    incoming_pos=jnp.where(active & ~is_pf, st.pos, -1),
+                    shardings=cache_shardings)
                 logits, cache = model.decode_chunk_paged(
                     params, cache, tokens, st.pos, n_valid, active,
-                    unload_mask=unload, attention=attn, plan=plan)
+                    unload_mask=unload, attention=attn, plan=plan,
+                    mesh=mesh)
             else:
                 logits, cache = model.decode_chunk_paged(
                     params, cache, tokens, st.pos, n_valid, active,
-                    attention=attn, plan=plan)
+                    attention=attn, plan=plan, mesh=mesh)
             finishing = is_pf & (st.pos + n_valid >= st.plen)
             emitting = (active & ~is_pf) | finishing
             # the first token after the prompt is the prefill ARGMAX in
@@ -719,7 +724,8 @@ class BatchedServeEngine:
                 length=cfg.segment_len,
             )
             if ring:
-                cache = PG.drain_ring(cache, use_kernel=dk)
+                cache = PG.drain_ring(cache, use_kernel=dk,
+                                      shardings=cache_shardings)
             if mesh is not None:
                 cache = PG.constrain(cache, cache_shardings)
                 st, mon, stats, swrites, emits, ems = (
@@ -810,7 +816,7 @@ class BatchedServeEngine:
                 active=qvalid.reshape(-1))
             t_logits, cache = model.decode_chunk_paged(
                 params, cache, chunk, st.pos, n_valid, active,
-                attention=attn, plan=plan, all_logits=True)
+                attention=attn, plan=plan, all_logits=True, mesh=mesh)
             commit, n_commit, n_acc, key = SMP.spec_verify(
                 t_logits, d_tokens, d_log, st.key, st.sampling, mode=mode)
             n_commit = jnp.where(active, n_commit, 0)

@@ -2,17 +2,18 @@
 
 Parity contract (DESIGN.md §7), tested at two boundaries:
 
-* KERNEL boundary: the Pallas kernel (interpret mode on CPU) replicates
-  the jnp reference's exact op ORDER and is held to fp32 ulp-level
-  equality (~1e-7 abs; tolerance carries 10x margin) — swept across ring
-  states (empty / partial / full / wrapped / conflict-shaped), chunk
-  sizes, GQA group sizes, and page geometries, and cross-checked against
-  the REAL reference core (``gather_view`` + ring concat + ``layers``
-  sdpa math, which IS bitwise-equal to the packaged oracle) so the
-  oracle can't drift into a strawman. Bit-identity across the two
-  formulations is not achievable on this stack: XLA tiles the kernel's
-  per-page [C, ps] score dots differently from the reference's
-  full-width einsum, reassociating the fp32 sums.
+* KERNEL boundary: the Pallas kernel (interpret mode on CPU) keeps the
+  jnp reference's op ORDER (logits -> mask -> softmax -> cast -> weighted
+  sum) and is held to fp32 ulp-level equality (~1e-7 abs; tolerance
+  carries 10x margin) — swept across ring states (empty / partial /
+  full / wrapped / conflict-shaped), chunk sizes, GQA group sizes, and
+  page geometries, and cross-checked against the REAL reference core
+  (``gather_view`` + ring concat + ``layers`` sdpa math, which IS
+  bitwise-equal to the packaged oracle) so the oracle can't drift into a
+  strawman. Bit-identity is not achievable: the kernel sums the softmax
+  denominator page by page with running-max rescaling, and the weighted
+  values page by page, where the reference reduces each in one pass —
+  the same terms in another fp32 association, hence ulps.
 * ENGINE boundary: fused vs reference serving produces IDENTICAL token
   streams across every paged-layout arch in the config matrix × write
   modes (direct / staged / adaptive) × chunked scheduling — ulp noise

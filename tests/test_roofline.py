@@ -84,10 +84,8 @@ def test_scan_undercounts_unroll_doesnt():
 
     x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     w = jax.ShapeDtypeStruct((4, 64, 64), jnp.float32)
-    from repro.compat import cost_analysis_dict
-
-    fs = cost_analysis_dict(jax.jit(f_scan).lower(x, w).compile())["flops"]
-    fu = cost_analysis_dict(jax.jit(f_unroll).lower(x, w).compile())["flops"]
+    fs = jax.jit(f_scan).lower(x, w).compile().cost_analysis()["flops"]
+    fu = jax.jit(f_unroll).lower(x, w).compile().cost_analysis()["flops"]
     assert fu > 3 * fs  # unrolled sees ~4x the flops
 
 
