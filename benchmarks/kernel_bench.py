@@ -11,9 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
-from repro.launch.device import V5E, device_peaks
-
-PEAKS = device_peaks(V5E)
+from repro.launch.device import PEAKS, V5E
 
 
 def _wall(f, *args, n=20) -> float:
@@ -39,7 +37,7 @@ def run() -> list:
     f = jax.jit(ref.staged_scatter_ref)
     rows.append(("kern/staged_scatter_ref_ms", _wall(f, dest, staging, rows_i, valid), "ms"))
     bytes_moved = n * w * 4 * 2
-    rows.append(("kern/staged_scatter_tpu_roofline_us", bytes_moved / PEAKS.hbm_bw * 1e6, "us"))
+    rows.append(("kern/staged_scatter_tpu_roofline_us", bytes_moved / PEAKS[V5E].hbm_bw * 1e6, "us"))
 
     # flash attention prefill tile: chunked-prefill geometry
     b, hq, hkv, s, t, d = 2, 16, 4, 1024, 8192, 128
@@ -49,7 +47,7 @@ def run() -> list:
     f = jax.jit(lambda q, k, v: ref.flash_attention_ref(q, k, v))
     rows.append(("kern/flash_attn_ref_ms", _wall(f, q, k, v, n=5), "ms"))
     flops = 4 * b * hq * s * t * d
-    rows.append(("kern/flash_attn_tpu_roofline_us", flops / PEAKS.flops_bf16 * 1e6, "us"))
+    rows.append(("kern/flash_attn_tpu_roofline_us", flops / PEAKS[V5E].flops_bf16 * 1e6, "us"))
 
     # flash decode: 32k cache
     tkv = 32768
@@ -60,7 +58,7 @@ def run() -> list:
     f = jax.jit(ref.flash_decode_ref)
     rows.append(("kern/flash_decode_ref_ms", _wall(f, qd, kd, vd, mask, n=5), "ms"))
     bytes_kv = 8 * tkv * hkv * d * 2 * 2
-    rows.append(("kern/flash_decode_tpu_roofline_us", bytes_kv / PEAKS.hbm_bw * 1e6, "us"))
+    rows.append(("kern/flash_decode_tpu_roofline_us", bytes_kv / PEAKS[V5E].hbm_bw * 1e6, "us"))
 
     # cms monitor hot path
     counts = jnp.zeros((4, 4096), jnp.int32)

@@ -21,7 +21,11 @@ reference path is.
 ``--chips 4`` runs only the mesh phase: the same traffic with
 ``ParallelConfig.tensor(4)`` (8 of the 32 heads per chip) against tensor
 parallelism 1 on one chip of the same host, in this one process, and
-prints the token agreement.
+prints the token agreement. It then checks what only the mesh runs: the
+read kernel per head shard against its oracle, the ring drain per head
+shard (``shard_map``) against the jnp scatter, and the tp=4 fused
+prefill-step logits against float32, held to the same bound as on one
+chip.
 
 Progress goes to stdout; the last line is one JSON object naming the
 device. Without a TPU the script fails before it serves anything.
@@ -29,6 +33,7 @@ device. Without a TPU the script fails before it serves anything.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -151,18 +156,40 @@ def agreement(a, b) -> dict:
                 [x[0] == y[0] for x, y in zip(a, b)]))}
 
 
-def kernel_checks(cfg) -> None:
+def kernel_checks(cfg, mesh=None) -> None:
     """Both Pallas kernels, compiled for this chip, at the served widths,
-    against their jnp oracles."""
+    against their jnp oracles. Under a ``mesh`` the read kernel runs as
+    serving runs it there, one instance per head shard; the drain is
+    checked by :func:`drain_check`."""
     import jax
     import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
 
-    from repro.kernels import flash_decode_paged, ref, staged_scatter
+    from repro.kernels import (
+        flash_decode_paged,
+        flash_decode_paged_sharded,
+        ref,
+        staged_scatter,
+    )
 
     hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     n_blocks, pages = N_SLOTS * MAX_SEQ // PAGE, MAX_SEQ // PAGE
     keys = jax.random.split(jax.random.key(SEED + 1), 8)
     bf16 = jnp.bfloat16
+    if mesh is None:
+        read, where = flash_decode_paged, ""
+
+        def put(x, heads):
+            return x
+    else:
+        read = functools.partial(flash_decode_paged_sharded, mesh)
+        where = f" per head shard on {mesh.shape['model']} chips"
+
+        def put(x, heads):   # heads ride axis 2 of every KV operand
+            spec = P(None, None, "model", None) if heads else P()
+            return jax.device_put(x, NamedSharding(mesh, spec))
+
     pool_k = jax.random.normal(keys[0], (n_blocks, PAGE, hkv, d), bf16)
     pool_v = jax.random.normal(keys[1], (n_blocks, PAGE, hkv, d), bf16)
     blocks = jax.random.randint(keys[2], (N_SLOTS, pages), 0, n_blocks)
@@ -175,7 +202,10 @@ def kernel_checks(cfg) -> None:
             extra = (jax.random.normal(keys[5], (N_SLOTS, RING, hkv, d), bf16),
                      jax.random.normal(keys[6], (N_SLOTS, RING, hkv, d), bf16),
                      jax.random.bernoulli(keys[7], 0.5, (N_SLOTS, RING)))
-        got = flash_decode_paged(q, pool_k, pool_v, blocks, view_ok, *extra)
+        heads = (True, True, True, False, False, True, True, False)
+        args = [x if x is None else put(x, h) for x, h in
+                zip((q, pool_k, pool_v, blocks, view_ok) + extra, heads)]
+        got = read(*args)
         want = jax.jit(ref.flash_decode_paged_ref)(
             q, pool_k, pool_v, blocks, view_ok, *extra)
         got = np.asarray(got, np.float32)
@@ -183,8 +213,10 @@ def kernel_checks(cfg) -> None:
         err = float(np.max(np.abs(got - want)))
         ok = np.allclose(got, want, atol=KERNEL_ATOL, rtol=KERNEL_RTOL)
         check(bool(ok) and np.isfinite(got).all(),
-              f"flash_decode_paged C={c} ring={ring} matches its oracle "
-              f"(max abs diff {err:.3g}, atol=rtol={KERNEL_ATOL})")
+              f"flash_decode_paged{where} C={c} ring={ring} matches its "
+              f"oracle (max abs diff {err:.3g}, atol=rtol={KERNEL_ATOL})")
+    if mesh is not None:
+        return
 
     rows_total, width = n_blocks * PAGE, hkv * d
     dest = jax.random.normal(keys[0], (rows_total, width), bf16)
@@ -198,15 +230,56 @@ def kernel_checks(cfg) -> None:
           "staged_scatter equals its oracle exactly (it is a copy)")
 
 
-def logit_check(model, weights) -> None:
+def drain_check(cfg, mesh) -> None:
+    """The staging-ring drain as serving runs it under ``mesh`` (one
+    ``staged_scatter`` per head shard, ``shard_map`` over the cache
+    placements), at the served pool geometry over two layers, against the
+    jnp scatter on one chip: a copy, so exact."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.distributed.sharding import serve_cache_shardings
+    from repro.kvcache import paged as PG
+
+    pages, bf16 = MAX_SEQ // PAGE, jnp.bfloat16
+    keys = jax.random.split(jax.random.key(SEED + 3), 8)
+    cache = PG.make_paged_kv(2, N_SLOTS * pages, PAGE, N_SLOTS, pages,
+                             cfg.n_kv_heads, cfg.resolved_head_dim,
+                             dtype=bf16, ring_size=RING)
+    for i, key in enumerate(("pages_k", "pages_v", "ring_k", "ring_v")):
+        cache[key] = jax.random.normal(keys[i], cache[key].shape, bf16)
+    cache["page_table"] = jax.random.permutation(
+        keys[4], N_SLOTS * pages).reshape(N_SLOTS, pages).astype(jnp.int32)
+    # distinct logical rows per slot, some lanes empty
+    rows = jax.vmap(lambda k: jax.random.permutation(k, MAX_SEQ)[:RING])(
+        jax.random.split(keys[5], N_SLOTS))
+    live = jax.random.bernoulli(keys[6], 0.7, rows.shape)
+    cache["ring_pos"] = jnp.where(live, rows, -1).astype(jnp.int32)
+    cache["ring_fill"] = jnp.asarray(RING, jnp.int32)
+    want = jax.jit(lambda c: PG.drain_ring(c, use_kernel=False))(cache)
+    shardings = serve_cache_shardings(cfg, mesh, cache)
+    got = jax.jit(lambda c: PG.drain_ring(c, use_kernel=True,
+                                          shardings=shardings))(
+        {k: jax.device_put(v, shardings[k]) for k, v in cache.items()})
+    same = all(np.array_equal(np.asarray(got[k]), np.asarray(want[k]))
+               for k in want)
+    check(same and int(live.sum()) > 0,
+          f"drain_ring per head shard on {mesh.shape['model']} chips "
+          f"equals the jnp scatter exactly ({int(live.sum())} staged rows)")
+
+
+def logit_check(model, weights, mesh=None) -> None:
     """One chunked-prefill step (64 tokens into each of 8 slots, logits at
     every position) through fused and reference attention in bf16, and
-    through the reference in float32 at full matmul precision."""
+    through the reference in float32 at full matmul precision. Under a
+    ``mesh`` the fused step runs there, placed as serving places it; the
+    reference and float32 steps run on one chip."""
     import dataclasses
 
     import jax
     import jax.numpy as jnp
 
+    from repro.distributed import sharding as DS
     from repro.kvcache import paged as PG
     from repro.models import build_model
 
@@ -218,18 +291,24 @@ def logit_check(model, weights) -> None:
     n_valid = jnp.full((N_SLOTS,), CHUNK, jnp.int32)
     write = jnp.ones((N_SLOTS,), jnp.bool_)
 
-    def step(m, params, attention):
+    def step(m, params, attention, mesh=None):
         cache = PG.make_paged_kv(
             cfg.n_layers, N_SLOTS * pages, PAGE, N_SLOTS, pages,
             cfg.n_kv_heads, cfg.resolved_head_dim, dtype=m.cfg.dtype)
         cache["page_table"] = jnp.arange(
             N_SLOTS * pages, dtype=jnp.int32).reshape(N_SLOTS, pages)
+        if mesh is not None:
+            params = jax.device_put(
+                params, DS.param_shardings(m.cfg, mesh, params))
+            cache = jax.device_put(
+                cache, DS.serve_cache_shardings(m.cfg, mesh, cache))
         fn = jax.jit(lambda p, c: m.decode_chunk_paged(
             p, c, tokens, start, n_valid, write, attention=attention,
-            all_logits=True)[0])
+            all_logits=True, mesh=mesh)[0])
         return np.asarray(fn(params, cache), np.float32)
 
-    fused = step(model, weights, "fused")
+    fused = step(model, weights, "fused", mesh)
+    label = "fused" if mesh is None else f"fused tp={mesh.shape['model']}"
     reference = step(model, weights, "reference")
     m32 = build_model(dataclasses.replace(cfg, dtype="float32"))
     w32 = jax.tree.map(lambda x: x.astype(jnp.float32)
@@ -248,13 +327,13 @@ def logit_check(model, weights) -> None:
 
     err_f, err_r = rel(fused), rel(reference)
     print(f"prefill-step logits vs float32 ({N_SLOTS}x{CHUNK} positions): "
-          f"rel rms error fused {err_f:.3g}, reference {err_r:.3g}; top-1 "
-          f"agreement fused/f32 {top1(fused, exact):.3f}, reference/f32 "
-          f"{top1(reference, exact):.3f}, fused/reference "
+          f"rel rms error {label} {err_f:.3g}, reference {err_r:.3g}; top-1 "
+          f"agreement {label}/f32 {top1(fused, exact):.3f}, reference/f32 "
+          f"{top1(reference, exact):.3f}, {label}/reference "
           f"{top1(fused, reference):.3f}", flush=True)
     check(np.isfinite(fused).all()
           and err_f <= max(LOGIT_ERR_RATIO * err_r, LOGIT_ERR_FLOOR),
-          f"fused logits no further from float32 than {LOGIT_ERR_RATIO}x "
+          f"{label} logits no further from float32 than {LOGIT_ERR_RATIO}x "
           f"the reference's (or {LOGIT_ERR_FLOOR})")
 
 
@@ -316,7 +395,8 @@ def four_chips(clock: CompileClock) -> None:
     eng = Engine.from_config(
         engine_config(parallel=ParallelConfig.tensor(4)), model, weights)
     sched = eng.scheduler
-    print(f"resolved: mesh={dict(sched.mesh.shape)} "
+    mesh = sched.mesh
+    print(f"resolved: mesh={dict(mesh.shape)} "
           f"attention={sched.attention} drain_kernel={sched._drain_kernel}",
           flush=True)
     check(sched.attention == "fused" and sched._drain_kernel is True,
@@ -325,6 +405,10 @@ def four_chips(clock: CompileClock) -> None:
     check(eng.stats["drains"] > 0, "the staging ring drained inside the scan")
     print(f"tp=4 vs tp=1 greedy tokens: {agreement(sharded, single)}",
           flush=True)
+    del eng, sched
+    kernel_checks(model.cfg, mesh)
+    drain_check(model.cfg, mesh)
+    logit_check(model, weights, mesh)
     print(f"compile seconds (all phases): {clock.seconds:.1f}", flush=True)
     peak_bytes(jax.devices()[:4])
 

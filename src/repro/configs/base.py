@@ -100,7 +100,7 @@ class ModelConfig:
 
     # ---- numerics ----
     dtype: str = "bfloat16"        # activation/compute dtype
-    param_dtype: str = "float32"   # master parameter dtype
+    param_dtype: str = "float32"   # dtype model.init stores parameters in
 
     # ------------------------------------------------------------------
     # Derived properties

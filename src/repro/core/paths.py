@@ -268,6 +268,27 @@ def resolve_attention(attention: str = "auto", *,
     return ATTN_FUSED if backend != "cpu" else ATTN_REFERENCE
 
 
+def resolve_drain_kernel(drain_kernel: Optional[bool] = None, *,
+                         backend: Optional[str] = None) -> bool:
+    """Whether ``kvcache.paged.drain_ring`` runs the ``staged_scatter``
+    kernel. The paged pool always meets the kernel's preconditions
+    (full-row entries, drain-unique destinations), so ``None`` selects it
+    wherever it is the fast path: any backend but the CPU. There the jnp
+    scatter is faster; ``REPRO_DRAIN_KERNEL=1`` forces the kernel anyway
+    (interpret mode) so CPU CI serves through the real drain kernel, and
+    ``REPRO_DRAIN_KERNEL=0`` forces the jnp scatter everywhere."""
+    if drain_kernel is not None:
+        return drain_kernel
+    env = os.environ.get("REPRO_DRAIN_KERNEL")
+    if env is not None:
+        return env not in ("", "0")
+    if backend is None:
+        import jax
+
+        backend = jax.default_backend()
+    return backend != "cpu"
+
+
 # ---------------------------------------------------------------------------
 # grouped sub-configs + the single validate() entry point (DESIGN.md §9)
 # ---------------------------------------------------------------------------
@@ -297,7 +318,7 @@ class AttentionConfig:
     ``attention`` string (``auto`` | ``fused`` | ``reference``, resolved
     through :func:`resolve_attention`), ``drain_kernel`` the old flat
     drain-kernel tri-state (``None`` = auto-select, see
-    ``kvcache.paged._auto_drain_kernel``)."""
+    :func:`resolve_drain_kernel`)."""
 
     impl: str = "auto"
     drain_kernel: Optional[bool] = None
@@ -375,7 +396,9 @@ def resolve_draft(spec: SpecConfig, *, vocab: Optional[int] = None,
             f"positional and recurrent cache state, which the engine "
             f"cannot roll back on rejection; supported draft families: "
             f"dense/moe (positional rewind) and ssm (state snapshot)")
-    return dcfg
+    # served like the target: parameters stored in the compute dtype
+    # (``serve.api.build_model_and_params``)
+    return dataclasses.replace(dcfg, param_dtype=dcfg.dtype)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -387,7 +410,7 @@ class ResolvedPlan:
     attention     "fused" | "reference" (the ``auto`` resolution applied)
     drain_kernel  whether ``kvcache.paged.drain_ring`` runs the
                   ``staged_scatter`` kernel (``None`` in the config is
-                  resolved here by ``kvcache.paged._auto_drain_kernel``)
+                  resolved here by :func:`resolve_drain_kernel`)
     path/decision the negotiated WritePath + assembled DecisionModule
     sched         the resolved AdmissionPolicy instance (admission-order
                   plane; ``serve.traffic.sched`` registry)
@@ -590,14 +613,10 @@ def validate(cfg, *, paged_capable: bool = True,
         path_name, getattr(cfg, "policy", None), n_regions=n_regions,
         hot_threshold=getattr(cfg, "hot_threshold", 4), layout=layout,
         chunked=chunked)
-    drain_kernel = attn_cfg.drain_kernel
-    if drain_kernel is None:
-        from ..kvcache.paged import _auto_drain_kernel
-
-        drain_kernel = _auto_drain_kernel()
     return ResolvedPlan(
         layout=layout, attention=attention,
-        drain_kernel=drain_kernel, path=path, decision=decision,
+        drain_kernel=resolve_drain_kernel(attn_cfg.drain_kernel,
+                                          backend=backend), path=path, decision=decision,
         sched=sched, memory=memory, parallel=parallel, mesh=mesh,
         n_blocks=n_blocks, max_pages=max_pages, n_regions=n_regions,
         spec=spec, draft_cfg=draft_cfg)
@@ -638,7 +657,8 @@ __all__ = [
     "ATTN_FUSED", "ATTN_REFERENCE",
     "WritePath", "register_path", "get_path", "available_paths",
     "DIRECT", "STAGED", "ADAPTIVE",
-    "negotiate", "negotiate_memory", "resolve_attention", "build_decision",
+    "negotiate", "negotiate_memory", "resolve_attention",
+    "resolve_drain_kernel", "build_decision",
     "ParallelConfig", "MemoryConfig", "AttentionConfig", "SpecConfig",
     "resolve_draft", "ResolvedPlan", "validate", "normalize_groups",
 ]

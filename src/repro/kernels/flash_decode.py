@@ -78,75 +78,52 @@ def _paged_kernel(
     *rest,                 # (+ ring refs) then o_ref, then scratch
     n_pages, scale, ring,
 ):
-    """Grid (B, 2, P): two sweeps of one slot's page table.
+    """Grid (B, P): one walk of one slot's page table.
 
     Each step reads one physical block straight out of the pool (the
     BlockSpecs index the pool through the scalar-prefetched table — no
-    gathered copy ever lands in HBM) and scores it for every head at once.
-    Sweep 0 folds the scores into the per-row softmax max and denominator;
-    sweep 1 recomputes them, normalizes, and accumulates the weighted
-    values. The staging-ring lanes join both sweeps at the last page as a
-    second KV source. Normalizing before the value product keeps the jnp
-    reference's op ORDER (fp32 logits -> mask -> softmax -> cast -> weighted
-    sum), so fused and reference outputs agree to fp32 ulp precision and
-    emit identical greedy tokens (DESIGN.md §7). Nothing in VMEM grows with
-    the context length.
+    gathered copy ever lands in HBM), scores it for every head at once
+    and folds it into an online softmax: running max, denominator and
+    unnormalized weighted sum, rescaled as the max grows. The staging-ring
+    lanes join at the last page as a second KV source; the output is the
+    weighted sum over the denominator. Nothing in VMEM grows with the
+    context length.
     """
     if ring:
         rk_ref, rv_ref, rm_ref, o_ref, mx_ref, l_ref, acc_ref = rest
     else:
         o_ref, mx_ref, l_ref, acc_ref = rest
-    sweep = pl.program_id(1)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     q = q_ref[0]             # [Hkv, GC, D]
 
-    def scores(k, ok):       # k [T, Hkv, D], ok [GC|1, T] -> [Hkv, GC, T]
-        s = jnp.einsum("hcd,thd->hct", q, k,
-                       preferred_element_type=jnp.float32)
-        s = s.astype(q.dtype).astype(jnp.float32) * scale
-        return jnp.where(ok[None], s, _NEG_INF)
-
-    def fold_stats(s):       # online max + softmax denominator
-        m_prev = mx_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        l_ref[...] = (l_ref[...] * jnp.exp(m_prev - m_new)
-                      + jnp.sum(jnp.exp(s - m_new), axis=-1, keepdims=True))
-        mx_ref[...] = m_new
-
-    def fold_values(s, v):   # normalized probs, then the weighted sum
-        p = (jnp.exp(s - mx_ref[...]) / l_ref[...]).astype(v.dtype)
-        acc_ref[...] += jnp.einsum("hct,thd->hcd", p, v,
-                                   preferred_element_type=jnp.float32)
-
-    def ring_scores():
-        return scores(rk_ref[0], rm_ref[0] != 0)
-
-    last = j == n_pages - 1
-    s = scores(k_ref[0], m_ref[0, 0] != 0)
-
-    @pl.when((sweep == 0) & (j == 0))
+    @pl.when(j == 0)
     def _init():
         mx_ref[...] = jnp.full_like(mx_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(sweep == 0)
-    def _stats():
-        fold_stats(s)
+    def fold(k, v, ok):      # k, v [T, Hkv, D], ok [GC|1, T]
+        s = jnp.einsum("hcd,thd->hct", q, k,
+                       preferred_element_type=jnp.float32)
+        s = s.astype(q.dtype).astype(jnp.float32) * scale
+        s = jnp.where(ok[None], s, _NEG_INF)
+        m_prev = mx_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.einsum(
+            "hct,thd->hcd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        mx_ref[...] = m_new
+
+    fold(k_ref[0], v_ref[0], m_ref[0, 0] != 0)
+
+    @pl.when(j == n_pages - 1)
+    def _finalize():
         if ring:
-            @pl.when(last)
-            def _ring_stats():
-                fold_stats(ring_scores())
-
-    @pl.when(sweep == 1)
-    def _values():
-        fold_values(s, v_ref[0])
-
-        @pl.when(last)
-        def _finalize():
-            if ring:
-                fold_values(ring_scores(), rv_ref[0])
-            o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+            fold(rk_ref[0], rv_ref[0], rm_ref[0] != 0)
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def flash_decode_paged(
@@ -194,23 +171,20 @@ def flash_decode_paged(
     mask = jnp.tile(mask, (1, 1, group, 1))            # [B, P, G*C, ps]
 
     kv_spec = pl.BlockSpec((1, ps, hkv, d),
-                           lambda b_, s, j, tab: (tab[b_, j], 0, 0, 0))
+                           lambda b_, j, tab: (tab[b_, j], 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, hkv, gc, d), lambda b_, s, j, tab: (b_, 0, 0, 0)),
+        pl.BlockSpec((1, hkv, gc, d), lambda b_, j, tab: (b_, 0, 0, 0)),
         kv_spec,
-        # values are only read by sweep 1; sweep 0 parks on the first
-        # page so the pipeline fetches it once for both sweeps
-        pl.BlockSpec((1, ps, hkv, d),
-                     lambda b_, s, j, tab: (tab[b_, j * s], 0, 0, 0)),
-        pl.BlockSpec((1, 1, gc, ps), lambda b_, s, j, tab: (b_, j, 0, 0)),
+        kv_spec,
+        pl.BlockSpec((1, 1, gc, ps), lambda b_, j, tab: (b_, j, 0, 0)),
     ]
     args = [qg, pages_k, pages_v, mask]
     if ring:
         lane_spec = pl.BlockSpec((1, r, hkv, d),
-                                 lambda b_, s, j, tab: (b_, 0, 0, 0))
+                                 lambda b_, j, tab: (b_, 0, 0, 0))
         in_specs += [
             lane_spec, lane_spec,
-            pl.BlockSpec((1, 1, r), lambda b_, s, j, tab: (b_, 0, 0)),
+            pl.BlockSpec((1, 1, r), lambda b_, j, tab: (b_, 0, 0)),
         ]
         args += [ring_k, ring_v, ring_ok.astype(jnp.int32).reshape(b, 1, r)]
 
@@ -220,10 +194,10 @@ def flash_decode_paged(
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, 2, n_pages),
+            grid=(b, n_pages),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (1, hkv, gc, d), lambda b_, s, j, tab: (b_, 0, 0, 0)),
+                (1, hkv, gc, d), lambda b_, j, tab: (b_, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((hkv, gc, 1), jnp.float32),   # running max
                 pltpu.VMEM((hkv, gc, 1), jnp.float32),   # denominator

@@ -37,7 +37,6 @@ universe, exactly the mixed write stream the paper's monitor sees.
 from __future__ import annotations
 
 import collections
-import os
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -689,24 +688,7 @@ def overlay_chunk(
     return jnp.concatenate([view_ok, ring_ok], axis=2), cur
 
 
-def _auto_drain_kernel() -> bool:
-    """Default kernel selection for :func:`drain_ring`.
-
-    The paged pool layout ALWAYS satisfies the ``staged_scatter``
-    preconditions (full-row entries, drain-unique destinations), so the
-    kernel is selected automatically wherever it is the fast path: any
-    non-CPU backend. On CPU the jnp oracle is the fast path, but setting
-    ``REPRO_DRAIN_KERNEL=1`` forces the kernel (interpret mode) so CI's
-    CPU serving jobs exercise the real drain kernel end to end;
-    ``REPRO_DRAIN_KERNEL=0`` forces the oracle everywhere.
-    """
-    env = os.environ.get("REPRO_DRAIN_KERNEL")
-    if env is not None:
-        return env not in ("", "0")
-    return jax.default_backend() != "cpu"
-
-
-def drain_ring(cache: PagedKV, use_kernel: Optional[bool] = None,
+def drain_ring(cache: PagedKV, use_kernel: bool,
                shardings=None) -> PagedKV:
     """Bulk-copy all staged entries into the pool, empty the ring.
 
@@ -714,9 +696,9 @@ def drain_ring(cache: PagedKV, use_kernel: Optional[bool] = None,
     merge_lanes``) and land with a single ``scatter_rows`` call — block
     ownership makes destinations unique across slots, conflict-forced
     drains make them unique within a slot (the ``staged_scatter``
-    precondition). ``use_kernel=None`` (the default) selects the kernel
-    automatically (:func:`_auto_drain_kernel`) — callers no longer have to
-    opt in for serving to exercise the drain kernel.
+    precondition). ``use_kernel`` picks the ``staged_scatter`` kernel or
+    the jnp scatter; serving takes it from its plan
+    (``core.paths.resolve_drain_kernel``).
 
     Mesh-sharded serving: each layer's scatter works on ``[rows,
     H * Dh]`` views whose ROW axis (physical pool row / ring entry) is
@@ -730,8 +712,6 @@ def drain_ring(cache: PagedKV, use_kernel: Optional[bool] = None,
     explicitly (``shard_map`` over the cache ``shardings``, the serve
     scheduler's placement dict), since a Mosaic kernel is never
     partitioned by the compiler."""
-    if use_kernel is None:
-        use_kernel = _auto_drain_kernel()
     # resolve logical -> physical per ring column, then flatten lanes
     phys = jax.vmap(lambda rows: logical_to_physical(cache, rows),
                     in_axes=1, out_axes=1)(cache["ring_pos"])
@@ -770,7 +750,7 @@ def drain_ring(cache: PagedKV, use_kernel: Optional[bool] = None,
 
 def maybe_drain(
     cache: PagedKV,
-    use_kernel: Optional[bool] = None,
+    use_kernel: bool,
     incoming_pos: Optional[jnp.ndarray] = None,
     shardings=None,
 ) -> Tuple[PagedKV, jnp.ndarray]:

@@ -1,7 +1,7 @@
 """Device facts shared by the launcher, the benchmarks and ``chip_smoke.py``.
 
 * :data:`PEAKS` — published per-chip peaks, keyed by the ``device_kind``
-  JAX reports. A device that is not in the table is an error, never a
+  JAX reports. A chip that is not in the table is a ``KeyError``, never a
   default: a roofline share computed against the wrong chip is wrong.
 * :func:`enable_compile_cache` — JAX's persistent compilation cache at a
   place that can be set from outside (``JAX_COMPILATION_CACHE_DIR``) and
@@ -36,17 +36,6 @@ PEAKS = {
 }
 
 V5E = "TPU v5 lite"
-
-
-def device_peaks(kind: str) -> Peaks:
-    """Peaks of the chip JAX calls ``kind`` (``device.device_kind``)."""
-    try:
-        return PEAKS[kind]
-    except KeyError:
-        raise ValueError(
-            f"no published peaks for device_kind {kind!r} "
-            f"(known: {sorted(PEAKS)}); add the chip to PEAKS with its "
-            f"source") from None
 
 
 def enable_compile_cache() -> str:

@@ -1,7 +1,8 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Hardware model: the target chip's published peaks (``launch.device.PEAKS``,
-TPU v5e by default: 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s per ICI link).
+Hardware model: the dry run's target chip, TPU v5e, at its published peaks
+(``launch.device.PEAKS``: 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s per ICI
+link).
 Per (arch x shape) on the single-pod mesh we derive:
 
     compute_s    = FLOPs_per_device / peak FLOP/s
@@ -37,7 +38,7 @@ import jax
 
 from ..configs.base import ModelConfig, ShapeSpec
 from . import cells as C
-from .device import V5E, device_peaks
+from .device import PEAKS, V5E
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -201,8 +202,8 @@ def probe_cell(
 
 
 def roofline_terms(metrics: Dict[str, float], cfg: ModelConfig,
-                   shape: ShapeSpec, device_kind: str = V5E) -> Dict[str, Any]:
-    peaks = device_peaks(device_kind)
+                   shape: ShapeSpec) -> Dict[str, Any]:
+    peaks = PEAKS[V5E]
     compute_s = metrics["flops"] / peaks.flops_bf16
     memory_s = metrics["bytes"] / peaks.hbm_bw
     coll_s = metrics["coll_bytes"] / peaks.ici_link_bw

@@ -28,6 +28,15 @@ from ..configs.base import GELU, LAYERNORM, RMSNORM, SQUARED_RELU, SWIGLU, Model
 # ---------------------------------------------------------------------------
 
 
+def as_param_dtype(cfg: ModelConfig, params):
+    """Every float leaf in ``cfg.param_dtype``, the dtype parameters are
+    stored in; each use casts to what it computes in."""
+    dtype = jnp.dtype(cfg.param_dtype)
+    return jax.tree.map(
+        lambda x: (x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating)
+                   else x), params)
+
+
 def init_norm(cfg: ModelConfig, d: Optional[int] = None) -> dict:
     d = d or cfg.d_model
     p = {"scale": jnp.ones((d,), jnp.float32)}

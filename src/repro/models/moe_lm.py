@@ -36,11 +36,11 @@ class MoELM:
     def init(self, key: jax.Array, max_seq: int = 0) -> Params:
         cfg = self.cfg
         k_emb, k_blocks = jax.random.split(key)
-        return {
+        return L.as_param_dtype(cfg, {
             "embed": L.init_embed(cfg, k_emb),
             "blocks": stack_init(partial(MOE.init_moe_block, cfg), k_blocks, cfg.n_layers),
             "ln_f": L.init_norm(cfg),
-        }
+        })
 
     # -- full forward --------------------------------------------------------
     def forward_with_stats(

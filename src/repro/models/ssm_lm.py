@@ -43,11 +43,11 @@ class MambaLM:
     def init(self, key: jax.Array, max_seq: int = 0) -> Params:
         cfg = self.cfg
         k_emb, k_blocks = jax.random.split(key)
-        return {
+        return L.as_param_dtype(cfg, {
             "embed": L.init_embed(cfg, k_emb),
             "blocks": stack_init(partial(M.init_mamba_block, cfg), k_blocks, cfg.n_layers),
             "ln_f": L.init_norm(cfg),
-        }
+        })
 
     def forward(self, params, tokens, remat: bool = False):
         cfg = self.cfg
@@ -140,12 +140,12 @@ class ZambaLM:
     def init(self, key: jax.Array, max_seq: int = 0) -> Params:
         cfg = self.cfg
         k_emb, k_blocks, k_shared = jax.random.split(key, 3)
-        return {
+        return L.as_param_dtype(cfg, {
             "embed": L.init_embed(cfg, k_emb),
             "blocks": stack_init(partial(M.init_mamba_block, cfg), k_blocks, cfg.n_layers),
             "shared": init_dense_block(cfg, k_shared),  # ONE shared block
             "ln_f": L.init_norm(cfg),
-        }
+        })
 
     def _grouped(self, params):
         return jax.tree.map(

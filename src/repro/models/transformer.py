@@ -164,7 +164,7 @@ class DecoderLM:
             params["blocks"] = stack_init(
                 partial(init_dense_block, cfg), k_blocks, cfg.n_layers
             )
-        return params
+        return L.as_param_dtype(cfg, params)
 
     # -- full forward (train / prefill) -----------------------------------
     def _trunk(

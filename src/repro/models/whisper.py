@@ -67,7 +67,7 @@ class WhisperModel:
         cfg = self.cfg
         ks = jax.random.split(key, 5)
         n_pos = max(cfg.max_position, max_seq)
-        return {
+        return L.as_param_dtype(cfg, {
             "embed": L.init_embed(cfg, ks[0]),
             "enc_pos": (jax.random.normal(ks[1], (cfg.n_audio_frames, cfg.d_model)) * 0.01
                         ).astype(jnp.float32),
@@ -77,7 +77,7 @@ class WhisperModel:
             "ln_enc": L.init_norm(cfg),
             "dec_blocks": stack_init(partial(init_decoder_block, cfg), ks[4], cfg.n_layers),
             "ln_f": L.init_norm(cfg),
-        }
+        })
 
     # -- encoder -----------------------------------------------------------
     def encode(self, params: Params, frames: jnp.ndarray, remat: bool = False):

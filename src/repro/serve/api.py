@@ -23,7 +23,6 @@ import dataclasses
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Union
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..core.paths import (AttentionConfig, MemoryConfig, ParallelConfig,
@@ -49,27 +48,22 @@ def build_model_and_params(arch: str, max_seq: int, *, seed: int = 0,
     """(cfg, model, params) for a registered architecture — the one
     model-construction block the examples/benchmarks/CLIs share.
 
-    Random weights from ``seed``. Matrices are held in the compute dtype
-    (``cfg.dtype``): the dense decoder casts every matrix to it before
-    use, so this changes no result and halves the bytes of float32
-    masters (a published-width stablelm-1.6b fits one 16 GB chip only this
-    way). Vectors (norm scales, biases) keep their dtype."""
+    Random weights from ``seed``. Serving keeps no float32 masters: the
+    config's ``param_dtype`` is set to its compute dtype, as a served
+    bf16 checkpoint is, so every parameter (the MoE router and the SSM
+    conv included) is stored rounded to it. That halves the bytes of
+    float32 masters: a published-width stablelm-1.6b fits one 16 GB chip
+    only this way. Reduced configs compute in float32, so for them
+    nothing changes."""
     from ..configs import get_config
     from ..models import build_model
 
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
+    cfg = dataclasses.replace(cfg, param_dtype=cfg.dtype)
     model = build_model(cfg)
-    params = model.init(jax.random.key(seed), max_seq)
-    dtype = jnp.dtype(cfg.dtype)
-
-    def serving(x):
-        if x.ndim >= 2 and jnp.issubdtype(x.dtype, jnp.floating):
-            return x.astype(dtype)
-        return x
-
-    return cfg, model, jax.tree.map(serving, params)
+    return cfg, model, model.init(jax.random.key(seed), max_seq)
 
 
 @dataclasses.dataclass

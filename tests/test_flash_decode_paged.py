@@ -2,18 +2,19 @@
 
 Parity contract (DESIGN.md §7), tested at two boundaries:
 
-* KERNEL boundary: the Pallas kernel (interpret mode on CPU) keeps the
-  jnp reference's op ORDER (logits -> mask -> softmax -> cast -> weighted
-  sum) and is held to fp32 ulp-level equality (~1e-7 abs; tolerance
-  carries 10x margin) — swept across ring states (empty / partial /
+* KERNEL boundary: the Pallas kernel (interpret mode on CPU) computes
+  the jnp reference's terms (logits -> mask -> softmax -> weighted sum)
+  as an online softmax and is held to fp32 ulp-level equality (~1e-7
+  abs; tolerance carries 10x margin) — swept across ring states (empty / partial /
   full / wrapped / conflict-shaped), chunk sizes, GQA group sizes, and
   page geometries, and cross-checked against the REAL reference core
   (``gather_view`` + ring concat + ``layers`` sdpa math, which IS
   bitwise-equal to the packaged oracle) so the oracle can't drift into a
   strawman. Bit-identity is not achievable: the kernel sums the softmax
   denominator page by page with running-max rescaling, and the weighted
-  values page by page, where the reference reduces each in one pass —
-  the same terms in another fp32 association, hence ulps.
+  values page by page before dividing, where the reference normalizes
+  first and reduces each in one pass — the same terms in another fp32
+  association, hence ulps.
 * ENGINE boundary: fused vs reference serving produces IDENTICAL token
   streams across every paged-layout arch in the config matrix × write
   modes (direct / staged / adaptive) × chunked scheduling — ulp noise
@@ -21,7 +22,8 @@ Parity contract (DESIGN.md §7), tested at two boundaries:
   contract serving actually needs.
 
 Also here: ``core.paths.resolve_attention`` negotiation and the
-``drain_ring`` automatic kernel selection (its own parity included).
+drain-kernel selection (``core.paths.resolve_drain_kernel``, its own
+parity included).
 """
 import jax
 import jax.numpy as jnp
@@ -29,7 +31,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS, get_config
-from repro.core.paths import resolve_attention
+from repro.core.paths import resolve_attention, resolve_drain_kernel
 from repro.data import synthetic_requests
 from repro.kernels import ref
 from repro.kernels.flash_decode import flash_decode_paged
@@ -357,16 +359,19 @@ def test_engine_resolves_attention(monkeypatch):
 
 
 def test_drain_kernel_auto_selection(monkeypatch):
-    """Satellite: drain_ring(use_kernel=None) picks the kernel wherever the
-    layout supports it without callers opting in — REPRO_DRAIN_KERNEL=1
-    routes CPU CI through the interpret kernel, and the result is bitwise
-    the jnp drain."""
+    """The plan picks the drain kernel wherever the layout supports it
+    without callers opting in — REPRO_DRAIN_KERNEL=1 routes CPU CI
+    through the interpret kernel, and the result is bitwise the jnp
+    drain."""
     monkeypatch.delenv("REPRO_DRAIN_KERNEL", raising=False)
-    assert PG._auto_drain_kernel() is (jax.default_backend() != "cpu")
+    assert resolve_drain_kernel() is (jax.default_backend() != "cpu")
+    assert resolve_drain_kernel(backend="tpu") is True
+    assert resolve_drain_kernel(backend="cpu") is False
+    assert resolve_drain_kernel(False, backend="tpu") is False
     monkeypatch.setenv("REPRO_DRAIN_KERNEL", "1")
-    assert PG._auto_drain_kernel() is True
+    assert resolve_drain_kernel() is True
     monkeypatch.setenv("REPRO_DRAIN_KERNEL", "0")
-    assert PG._auto_drain_kernel() is False
+    assert resolve_drain_kernel(backend="tpu") is False
 
     cfg = get_config("stablelm-1.6b").reduced()
     model = build_model(cfg)
@@ -374,7 +379,7 @@ def test_drain_kernel_auto_selection(monkeypatch):
     cache = _paged_cache_with_ring(model, rng)
     plain = PG.drain_ring(dict(cache), use_kernel=False)
     monkeypatch.setenv("REPRO_DRAIN_KERNEL", "1")
-    auto = PG.drain_ring(dict(cache))  # auto -> interpret kernel on CPU
+    auto = PG.drain_ring(dict(cache), use_kernel=resolve_drain_kernel())
     for key in plain:
         np.testing.assert_array_equal(np.asarray(plain[key]),
                                       np.asarray(auto[key]), err_msg=key)

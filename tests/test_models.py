@@ -52,6 +52,29 @@ def test_smoke_forward_shapes_and_finiteness(arch):
 
 
 @pytest.mark.parametrize("arch", ALL_ARCHS)
+def test_init_stores_params_in_param_dtype(arch):
+    """``param_dtype`` decides the dtype every float parameter is stored
+    in (serving sets it to the compute dtype); the model runs on them."""
+    cfg = dataclasses.replace(get_config(arch).reduced(), dtype="bfloat16",
+                              param_dtype="bfloat16")
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0), 64)
+    floats = [x for x in jax.tree.leaves(params)
+              if jnp.issubdtype(x.dtype, jnp.floating)]
+    assert floats and all(x.dtype == jnp.bfloat16 for x in floats)
+    tokens = jax.random.randint(jax.random.key(1), (2, 16), 0, cfg.vocab)
+    if needs_media(cfg):
+        media = jax.random.normal(jax.random.key(2),
+                                  media_spec(cfg, 2, jnp.bfloat16).shape,
+                                  jnp.bfloat16)
+        logits = model.forward(params, tokens, media)
+    else:
+        logits = model.forward(params, tokens)
+    assert logits.shape == (2, 16, cfg.vocab)
+    assert bool(jnp.all(jnp.isfinite(logits)))
+
+
+@pytest.mark.parametrize("arch", ALL_ARCHS)
 def test_smoke_one_train_step(arch):
     cfg, model, params, tokens, media = _setup(arch)
     opt = AdamW(lr=1e-3)

@@ -89,17 +89,23 @@ def test_sharded_bit_parity_indivisible_heads_fall_back_replicated():
 
 
 @needs_mesh
-def test_sharded_bit_parity_fused_attention():
+@pytest.mark.parametrize("drain_kernel", [False, True])
+def test_sharded_bit_parity_fused_attention(drain_kernel):
     # the head-sharded fused read path: Hq=Hkv=4 divide tp=2, so validate
     # admits attention='fused' and the kernel (interpret mode on CPU)
-    # must reproduce the reference engine's tokens bit-for-bit
+    # must reproduce the reference engine's tokens bit-for-bit; with the
+    # drain kernel the staging ring drains per shard (shard_map) inside
+    # the scan (ring_size 4 < segment_len 8)
     from repro.serve import AttentionConfig
 
     tp = min(2, jax.device_count())
-    attn = AttentionConfig(impl="fused")
-    ref = _payload(*_serve("stablelm-1.6b", 1, attention=attn, max_tokens=6))
-    got = _payload(*_serve("stablelm-1.6b", tp, attention=attn, max_tokens=6))
-    assert got == ref
+    attn = AttentionConfig(impl="fused", drain_kernel=drain_kernel)
+    kw = dict(attention=attn, path="staged", segment_len=8, max_tokens=6)
+    ref_eng, ref_comps = _serve("stablelm-1.6b", 1, **kw)
+    eng, comps = _serve("stablelm-1.6b", tp, **kw)
+    assert eng.scheduler._drain_kernel is drain_kernel
+    assert eng.stats["drains"] > 0
+    assert _payload(eng, comps) == _payload(ref_eng, ref_comps)
 
 
 @needs_mesh
@@ -194,6 +200,44 @@ def test_per_shard_drain_volumes_sum_to_unsharded():
     shard_vols = [np.prod(sh.data.shape) for sh in ring.addressable_shards]
     assert sum(shard_vols) == per_drain_unsharded
     assert all(v == per_drain_unsharded // tp for v in shard_vols)
+
+
+@needs_mesh
+def test_sharded_drain_kernel_matches_jnp_drain():
+    # the drain kernel run per head shard (shard_map over the serving
+    # placements) lands every staged row where the unsharded jnp
+    # scatter does: a copy, so bit-exact
+    from repro.distributed.sharding import serve_cache_shardings
+    from repro.kvcache import paged as PG
+
+    tp = min(4, jax.device_count())
+    mesh = ParallelConfig.tensor(tp).build_mesh()
+    cfg = get_config("stablelm-1.6b").reduced()
+    n_slots, pages, ps, ring = 4, 3, 4, 4
+    rng = np.random.default_rng(0)
+    cache = PG.make_paged_kv(cfg.n_layers, n_slots * pages, ps, n_slots,
+                             pages, cfg.n_kv_heads, cfg.resolved_head_dim,
+                             ring_size=ring)
+    for key in ("pages_k", "pages_v", "ring_k", "ring_v"):
+        cache[key] = rng.standard_normal(cache[key].shape, np.float32)
+    cache["page_table"] = rng.permutation(n_slots * pages).reshape(
+        n_slots, pages).astype(np.int32)
+    pos = np.stack([rng.permutation(pages * ps)[:ring]
+                    for _ in range(n_slots)])
+    cache["ring_pos"] = np.where(rng.random(pos.shape) < 0.7, pos,
+                                 -1).astype(np.int32)
+    cache["ring_fill"] = np.int32(ring)
+    cache = {k: jax.numpy.asarray(v) for k, v in cache.items()}
+    want = PG.drain_ring(dict(cache), use_kernel=False)
+    shardings = serve_cache_shardings(cfg, mesh, cache)
+    got = jax.jit(lambda c: PG.drain_ring(c, use_kernel=True,
+                                          shardings=shardings))(
+        {k: jax.device_put(v, shardings[k]) for k, v in cache.items()})
+    assert got["pages_k"].sharding.is_equivalent_to(
+        shardings["pages_k"], got["pages_k"].ndim)
+    for key in want:
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(want[key]), err_msg=key)
 
 
 @needs_mesh

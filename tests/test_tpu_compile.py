@@ -121,3 +121,29 @@ def test_staged_scatter_compiles_for_v5e(one_chip, layered):
     else:
         fn = drain
     _assert_kernel_compiles(fn, args)
+
+
+def test_drain_ring_sharded_compiles_for_v5e_2x2(topo, monkeypatch):
+    """The ring drain over a head-sharded pool on four chips, as serving
+    places it (``serve_cache_shardings``): one ``staged_scatter`` per
+    shard, every layer at once."""
+    from repro.configs import get_config
+    from repro.distributed.sharding import serve_cache_shardings
+    from repro.kernels import ops
+    from repro.kvcache import paged as PG
+
+    # the kernel wrapper picks interpret mode on a CPU backend; the
+    # compile here is for the described chip
+    monkeypatch.setattr(ops, "_on_cpu", lambda: False)
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("data", "model"))
+    cache = jax.eval_shape(lambda: PG.make_paged_kv(
+        LAYERS, N_BLOCKS, PAGE, B, PAGES, HKV, D, dtype=jnp.bfloat16,
+        ring_size=RING))
+    shardings = serve_cache_shardings(get_config("stablelm-1.6b"), mesh,
+                                      cache)
+    assert shardings["pages_k"].spec[3] == "model"
+    args = {k: _shape(v.shape, v.dtype, shardings[k])
+            for k, v in cache.items()}
+    _assert_kernel_compiles(
+        lambda c: PG.drain_ring(c, use_kernel=True, shardings=shardings),
+        [args])
