@@ -460,6 +460,7 @@ def kernel_blocks(cache: PagedKV) -> jnp.ndarray:
     return jnp.maximum(cache["page_table"], 0).astype(jnp.int32)
 
 
+@jax.named_scope("kv_view")
 def step_plan(cache: PagedKV) -> StepPlan:
     """Build the per-segment :class:`StepPlan` (see its docstring)."""
     ps = cache["pages_k"].shape[2]
@@ -688,6 +689,7 @@ def overlay_chunk(
     return jnp.concatenate([view_ok, ring_ok], axis=2), cur
 
 
+@jax.named_scope("kv_drain")
 def drain_ring(cache: PagedKV, use_kernel: bool,
                shardings=None) -> PagedKV:
     """Bulk-copy all staged entries into the pool, empty the ring.

@@ -450,27 +450,30 @@ class DecoderLM:
             )
         fused = attention == "fused"
         dtype = jnp.dtype(cfg.dtype)
-        x = L.embed_tokens(cfg, params["embed"], tokens[:, None], dtype)
+        with jax.named_scope("head"):
+            x = L.embed_tokens(cfg, params["embed"], tokens[:, None], dtype)
         ring = PG.has_ring(cache)
-        if plan is None:
-            plan = PG.step_plan(cache)
-        vmask = PG.view_mask_from(plan.allocated, pos)
-        view_ids = plan.view_ids
-        if ring:
-            if unload_mask is None:
-                unload_mask = jnp.ones_like(write_mask)
-            unload_mask = unload_mask & write_mask
-            view_ok, ring_ok, cur = PG.overlay_step_parts(
-                cache, vmask, pos, unload_mask)
-            full_mask = jnp.concatenate([view_ok, ring_ok], axis=1)
-            direct = write_mask & ~unload_mask
-        else:
-            view_ok = full_mask = vmask
-            ring_ok = None
-            direct = write_mask
+        with jax.named_scope("kv_view"):
+            if plan is None:
+                plan = PG.step_plan(cache)
+            vmask = PG.view_mask_from(plan.allocated, pos)
+            view_ids = plan.view_ids
+            if ring:
+                if unload_mask is None:
+                    unload_mask = jnp.ones_like(write_mask)
+                unload_mask = unload_mask & write_mask
+                view_ok, ring_ok, cur = PG.overlay_step_parts(
+                    cache, vmask, pos, unload_mask)
+                full_mask = jnp.concatenate([view_ok, ring_ok], axis=1)
+                direct = write_mask & ~unload_mask
+            else:
+                view_ok = full_mask = vmask
+                ring_ok = None
+                direct = write_mask
         # physical destination for the direct subset; sentinel (-1 logical
         # -> out-of-range physical) DROPS staged and dead slots
-        dest = PG.logical_to_physical(cache, jnp.where(direct, pos, -1))
+        with jax.named_scope("kv_write"):
+            dest = PG.logical_to_physical(cache, jnp.where(direct, pos, -1))
 
         def self_body(carry, xs):
             h = carry
@@ -478,29 +481,36 @@ class DecoderLM:
                 p, pk, pv, rk, rv = xs
             else:
                 p, pk, pv = xs
-            hn = L.apply_norm(cfg, p["ln1"], h)
-            k_new, v_new = L.project_kv(cfg, p["attn"], hn, pos[:, None])
-            pk = PG.scatter_token(pk, dest, k_new[:, 0])
-            pv = PG.scatter_token(pv, dest, v_new[:, 0])
-            if ring:
-                rk = PG.stage_tile(rk, k_new[:, 0], cur)
-                rv = PG.stage_tile(rv, v_new[:, 0], cur)
-            if fused:
-                a = L.fused_paged_attention(
-                    cfg, p["attn"], hn, pos[:, None], pk, pv,
-                    plan.blocks, view_ok[:, None, :],
-                    rk if ring else None, rv if ring else None, ring_ok,
-                    mesh=mesh)
-            else:
-                ak = PG.gather_view(pk, view_ids)
-                av = PG.gather_view(pv, view_ids)
+            with jax.named_scope("attention"):
+                hn = L.apply_norm(cfg, p["ln1"], h)
+                k_new, v_new = L.project_kv(cfg, p["attn"], hn, pos[:, None])
+            with jax.named_scope("kv_write"):
+                pk = PG.scatter_token(pk, dest, k_new[:, 0])
+                pv = PG.scatter_token(pv, dest, v_new[:, 0])
                 if ring:
-                    ak = jnp.concatenate([ak, rk], axis=1)
-                    av = jnp.concatenate([av, rv], axis=1)
-                a = L.decode_attention(cfg, p["attn"], hn, pos, ak, av,
-                                       full_mask)
+                    rk = PG.stage_tile(rk, k_new[:, 0], cur)
+                    rv = PG.stage_tile(rv, v_new[:, 0], cur)
+            if fused:
+                with jax.named_scope("attention"):
+                    a = L.fused_paged_attention(
+                        cfg, p["attn"], hn, pos[:, None], pk, pv,
+                        plan.blocks, view_ok[:, None, :],
+                        rk if ring else None, rv if ring else None, ring_ok,
+                        mesh=mesh)
+            else:
+                with jax.named_scope("kv_view"):
+                    ak = PG.gather_view(pk, view_ids)
+                    av = PG.gather_view(pv, view_ids)
+                    if ring:
+                        ak = jnp.concatenate([ak, rk], axis=1)
+                        av = jnp.concatenate([av, rv], axis=1)
+                with jax.named_scope("attention"):
+                    a = L.decode_attention(cfg, p["attn"], hn, pos, ak, av,
+                                           full_mask)
             h = h + a
-            h = h + L.apply_mlp(cfg, p["mlp"], L.apply_norm(cfg, p["ln2"], h))
+            with jax.named_scope("mlp"):
+                h = h + L.apply_mlp(cfg, p["mlp"],
+                                    L.apply_norm(cfg, p["ln2"], h))
             if ring:
                 return h, (pk, pv, rk, rv)
             return h, (pk, pv)
@@ -511,10 +521,12 @@ class DecoderLM:
                 (params["blocks"], cache["pages_k"], cache["pages_v"],
                  cache["ring_k"], cache["ring_v"]),
             )
-            new_cache = PG.ring_commit(
-                dict(cache, pages_k=pks, pages_v=pvs, ring_k=rks, ring_v=rvs),
-                pos, unload_mask,
-            )
+            with jax.named_scope("kv_write"):
+                new_cache = PG.ring_commit(
+                    dict(cache, pages_k=pks, pages_v=pvs, ring_k=rks,
+                         ring_v=rvs),
+                    pos, unload_mask,
+                )
         else:
             x, (pks, pvs) = self._scan(
                 self_body, x,
@@ -522,8 +534,9 @@ class DecoderLM:
             )
             new_cache = dict(cache, pages_k=pks, pages_v=pvs)
 
-        x = L.apply_norm(cfg, params["ln_f"], x)
-        logits = L.lm_logits(cfg, params["embed"], x)[:, 0]
+        with jax.named_scope("head"):
+            x = L.apply_norm(cfg, params["ln_f"], x)
+            logits = L.lm_logits(cfg, params["embed"], x)[:, 0]
         return logits, new_cache
 
     # -- mixed-phase chunk step (paged pool) --------------------------------
@@ -571,32 +584,35 @@ class DecoderLM:
         fused = attention == "fused"
         dtype = jnp.dtype(cfg.dtype)
         b, c = tokens.shape
-        x = L.embed_tokens(cfg, params["embed"], tokens, dtype)
+        with jax.named_scope("head"):
+            x = L.embed_tokens(cfg, params["embed"], tokens, dtype)
         positions = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
         wvalid = (jnp.arange(c)[None, :] < n_valid[:, None]) & write_mask[:, None]
         ring = PG.has_ring(cache)
-        if plan is None:
-            plan = PG.step_plan(cache)
-        if ring:
-            if unload_mask is None:
-                unload_mask = jnp.zeros((b,), jnp.bool_)
-            unload_mask = unload_mask & wvalid[:, 0]
-            view_ok, ring_lane_ok, cur = PG.overlay_chunk_parts(
-                cache, positions, unload_mask, allocated=plan.allocated)
-            r = ring_lane_ok.shape[1]
-            full_mask = jnp.concatenate(
-                [view_ok,
-                 jnp.broadcast_to(ring_lane_ok[:, None, :], (b, c, r))],
-                axis=2)
-            direct = wvalid & ~unload_mask[:, None]
-        else:
-            view_ok = full_mask = PG.view_chunk_mask_from(plan.allocated,
-                                                          positions)
-            ring_lane_ok = None
-            direct = wvalid
-        dest = PG.logical_to_physical_many(
-            cache, jnp.where(direct, positions, -1))
-        view_ids = plan.view_ids
+        with jax.named_scope("kv_view"):
+            if plan is None:
+                plan = PG.step_plan(cache)
+            if ring:
+                if unload_mask is None:
+                    unload_mask = jnp.zeros((b,), jnp.bool_)
+                unload_mask = unload_mask & wvalid[:, 0]
+                view_ok, ring_lane_ok, cur = PG.overlay_chunk_parts(
+                    cache, positions, unload_mask, allocated=plan.allocated)
+                r = ring_lane_ok.shape[1]
+                full_mask = jnp.concatenate(
+                    [view_ok,
+                     jnp.broadcast_to(ring_lane_ok[:, None, :], (b, c, r))],
+                    axis=2)
+                direct = wvalid & ~unload_mask[:, None]
+            else:
+                view_ok = full_mask = PG.view_chunk_mask_from(plan.allocated,
+                                                              positions)
+                ring_lane_ok = None
+                direct = wvalid
+            view_ids = plan.view_ids
+        with jax.named_scope("kv_write"):
+            dest = PG.logical_to_physical_many(
+                cache, jnp.where(direct, positions, -1))
 
         def self_body(carry, xs):
             h = carry
@@ -604,29 +620,36 @@ class DecoderLM:
                 p, pk, pv, rk, rv = xs
             else:
                 p, pk, pv = xs
-            hn = L.apply_norm(cfg, p["ln1"], h)
-            k_new, v_new = L.project_kv(cfg, p["attn"], hn, positions)
-            pk = PG.scatter_chunk(pk, dest, k_new)
-            pv = PG.scatter_chunk(pv, dest, v_new)
-            if ring:
-                rk = PG.stage_tile(rk, k_new[:, 0], cur)
-                rv = PG.stage_tile(rv, v_new[:, 0], cur)
-            if fused:
-                a = L.fused_paged_attention(
-                    cfg, p["attn"], hn, positions, pk, pv,
-                    plan.blocks, view_ok,
-                    rk if ring else None, rv if ring else None, ring_lane_ok,
-                    mesh=mesh)
-            else:
-                ak = PG.gather_view(pk, view_ids)
-                av = PG.gather_view(pv, view_ids)
+            with jax.named_scope("attention"):
+                hn = L.apply_norm(cfg, p["ln1"], h)
+                k_new, v_new = L.project_kv(cfg, p["attn"], hn, positions)
+            with jax.named_scope("kv_write"):
+                pk = PG.scatter_chunk(pk, dest, k_new)
+                pv = PG.scatter_chunk(pv, dest, v_new)
                 if ring:
-                    ak = jnp.concatenate([ak, rk], axis=1)
-                    av = jnp.concatenate([av, rv], axis=1)
-                a = L.masked_chunk_attention(
-                    cfg, p["attn"], hn, positions, ak, av, full_mask)
+                    rk = PG.stage_tile(rk, k_new[:, 0], cur)
+                    rv = PG.stage_tile(rv, v_new[:, 0], cur)
+            if fused:
+                with jax.named_scope("attention"):
+                    a = L.fused_paged_attention(
+                        cfg, p["attn"], hn, positions, pk, pv,
+                        plan.blocks, view_ok,
+                        rk if ring else None, rv if ring else None,
+                        ring_lane_ok, mesh=mesh)
+            else:
+                with jax.named_scope("kv_view"):
+                    ak = PG.gather_view(pk, view_ids)
+                    av = PG.gather_view(pv, view_ids)
+                    if ring:
+                        ak = jnp.concatenate([ak, rk], axis=1)
+                        av = jnp.concatenate([av, rv], axis=1)
+                with jax.named_scope("attention"):
+                    a = L.masked_chunk_attention(
+                        cfg, p["attn"], hn, positions, ak, av, full_mask)
             h = h + a
-            h = h + L.apply_mlp(cfg, p["mlp"], L.apply_norm(cfg, p["ln2"], h))
+            with jax.named_scope("mlp"):
+                h = h + L.apply_mlp(cfg, p["mlp"],
+                                    L.apply_norm(cfg, p["ln2"], h))
             if ring:
                 return h, (pk, pv, rk, rv)
             return h, (pk, pv)
@@ -637,10 +660,12 @@ class DecoderLM:
                 (params["blocks"], cache["pages_k"], cache["pages_v"],
                  cache["ring_k"], cache["ring_v"]),
             )
-            new_cache = PG.ring_commit(
-                dict(cache, pages_k=pks, pages_v=pvs, ring_k=rks, ring_v=rvs),
-                start, unload_mask,
-            )
+            with jax.named_scope("kv_write"):
+                new_cache = PG.ring_commit(
+                    dict(cache, pages_k=pks, pages_v=pvs, ring_k=rks,
+                         ring_v=rvs),
+                    start, unload_mask,
+                )
         else:
             x, (pks, pvs) = self._scan(
                 self_body, x,
@@ -648,15 +673,17 @@ class DecoderLM:
             )
             new_cache = dict(cache, pages_k=pks, pages_v=pvs)
 
-        if all_logits:
+        with jax.named_scope("head"):
+            if all_logits:
+                x = L.apply_norm(cfg, params["ln_f"], x)
+                return L.lm_logits(cfg, params["embed"], x), new_cache
+            # logits at each slot's last valid column: the final prompt
+            # token (prefill, phase-flip sampling) or the decode token
+            # (column 0)
+            sel = jnp.clip(n_valid - 1, 0)[:, None, None]
+            x = jnp.take_along_axis(x, sel, axis=1)
             x = L.apply_norm(cfg, params["ln_f"], x)
-            return L.lm_logits(cfg, params["embed"], x), new_cache
-        # logits at each slot's last valid column: the final prompt token
-        # (prefill, phase-flip sampling) or the decode token (column 0)
-        sel = jnp.clip(n_valid - 1, 0)[:, None, None]
-        x = jnp.take_along_axis(x, sel, axis=1)
-        x = L.apply_norm(cfg, params["ln_f"], x)
-        logits = L.lm_logits(cfg, params["embed"], x)[:, 0]
+            logits = L.lm_logits(cfg, params["embed"], x)[:, 0]
         return logits, new_cache
 
     # -- decode ------------------------------------------------------------

@@ -20,7 +20,8 @@ scan segments retire them; ``Engine.generate`` drains to completion.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Iterator, List, Optional, Sequence, Union
+from typing import (Any, Dict, Iterator, List, Optional, Sequence, Tuple,
+                    Union)
 
 import jax
 import numpy as np
@@ -29,6 +30,7 @@ from ..core.paths import (AttentionConfig, MemoryConfig, ParallelConfig,
                           SpecConfig, normalize_groups)
 from ..models.sampling import SamplingParams
 from .scheduler import BatchConfig, BatchedServeEngine
+from .trace import SegmentRecord
 
 __all__ = [
     "AttentionConfig",
@@ -170,10 +172,8 @@ class Completion:
                   the queue clock's stamp) to the first emitted token —
                   queueing delay included, the open-loop SLO quantity.
                   Requests injected without going through
-                  ``RequestQueue.submit`` fall back to the serve-relative
-                  value.
-    ttft_serve_s  the legacy value: seconds from serve start to the
-                  first emitted token (queueing delay invisible)
+                  ``RequestQueue.submit`` fall back to seconds from serve
+                  start.
     finish_reason ``"stop"`` (stop-token hit) or ``"length"`` (budget)
     path_counts   how this request's KV writes were routed:
                   {"direct", "staged", "prefill"} (prefill = bulk rows
@@ -184,7 +184,6 @@ class Completion:
     tokens: np.ndarray
     params: SamplingParams
     ttft_s: float
-    ttft_serve_s: float
     finish_reason: str
     path_counts: Dict[str, int]
 
@@ -211,6 +210,15 @@ class Engine:
     scheduler (slots, paged pool / lanes, write-path machinery) is an
     implementation detail reachable at ``engine.scheduler`` for tests and
     benchmarks that need the internals.
+
+    ``segment_records`` is the engine's own timing of its scan segments:
+    a tuple of ``repro.serve.trace.SegmentRecord`` (segment count, program
+    kind ``"mixed"``/``"decode"``/``"spec"``, wall seconds from dispatch to
+    readback complete, host seconds spent since the previous readback),
+    the newest ``repro.serve.trace.RECORDS`` of them, cleared on
+    :meth:`reset`. In a profile (``jax.profiler``) the same phases are the
+    host spans ``engine.retire``, ``engine.admit``, ``engine.topup``,
+    ``engine.dispatch``, ``engine.readback`` and ``engine.emit``.
     """
 
     def __init__(self, model, params, cfg: EngineConfig):
@@ -250,6 +258,10 @@ class Engine:
     def ttft(self) -> Dict[int, float]:
         return self.scheduler.ttft
 
+    @property
+    def segment_records(self) -> Tuple[SegmentRecord, ...]:
+        return tuple(self.scheduler.segment_log.records)
+
     def reset(self, keep_cache: bool = False) -> None:
         """Fresh serving state; compiled segment functions are retained.
         ``keep_cache=True`` keeps the block pool + device cache so
@@ -287,20 +299,18 @@ class Engine:
         reason = ("stop" if len(tokens) and int(tokens[-1]) in stop
                   else "length")
         d, s, p = (int(x) for x in eng.req_writes[rid])
-        ttft_serve = float(eng.ttft.get(rid, 0.0))
         # arrival-based TTFT: absolute first-token instant minus the
         # queue-stamped arrival (both on the engine clock's timeline);
         # serve-relative fallback when either stamp is missing
         if rid in eng.first_token_t and rid in eng.req_arrival:
             ttft = float(eng.first_token_t[rid] - eng.req_arrival[rid])
         else:
-            ttft = ttft_serve
+            ttft = float(eng.ttft.get(rid, 0.0))
         return Completion(
             req_id=rid,
             tokens=tokens,
             params=params,
             ttft_s=ttft,
-            ttft_serve_s=ttft_serve,
             finish_reason=reason,
             path_counts={"direct": d, "staged": s, "prefill": p},
         )
@@ -333,12 +343,13 @@ class Engine:
         sent: Dict[int, int] = {}
         finished: set = set()
 
-        def drain_events():
+        def events(done_flags):
             # report in request order for determinism; done-ness comes
             # from the slot state (retirement happens next loop turn)
             done_now = {eng._slot_req[s]
                         for s in range(eng.cfg.n_slots)
                         if eng._occupied[s] and bool(done_flags[s])}
+            out = []
             for rid in sorted(eng.outputs):
                 if rid in finished:
                     continue
@@ -350,12 +361,13 @@ class Engine:
                     if is_done:
                         finished.add(rid)
                         completion = self._completion(rid)
-                    yield StreamEvent(
+                    out.append(StreamEvent(
                         req_id=rid,
                         tokens=np.asarray(new, np.int32),
                         done=is_done,
                         completion=completion,
-                    )
+                    ))
+            return out
 
         for _ in range(max_segments):
             eng.retire_done()
@@ -374,8 +386,11 @@ class Engine:
                         "every live slot stalled on block top-up: the pool "
                         "is too small for the admitted working set")
                 eng.run_segment(enabled)
-            done_flags = np.asarray(eng.slots.done)
-            yield from drain_events()
+            # built whole before the first yield: no span stays open
+            # while the consumer holds the stream
+            with eng.segment_log.phase("engine.emit"):
+                batch = events(np.asarray(eng.slots.done))
+            yield from batch
         raise RuntimeError(f"stream() exceeded {max_segments} segments")
 
     # ------------------------------------------------------------------

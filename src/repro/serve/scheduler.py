@@ -79,6 +79,7 @@ from ..kvcache import paged as PG
 from ..models import sampling as SMP
 from ..models.sampling import SamplingParams, SlotParams
 from ..models.transformer import DecoderLM, direct_kv_write
+from .trace import SegmentLog, phase
 
 # Slot phases (values of SlotState.phase). DONE is not a phase: the `done`
 # flag retires a slot out of both phases.
@@ -358,11 +359,15 @@ class BatchedServeEngine:
         self.clock: Callable[[], float] = time.perf_counter
         self.time_model: Optional[Callable[[dict], float]] = None
         # open-loop accounting: per-request arrival instant (stamped at
-        # admission from Request.arrival_s — the queue's clock) and the
-        # ABSOLUTE first-token instant on the engine clock; ``ttft`` stays
+        # admission from Request.arrival_s — the queue's clock), the
+        # instant admission took it from the queue, and the ABSOLUTE
+        # first-token instant on the engine clock; ``ttft`` stays
         # serve-relative (first token - serve start, the legacy value)
         self.req_arrival: Dict[int, float] = {}
+        self.req_admit: Dict[int, float] = {}
         self.first_token_t: Dict[int, float] = {}
+        # host phases and per-segment records (``serve.trace``)
+        self.segment_log = SegmentLog()
         # preempt-and-requeue (sched/preempt): the original Request
         # objects (requeued verbatim on preemption) and each preempted
         # request's saved execution state (KV bytes host-side + slot
@@ -489,7 +494,9 @@ class BatchedServeEngine:
         self.req_writes = {}
         self._t_serve0 = None
         self.req_arrival = {}
+        self.req_admit = {}
         self.first_token_t = {}
+        self.segment_log.clear()
         self._requests = {}
         self._preempted = {}
         self._slot_priority = [0] * cfg.n_slots
@@ -583,7 +590,7 @@ class BatchedServeEngine:
             emit = jnp.where(active, nxt, -1)
             return (cache, st, mon, stats, swrites), (emit, active)
 
-        def run(params, cache, st, mon, enabled):
+        def segment_decode(params, cache, st, mon, enabled):
             # page-table products are segment-invariant (allocation is
             # host-side, between segments): derive them ONCE here, outside
             # the scan, instead of once per step per layer
@@ -612,7 +619,7 @@ class BatchedServeEngine:
                         (st, mon, stats, swrites, emits, acts), mesh))
             return cache, st, mon, stats, swrites, emits, acts
 
-        return jax.jit(run)
+        return jax.jit(segment_decode)
 
     def _build_mixed_segment(self, mode: str) -> Callable:
         """Mixed-phase segment (chunked, paged layout): each step every
@@ -712,7 +719,7 @@ class BatchedServeEngine:
             emit = jnp.where(emitting, nxt, -1)
             return (cache, st, mon, stats, swrites), (emit, emitting)
 
-        def run(params, cache, st, mon, prompts, enabled):
+        def segment_mixed(params, cache, st, mon, prompts, enabled):
             # per-segment hoist of page-table products (see _build_segment)
             plan = PG.step_plan(cache)
             stats0 = jnp.zeros((4,), jnp.int32)
@@ -733,7 +740,7 @@ class BatchedServeEngine:
                         (st, mon, stats, swrites, emits, ems), mesh))
             return cache, st, mon, stats, swrites, emits, ems
 
-        return jax.jit(run)
+        return jax.jit(segment_mixed)
 
     def _build_spec_segment(self, mode: str) -> Callable:
         """Speculative segment (the third path, DESIGN.md §11): per scan
@@ -868,7 +875,7 @@ class BatchedServeEngine:
             return ((cache, dc, st, mon, stats, sstats, swrites),
                     (emit, emit_mask))
 
-        def run(params, dpar, cache, dcache, st, mon, enabled):
+        def segment_spec(params, dpar, cache, dcache, st, mon, enabled):
             plan = PG.step_plan(cache)
             stats0 = jnp.zeros((4,), jnp.int32)
             sst0 = jnp.zeros((4,), jnp.int32)
@@ -889,7 +896,7 @@ class BatchedServeEngine:
             return (cache, dcache, st, mon, stats, sstats, swrites,
                     emits, ems)
 
-        return jax.jit(run)
+        return jax.jit(segment_spec)
 
     # ------------------------------------------------------------------
     # admission / retirement / allocation (host, between segments)
@@ -1003,6 +1010,7 @@ class BatchedServeEngine:
             self.pool.register(s, held[p], self._slot_hashes[s][p])
         self._slot_reg[s] = reach
 
+    @phase("engine.topup")
     def _topup_blocks(self) -> np.ndarray:
         """The between-segment memory manager: page parked slots back in,
         extend live chunked slots' page tables to cover the rows the NEXT
@@ -1571,6 +1579,7 @@ class BatchedServeEngine:
         if wait_pri > self._slot_priority[victim]:
             self.preempt_slot(victim, queue)
 
+    @phase("engine.admit")
     def admit(self, queue: RequestQueue) -> int:
         """Admit waiting requests into free slots, scanning the queue in
         the admission policy's order (``cfg.sched``; FIFO = submission
@@ -1634,6 +1643,9 @@ class BatchedServeEngine:
                 self._skip_rid, self._skip_count = -1, 0
             picks.append((free.pop(0), queue.pop_at(qi), plan))
             popped.append(oi)
+        now = self._now()
+        for _, req, _ in picks:
+            self.req_admit.setdefault(req.req_id, now)
         if self._in_scan_prefill:
             if picks:
                 self._admit_chunked([p[0] for p in picks],
@@ -1668,7 +1680,20 @@ class BatchedServeEngine:
         """One jitted scan segment + ONE host readback. Returns the bool
         [segment_len, n_slots] emission matrix (which steps emitted).
         ``enabled`` (bool[n_slots], optional) stalls slots whose per-chunk
-        block top-up failed."""
+        block top-up failed. Appends the segment's record to
+        ``segment_log``."""
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("engine.dispatch"):
+            kind, out = self._dispatch(enabled)
+        with jax.profiler.TraceAnnotation("engine.readback"):
+            acts = self._readback(kind, *out)
+        self.segment_log.record(self.stats["segments"], kind,
+                                time.perf_counter() - t0)
+        return acts
+
+    def _dispatch(self, enabled: Optional[np.ndarray]):
+        """Enqueue the segment program the slots need: (its kind, its
+        device outputs other than the new engine state)."""
         if enabled is None:
             enabled = np.ones((self.cfg.n_slots,), bool)
         enabled_j = jnp.asarray(enabled)
@@ -1679,8 +1704,6 @@ class BatchedServeEngine:
         mode = SMP.required_mode(
             [self.req_params[self._slot_req[s]]
              for s in range(self.cfg.n_slots) if self._occupied[s]])
-        spec_ran = False
-        sstats = None
         if self._mixed_phase_pending():
             self._mixed_fn = self._mixed_fns.get(mode)
             if self._mixed_fn is None:
@@ -1690,10 +1713,10 @@ class BatchedServeEngine:
              emits, acts) = (
                 self._mixed_fn(self.params, self.cache, self.slots,
                                self.mon_state, self.prompts, enabled_j))
-        elif self._spec_enabled:
+            return "mixed", (stats, None, swrites, emits, acts)
+        if self._spec_enabled:
             # the third path: draft-then-verify rounds need every live
             # slot in DECODE phase and the draft lanes in sync
-            spec_ran = True
             self._sync_draft()
             self._spec_fn = self._spec_fns.get(mode)
             if self._spec_fn is None:
@@ -1704,15 +1727,22 @@ class BatchedServeEngine:
                 self._spec_fn(self.params, self.draft_params, self.cache,
                               self.draft_cache, self.slots,
                               self.mon_state, enabled_j))
-        else:
-            self._segment_fn = self._segment_fns.get(mode)
-            if self._segment_fn is None:
-                self._segment_fn = self._build_segment(mode)
-                self._segment_fns[mode] = self._segment_fn
-            (self.cache, self.slots, self.mon_state, stats, swrites,
-             emits, acts) = (
-                self._segment_fn(self.params, self.cache, self.slots,
-                                 self.mon_state, enabled_j))
+            return "spec", (stats, sstats, swrites, emits, acts)
+        self._segment_fn = self._segment_fns.get(mode)
+        if self._segment_fn is None:
+            self._segment_fn = self._build_segment(mode)
+            self._segment_fns[mode] = self._segment_fn
+        (self.cache, self.slots, self.mon_state, stats, swrites,
+         emits, acts) = (
+            self._segment_fn(self.params, self.cache, self.slots,
+                             self.mon_state, enabled_j))
+        return "decode", (stats, None, swrites, emits, acts)
+
+    def _readback(self, kind: str, stats, sstats, swrites, emits,
+                  acts) -> np.ndarray:
+        """Bring one segment's outputs to the host (the host waits on the
+        device here) and fold them into the counters and the outputs."""
+        spec_ran = kind == "spec"
         emits, acts = np.asarray(emits), np.asarray(acts)
         swrites = np.asarray(swrites)
         d, s, dr, pf = (int(x) for x in stats)
@@ -1764,6 +1794,7 @@ class BatchedServeEngine:
             return acts.any(axis=2)
         return acts
 
+    @phase("engine.retire")
     def retire_done(self) -> int:
         """Free every occupied-but-done slot (host, between segments)."""
         done = np.asarray(self.slots.done)
