@@ -186,12 +186,17 @@ def kernel_checks(cfg, mesh=None) -> None:
         read = functools.partial(flash_decode_paged_sharded, mesh)
         where = f" per head shard on {mesh.shape['model']} chips"
 
-        def put(x, heads):   # heads ride axis 2 of every KV operand
-            spec = P(None, None, "model", None) if heads else P()
+        def put(x, heads):   # heads ride the second-to-last axis
+            spec = P(*([None] * (x.ndim - 2)), "model", None) if heads else P()
             return jax.device_put(x, NamedSharding(mesh, spec))
 
-    pool_k = jax.random.normal(keys[0], (n_blocks, PAGE, hkv, d), bf16)
-    pool_v = jax.random.normal(keys[1], (n_blocks, PAGE, hkv, d), bf16)
+    # the kernel reads one layer of the whole stacked pool and ring, as
+    # serving passes them; check the last layer of two
+    n_layers, layer = 2, 1
+    pool_k = jax.random.normal(keys[0], (n_layers, n_blocks, PAGE, hkv, d),
+                               bf16)
+    pool_v = jax.random.normal(keys[1], (n_layers, n_blocks, PAGE, hkv, d),
+                               bf16)
     blocks = jax.random.randint(keys[2], (N_SLOTS, pages), 0, n_blocks)
     for c, ring in ((1, True), (CHUNK, False)):
         q = jax.random.normal(keys[3], (N_SLOTS, c, hq, d), bf16)
@@ -199,15 +204,17 @@ def kernel_checks(cfg, mesh=None) -> None:
                                        (N_SLOTS, c, pages * PAGE))
         extra = (None, None, None)
         if ring:
-            extra = (jax.random.normal(keys[5], (N_SLOTS, RING, hkv, d), bf16),
-                     jax.random.normal(keys[6], (N_SLOTS, RING, hkv, d), bf16),
+            ring_shape = (n_layers, N_SLOTS, RING, hkv, d)
+            extra = (jax.random.normal(keys[5], ring_shape, bf16),
+                     jax.random.normal(keys[6], ring_shape, bf16),
                      jax.random.bernoulli(keys[7], 0.5, (N_SLOTS, RING)))
-        heads = (True, True, True, False, False, True, True, False)
+        heads = (True, True, True, False, False, False, True, True, False)
+        lay = jnp.asarray(layer, jnp.int32)
         args = [x if x is None else put(x, h) for x, h in
-                zip((q, pool_k, pool_v, blocks, view_ok) + extra, heads)]
+                zip((q, pool_k, pool_v, lay, blocks, view_ok) + extra, heads)]
         got = read(*args)
         want = jax.jit(ref.flash_decode_paged_ref)(
-            q, pool_k, pool_v, blocks, view_ok, *extra)
+            q, pool_k, pool_v, lay, blocks, view_ok, *extra)
         got = np.asarray(got, np.float32)
         want = np.asarray(want, np.float32)
         err = float(np.max(np.abs(got - want)))

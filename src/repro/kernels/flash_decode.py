@@ -70,6 +70,7 @@ def _decode_kernel(
 def _paged_kernel(
     # scalar prefetch
     tab_ref,               # int32 [B, P] physical block ids (clamped >= 0)
+    layer_ref,             # int32 [1] the layer of the pool to read
     # inputs
     q_ref,                 # [1, Hkv, G*C, D] queries, grouped by KV head
     k_ref,                 # [1, ps, Hkv, D] one physical KV block, all heads
@@ -81,13 +82,13 @@ def _paged_kernel(
     """Grid (B, P): one walk of one slot's page table.
 
     Each step reads one physical block straight out of the pool (the
-    BlockSpecs index the pool through the scalar-prefetched table — no
-    gathered copy ever lands in HBM), scores it for every head at once
-    and folds it into an online softmax: running max, denominator and
-    unnormalized weighted sum, rescaled as the max grows. The staging-ring
-    lanes join at the last page as a second KV source; the output is the
-    weighted sum over the denominator. Nothing in VMEM grows with the
-    context length.
+    BlockSpecs index the pool through the scalar-prefetched layer and
+    table — no gathered copy ever lands in HBM), scores it for every head
+    at once and folds it into an online softmax: running max, denominator
+    and unnormalized weighted sum, rescaled as the max grows. The
+    staging-ring lanes join at the last page as a second KV source; the
+    output is the weighted sum over the denominator. Nothing in VMEM grows
+    with the context length.
     """
     if ring:
         rk_ref, rv_ref, rm_ref, o_ref, mx_ref, l_ref, acc_ref = rest
@@ -128,11 +129,12 @@ def _paged_kernel(
 
 def flash_decode_paged(
     q: jnp.ndarray,         # [B, C, Hq, D] query slab (C=1 for step decode)
-    pages_k: jnp.ndarray,   # [n_blocks, ps, Hkv, D] physical pool (one layer)
-    pages_v: jnp.ndarray,   # [n_blocks, ps, Hkv, D]
+    pages_k: jnp.ndarray,   # [L, n_blocks, ps, Hkv, D] physical pool
+    pages_v: jnp.ndarray,   # [L, n_blocks, ps, Hkv, D]
+    layer: jnp.ndarray,     # int32 scalar: the layer of pool and ring to read
     blocks: jnp.ndarray,    # int32 [B, P] per-slot physical block ids (>= 0)
     view_ok: jnp.ndarray,   # bool [B, C, P*ps] paged-view validity mask
-    ring_k: jnp.ndarray | None = None,   # [B, R, Hkv, D] staging-ring lanes
+    ring_k: jnp.ndarray | None = None,   # [L, B, R, Hkv, D] staging ring
     ring_v: jnp.ndarray | None = None,
     ring_ok: jnp.ndarray | None = None,  # bool [B, R] lane validity
     *,
@@ -140,11 +142,14 @@ def flash_decode_paged(
 ) -> jnp.ndarray:
     """Fused paged-attention decode: page-table walk + ring overlay + SDPA.
 
-    The scalar-prefetched ``blocks`` table drives the pool BlockSpecs, so
-    each grid step reads its [ps, Hkv, D] KV block directly from the
-    physical pool; undrained staging-ring lanes join the same softmax as a
-    second source. Nothing is gathered or overlaid in HBM first — the
-    read-side twin of ``staged_scatter``. Returns [B, C, Hq, D].
+    Pool and ring come whole, every layer stacked, and ``layer`` picks
+    the one to read: the scalar-prefetched ``layer`` and ``blocks`` drive
+    the pool BlockSpecs, so each grid step reads its [ps, Hkv, D] KV block
+    of that layer directly from the physical pool, and no layer plane is
+    ever sliced out of the stack. Undrained staging-ring lanes join the
+    same softmax as a second source. Nothing is gathered or overlaid in
+    HBM first — the read-side twin of ``staged_scatter``. Returns
+    [B, C, Hq, D].
 
     TPU tiling: every block's last two dims are whole array dims (heads x
     head_dim, query rows x page rows), so any page size, head count and
@@ -153,7 +158,7 @@ def flash_decode_paged(
     and the wrapper restores [B, C, Hq, D].
     """
     b, c, hq, d = q.shape
-    ps, hkv = pages_k.shape[1], pages_k.shape[2]
+    ps, hkv = pages_k.shape[2], pages_k.shape[3]
     n_pages = blocks.shape[1]
     assert hq % hkv == 0
     group = hq // hkv
@@ -161,7 +166,7 @@ def flash_decode_paged(
     assert view_ok.shape == (b, c, n_pages * ps), (view_ok.shape, n_pages, ps)
     ring = ring_k is not None
     if ring:
-        r = ring_k.shape[1]
+        r = ring_k.shape[2]
         assert ring_ok is not None and ring_ok.shape == (b, r)
 
     qg = (q.reshape(b, c, hkv, group, d).transpose(0, 2, 3, 1, 4)
@@ -170,21 +175,23 @@ def flash_decode_paged(
             .astype(jnp.int32))
     mask = jnp.tile(mask, (1, 1, group, 1))            # [B, P, G*C, ps]
 
-    kv_spec = pl.BlockSpec((1, ps, hkv, d),
-                           lambda b_, j, tab: (tab[b_, j], 0, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (pl.Squeezed(), 1, ps, hkv, d),
+        lambda b_, j, tab, lay: (lay[0], tab[b_, j], 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, hkv, gc, d), lambda b_, j, tab: (b_, 0, 0, 0)),
+        pl.BlockSpec((1, hkv, gc, d), lambda b_, j, tab, lay: (b_, 0, 0, 0)),
         kv_spec,
         kv_spec,
-        pl.BlockSpec((1, 1, gc, ps), lambda b_, j, tab: (b_, j, 0, 0)),
+        pl.BlockSpec((1, 1, gc, ps), lambda b_, j, tab, lay: (b_, j, 0, 0)),
     ]
     args = [qg, pages_k, pages_v, mask]
     if ring:
-        lane_spec = pl.BlockSpec((1, r, hkv, d),
-                                 lambda b_, j, tab: (b_, 0, 0, 0))
+        lane_spec = pl.BlockSpec(
+            (pl.Squeezed(), 1, r, hkv, d),
+            lambda b_, j, tab, lay: (lay[0], b_, 0, 0, 0))
         in_specs += [
             lane_spec, lane_spec,
-            pl.BlockSpec((1, 1, r), lambda b_, j, tab: (b_, 0, 0)),
+            pl.BlockSpec((1, 1, r), lambda b_, j, tab, lay: (b_, 0, 0)),
         ]
         args += [ring_k, ring_v, ring_ok.astype(jnp.int32).reshape(b, 1, r)]
 
@@ -193,11 +200,11 @@ def flash_decode_paged(
             _paged_kernel, n_pages=n_pages, scale=d ** -0.5, ring=ring,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b, n_pages),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (1, hkv, gc, d), lambda b_, j, tab: (b_, 0, 0, 0)),
+                (1, hkv, gc, d), lambda b_, j, tab, lay: (b_, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((hkv, gc, 1), jnp.float32),   # running max
                 pltpu.VMEM((hkv, gc, 1), jnp.float32),   # denominator
@@ -208,7 +215,7 @@ def flash_decode_paged(
         interpret=interpret,
         name="flash_decode_paged",
     )
-    out = fn(blocks, *args)
+    out = fn(blocks, jnp.reshape(layer, (1,)).astype(jnp.int32), *args)
     return (out.reshape(b, hkv, group, c, d).transpose(0, 3, 1, 2, 4)
             .reshape(b, c, hq, d))
 
@@ -216,11 +223,12 @@ def flash_decode_paged(
 def flash_decode_paged_sharded(
     mesh,
     q: jnp.ndarray,         # [B, C, Hq, D]
-    pages_k: jnp.ndarray,   # [n_blocks, ps, Hkv, D]
+    pages_k: jnp.ndarray,   # [L, n_blocks, ps, Hkv, D]
     pages_v: jnp.ndarray,
-    blocks: jnp.ndarray,    # int32 [B, P]      (replicated)
-    view_ok: jnp.ndarray,   # bool [B, C, P*ps] (replicated)
-    ring_k: jnp.ndarray | None = None,   # [B, R, Hkv, D]
+    layer: jnp.ndarray,     # int32 scalar              (replicated)
+    blocks: jnp.ndarray,    # int32 [B, P]              (replicated)
+    view_ok: jnp.ndarray,   # bool [B, C, P*ps]         (replicated)
+    ring_k: jnp.ndarray | None = None,   # [L, B, R, Hkv, D]
     ring_v: jnp.ndarray | None = None,
     ring_ok: jnp.ndarray | None = None,  # bool [B, R]   (replicated)
     *,
@@ -235,28 +243,32 @@ def flash_decode_paged_sharded(
     Both Hq and Hkv must divide the ``axis`` size so every shard holds
     whole GQA groups — softmax is per-q-head, so no cross-shard combine
     is needed and the result is bitwise the unsharded kernel's. Routing
-    inputs (``blocks``, ``view_ok``, ``ring_ok``) are replicated;
-    KV-carrying tensors split on their head axis.
+    inputs (``layer``, ``blocks``, ``view_ok``, ``ring_ok``) are
+    replicated; KV-carrying tensors split on their head axis (axis 2 of
+    the queries, axis 3 of the stacked pool and ring).
     """
     from jax.sharding import PartitionSpec as P
 
     tp = mesh.shape[axis]
     if tp == 1:
-        return flash_decode_paged(q, pages_k, pages_v, blocks, view_ok,
-                                  ring_k, ring_v, ring_ok,
+        return flash_decode_paged(q, pages_k, pages_v, layer, blocks,
+                                  view_ok, ring_k, ring_v, ring_ok,
                                   interpret=interpret)
-    hq, hkv = q.shape[2], pages_k.shape[2]
+    hq, hkv = q.shape[2], pages_k.shape[3]
     if hq % tp or hkv % tp:
         raise ValueError(
             f"flash_decode_paged_sharded: axis {axis!r} of {tp} must "
             f"divide both Hq={hq} and Hkv={hkv} (whole GQA groups per "
             f"shard); use a divisible mesh or the unsharded kernel")
-    h_spec = P(None, None, axis, None)   # head axis = axis 2 everywhere
-    in_specs = [h_spec, h_spec, h_spec, P(None, None), P(None, None, None)]
-    args = [q, pages_k, pages_v, blocks, view_ok]
+    q_spec = P(None, None, axis, None)
+    kv_spec = P(None, None, None, axis, None)
+    in_specs = [q_spec, kv_spec, kv_spec, P(), P(None, None),
+                P(None, None, None)]
+    args = [q, pages_k, pages_v, jnp.asarray(layer, jnp.int32), blocks,
+            view_ok]
     ring = ring_k is not None
     if ring:
-        in_specs += [h_spec, h_spec, P(None, None)]
+        in_specs += [kv_spec, kv_spec, P(None, None)]
         args += [ring_k, ring_v, ring_ok]
 
     def shard_fn(*xs):
@@ -264,7 +276,7 @@ def flash_decode_paged_sharded(
 
     # pallas_call has no replication rule — skip the check
     sm = jax.shard_map(shard_fn, mesh=mesh, in_specs=tuple(in_specs),
-                       out_specs=h_spec, check_vma=False)
+                       out_specs=q_spec, check_vma=False)
     return sm(*args)
 
 

@@ -104,21 +104,26 @@ def flash_attention_ref(
 
 def flash_decode_paged_ref(
     q: jnp.ndarray,        # [B, C, Hq, D]
-    pages_k: jnp.ndarray,  # [n_blocks, ps, Hkv, D] physical pool (one layer)
+    pages_k: jnp.ndarray,  # [L, n_blocks, ps, Hkv, D] physical pool
     pages_v: jnp.ndarray,
+    layer: jnp.ndarray,    # int32 scalar: the layer to read
     blocks: jnp.ndarray,   # int32 [B, P] physical block ids (clamped >= 0)
     view_ok: jnp.ndarray,  # bool [B, C, P*ps]
-    ring_k: jnp.ndarray | None = None,   # [B, R, Hkv, D]
+    ring_k: jnp.ndarray | None = None,   # [L, B, R, Hkv, D]
     ring_v: jnp.ndarray | None = None,
     ring_ok: jnp.ndarray | None = None,  # bool [B, R]
 ) -> jnp.ndarray:
-    """Oracle for the fused paged+ring decode kernel: gather the per-slot
-    view through the page table, append the staging-ring lanes, then the
+    """Oracle for the fused paged+ring decode kernel: take layer ``layer``
+    of pool and ring, gather the per-slot view through the page table,
+    append the staging-ring lanes, then the
     exact ``layers._sdpa_once`` op order (fp32 logits -> mask -> softmax ->
     dtype cast -> weighted sum) so the kernel can be held to ulp-level
     fp32 equality (same op order; XLA's shape-dependent GEMM tiling keeps
     the two graphs ~1e-7 apart — DESIGN.md §7)."""
     b, c, hq, d = q.shape
+    pages_k, pages_v = pages_k[layer], pages_v[layer]
+    if ring_k is not None:
+        ring_k, ring_v = ring_k[layer], ring_v[layer]
     ps, hkv = pages_k.shape[1], pages_k.shape[2]
     rows = (blocks[:, :, None] * ps
             + jnp.arange(ps, dtype=blocks.dtype)[None, None, :]).reshape(b, -1)

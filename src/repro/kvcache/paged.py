@@ -497,30 +497,33 @@ def gather_view(pages_l: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
 
 
 def scatter_token(
-    pages_l: jnp.ndarray,   # [n_blocks, ps, H, Dh]
+    pages: jnp.ndarray,     # [L, n_blocks, ps, H, Dh] the whole pool plane
+    layer: jnp.ndarray,     # int32 scalar: the layer written
     dest: jnp.ndarray,      # int32 [n_slots] physical rows (sentinel drops)
     tile: jnp.ndarray,      # [n_slots, H, Dh]
 ) -> jnp.ndarray:
-    """Direct (offload-path) write of one decode step's tiles."""
-    flat = pages_l.reshape((-1,) + pages_l.shape[2:])
-    flat = flat.at[dest].set(tile.astype(flat.dtype), mode="drop")
-    return flat.reshape(pages_l.shape)
+    """Direct (offload-path) write of one decode step's tiles at
+    ``[layer, dest]``. Only those rows change: no layer plane is sliced
+    out and put back, so a pool carried through the layer scan is updated
+    in place."""
+    flat = pages.reshape(pages.shape[:1] + (-1,) + pages.shape[3:])
+    flat = flat.at[layer, dest].set(tile.astype(flat.dtype), mode="drop")
+    return flat.reshape(pages.shape)
 
 
 def scatter_chunk(
-    pages_l: jnp.ndarray,   # [n_blocks, ps, H, Dh]
+    pages: jnp.ndarray,     # [L, n_blocks, ps, H, Dh] the whole pool plane
+    layer: jnp.ndarray,     # int32 scalar: the layer written
     dest: jnp.ndarray,      # int32 [n_slots, C] physical rows (sentinel drops)
     tiles: jnp.ndarray,     # [n_slots, C, H, Dh]
 ) -> jnp.ndarray:
     """Direct (offload-path) bulk write of one mixed-phase step's tiles —
-    the prefill-chunk analogue of :func:`scatter_token`. Destinations are
-    unique across slots (block ownership) and within a chunk (consecutive
-    logical rows), so the scatter never collides."""
-    flat = pages_l.reshape((-1,) + pages_l.shape[2:])
-    flat = flat.at[dest.reshape(-1)].set(
-        tiles.reshape((-1,) + tiles.shape[2:]).astype(flat.dtype),
-        mode="drop")
-    return flat.reshape(pages_l.shape)
+    the prefill-chunk analogue of :func:`scatter_token`, in place at
+    ``[layer, dest]``. Destinations are unique across slots (block
+    ownership) and within a chunk (consecutive logical rows), so the
+    scatter never collides."""
+    return scatter_token(pages, layer, dest.reshape(-1),
+                         tiles.reshape((-1,) + tiles.shape[2:]))
 
 
 # ---------------------------------------------------------------------------
@@ -549,10 +552,12 @@ def ring_conflicts(cache: PagedKV, pos: jnp.ndarray) -> jnp.ndarray:
                        (pos[:, None],))
 
 
-def stage_tile(plane: jnp.ndarray, tile: jnp.ndarray,
+def stage_tile(plane: jnp.ndarray, layer: jnp.ndarray, tile: jnp.ndarray,
                cur: jnp.ndarray) -> jnp.ndarray:
-    """Append one layer's tiles [n_slots, H, Dh] at ring column ``cur``."""
-    return R.push_column(plane, cur, tile, axis=1)
+    """Append layer ``layer``'s tiles [n_slots, H, Dh] at ring column
+    ``cur`` of the whole ring plane [L, n_slots, R, H, Dh], in place."""
+    return lax.dynamic_update_slice(
+        plane, tile[None, :, None].astype(plane.dtype), (layer, 0, cur, 0, 0))
 
 
 def ring_commit(cache: PagedKV, pos: jnp.ndarray,

@@ -369,11 +369,12 @@ def fused_paged_attention(
     p: dict,
     x: jnp.ndarray,          # [B, C, D] normed activations (C=1 for step)
     positions: jnp.ndarray,  # [B, C] absolute query positions
-    pages_k: jnp.ndarray,    # [n_blocks, ps, Hkv, Dh] physical pool (layer)
+    pages_k: jnp.ndarray,    # [L, n_blocks, ps, Hkv, Dh] physical pool
     pages_v: jnp.ndarray,
+    layer: jnp.ndarray,      # int32 scalar: the layer of pool and ring read
     blocks: jnp.ndarray,     # int32 [B, P] clamped physical block ids
     view_ok: jnp.ndarray,    # bool [B, C, P*ps]
-    ring_k: Optional[jnp.ndarray] = None,   # [B, R, Hkv, Dh] staging lanes
+    ring_k: Optional[jnp.ndarray] = None,   # [L, B, R, Hkv, Dh] staging ring
     ring_v: Optional[jnp.ndarray] = None,
     ring_ok: Optional[jnp.ndarray] = None,  # bool [B, R]
     use_rope: bool = True,
@@ -385,6 +386,8 @@ def fused_paged_attention(
     The fused twin of :func:`decode_attention` / :func:`masked_chunk_attention`
     over a paged pool: the kernel walks the page table and overlays the
     staging ring inside one softmax, so no gathered view is materialized.
+    Pool and ring come whole (every layer) and the kernel indexes
+    ``layer`` itself, so no per-layer plane is sliced out of them.
     Projections (``project_q``) and the output einsum are shared with the
     jnp cores — fused and reference differ ONLY in the attention core,
     which the kernel holds to ulp-level fp32 parity (identical greedy
@@ -397,10 +400,11 @@ def fused_paged_attention(
     dtype = x.dtype
     q = project_q(cfg, p, x, positions, use_rope)   # [B, C, Hq, Dh]
     if mesh is not None:
-        out = flash_decode_paged_sharded(mesh, q, pages_k, pages_v, blocks,
-                                         view_ok, ring_k, ring_v, ring_ok)
+        out = flash_decode_paged_sharded(mesh, q, pages_k, pages_v, layer,
+                                         blocks, view_ok, ring_k, ring_v,
+                                         ring_ok)
     else:
-        out = flash_decode_paged(q, pages_k, pages_v, blocks, view_ok,
+        out = flash_decode_paged(q, pages_k, pages_v, layer, blocks, view_ok,
                                  ring_k, ring_v, ring_ok, impl=impl)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dtype))
 

@@ -129,6 +129,18 @@ def valid_mask(cfg: ModelConfig, pos: jnp.ndarray, cache_len: int) -> jnp.ndarra
     return linear
 
 
+def _reference_view(pk, pv, rk, rv, layer, view_ids):
+    """The reference read path's per-slot KV set: layer ``layer`` of the
+    whole pool planes gathered through the page table, then that layer's
+    ring lanes appended (``rk``/``rv`` None without a ring)."""
+    ak = PG.gather_view(pk[layer], view_ids)
+    av = PG.gather_view(pv[layer], view_ids)
+    if rk is not None:
+        ak = jnp.concatenate([ak, rk[layer]], axis=1)
+        av = jnp.concatenate([av, rv[layer]], axis=1)
+    return ak, av
+
+
 # ---------------------------------------------------------------------------
 # DecoderLM: dense + VLM
 # ---------------------------------------------------------------------------
@@ -400,6 +412,30 @@ class DecoderLM:
         return logits, new_cache
 
     # -- decode (paged pool) -----------------------------------------------
+    def _scan_paged(self, body, x, params: Params, cache: Params):
+        """Run ``body`` over the layer stack with the paged pool (and the
+        staging ring) in the scan's CARRY, next to the activations.
+
+        ``body(h, (pages_k, pages_v, ring_k, ring_v), p, layer)`` gets the
+        whole planes (ring entries None without a ring) and its layer
+        index, writes that layer's rows in place and returns
+        ``(h, planes)``. Only the layer parameters and the index ride the
+        scan's xs and nothing rides its ys, so no per-layer plane is ever
+        sliced out of the pool and restacked. Returns (x, cache with the
+        new planes)."""
+        keys = ("pages_k", "pages_v", "ring_k", "ring_v")
+        planes = tuple(cache.get(k) for k in keys)
+        n_layers = cache["pages_k"].shape[0]
+
+        def step(carry, xs):
+            return body(*carry, *xs), None
+
+        (x, planes), _ = self._scan(
+            step, (x, planes),
+            (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)))
+        return x, dict(cache, **{k: v for k, v in zip(keys, planes)
+                                 if v is not None})
+
     def decode_step_paged(
         self,
         params: Params,
@@ -425,13 +461,15 @@ class DecoderLM:
         ``attention`` picks the read implementation (negotiate it through
         ``core.paths.resolve_attention``): ``"reference"`` gathers the
         per-slot view from the pool and concatenates the ring in jnp;
-        ``"fused"`` hands the physical pool, the scalar-prefetch block
-        table, and the ring planes to ``flash_decode_paged``, which walks
-        the page table and merges both sources inside one softmax — no
-        gathered view ever materializes. The two share one op order and
-        agree to fp32 ulp precision with identical greedy tokens (the
-        reference is the kernel's oracle; DESIGN.md §7 has the parity
-        contract). ``plan`` threads per-segment
+        ``"fused"`` hands the whole stacked pool and ring planes, the
+        layer index and the scalar-prefetch block table to
+        ``flash_decode_paged``, which walks that layer's page table and
+        merges both sources inside one softmax — no gathered view ever
+        materializes. Pool and ring ride the layer scan's carry and each
+        layer writes its rows in place (``_scan_paged``). The two share
+        one op order and agree to fp32 ulp precision with identical
+        greedy tokens (the reference is the kernel's oracle; DESIGN.md §7
+        has the parity contract). ``plan`` threads per-segment
         hoisted page-table products (``PG.step_plan``); when None it is
         derived here. ``mesh`` is the serving mesh of a head-sharded pool
         (the fused kernel then runs per shard).
@@ -475,35 +513,26 @@ class DecoderLM:
         with jax.named_scope("kv_write"):
             dest = PG.logical_to_physical(cache, jnp.where(direct, pos, -1))
 
-        def self_body(carry, xs):
-            h = carry
-            if ring:
-                p, pk, pv, rk, rv = xs
-            else:
-                p, pk, pv = xs
+        def self_body(h, planes, p, l):
+            pk, pv, rk, rv = planes
             with jax.named_scope("attention"):
                 hn = L.apply_norm(cfg, p["ln1"], h)
                 k_new, v_new = L.project_kv(cfg, p["attn"], hn, pos[:, None])
             with jax.named_scope("kv_write"):
-                pk = PG.scatter_token(pk, dest, k_new[:, 0])
-                pv = PG.scatter_token(pv, dest, v_new[:, 0])
+                pk = PG.scatter_token(pk, l, dest, k_new[:, 0])
+                pv = PG.scatter_token(pv, l, dest, v_new[:, 0])
                 if ring:
-                    rk = PG.stage_tile(rk, k_new[:, 0], cur)
-                    rv = PG.stage_tile(rv, v_new[:, 0], cur)
+                    rk = PG.stage_tile(rk, l, k_new[:, 0], cur)
+                    rv = PG.stage_tile(rv, l, v_new[:, 0], cur)
             if fused:
                 with jax.named_scope("attention"):
                     a = L.fused_paged_attention(
-                        cfg, p["attn"], hn, pos[:, None], pk, pv,
-                        plan.blocks, view_ok[:, None, :],
-                        rk if ring else None, rv if ring else None, ring_ok,
+                        cfg, p["attn"], hn, pos[:, None], pk, pv, l,
+                        plan.blocks, view_ok[:, None, :], rk, rv, ring_ok,
                         mesh=mesh)
             else:
                 with jax.named_scope("kv_view"):
-                    ak = PG.gather_view(pk, view_ids)
-                    av = PG.gather_view(pv, view_ids)
-                    if ring:
-                        ak = jnp.concatenate([ak, rk], axis=1)
-                        av = jnp.concatenate([av, rv], axis=1)
+                    ak, av = _reference_view(pk, pv, rk, rv, l, view_ids)
                 with jax.named_scope("attention"):
                     a = L.decode_attention(cfg, p["attn"], hn, pos, ak, av,
                                            full_mask)
@@ -511,28 +540,12 @@ class DecoderLM:
             with jax.named_scope("mlp"):
                 h = h + L.apply_mlp(cfg, p["mlp"],
                                     L.apply_norm(cfg, p["ln2"], h))
-            if ring:
-                return h, (pk, pv, rk, rv)
-            return h, (pk, pv)
+            return h, (pk, pv, rk, rv)
 
+        x, new_cache = self._scan_paged(self_body, x, params, cache)
         if ring:
-            x, (pks, pvs, rks, rvs) = self._scan(
-                self_body, x,
-                (params["blocks"], cache["pages_k"], cache["pages_v"],
-                 cache["ring_k"], cache["ring_v"]),
-            )
             with jax.named_scope("kv_write"):
-                new_cache = PG.ring_commit(
-                    dict(cache, pages_k=pks, pages_v=pvs, ring_k=rks,
-                         ring_v=rvs),
-                    pos, unload_mask,
-                )
-        else:
-            x, (pks, pvs) = self._scan(
-                self_body, x,
-                (params["blocks"], cache["pages_k"], cache["pages_v"]),
-            )
-            new_cache = dict(cache, pages_k=pks, pages_v=pvs)
+                new_cache = PG.ring_commit(new_cache, pos, unload_mask)
 
         with jax.named_scope("head"):
             x = L.apply_norm(cfg, params["ln_f"], x)
@@ -614,35 +627,26 @@ class DecoderLM:
             dest = PG.logical_to_physical_many(
                 cache, jnp.where(direct, positions, -1))
 
-        def self_body(carry, xs):
-            h = carry
-            if ring:
-                p, pk, pv, rk, rv = xs
-            else:
-                p, pk, pv = xs
+        def self_body(h, planes, p, l):
+            pk, pv, rk, rv = planes
             with jax.named_scope("attention"):
                 hn = L.apply_norm(cfg, p["ln1"], h)
                 k_new, v_new = L.project_kv(cfg, p["attn"], hn, positions)
             with jax.named_scope("kv_write"):
-                pk = PG.scatter_chunk(pk, dest, k_new)
-                pv = PG.scatter_chunk(pv, dest, v_new)
+                pk = PG.scatter_chunk(pk, l, dest, k_new)
+                pv = PG.scatter_chunk(pv, l, dest, v_new)
                 if ring:
-                    rk = PG.stage_tile(rk, k_new[:, 0], cur)
-                    rv = PG.stage_tile(rv, v_new[:, 0], cur)
+                    rk = PG.stage_tile(rk, l, k_new[:, 0], cur)
+                    rv = PG.stage_tile(rv, l, v_new[:, 0], cur)
             if fused:
                 with jax.named_scope("attention"):
                     a = L.fused_paged_attention(
-                        cfg, p["attn"], hn, positions, pk, pv,
-                        plan.blocks, view_ok,
-                        rk if ring else None, rv if ring else None,
-                        ring_lane_ok, mesh=mesh)
+                        cfg, p["attn"], hn, positions, pk, pv, l,
+                        plan.blocks, view_ok, rk, rv, ring_lane_ok,
+                        mesh=mesh)
             else:
                 with jax.named_scope("kv_view"):
-                    ak = PG.gather_view(pk, view_ids)
-                    av = PG.gather_view(pv, view_ids)
-                    if ring:
-                        ak = jnp.concatenate([ak, rk], axis=1)
-                        av = jnp.concatenate([av, rv], axis=1)
+                    ak, av = _reference_view(pk, pv, rk, rv, l, view_ids)
                 with jax.named_scope("attention"):
                     a = L.masked_chunk_attention(
                         cfg, p["attn"], hn, positions, ak, av, full_mask)
@@ -650,28 +654,12 @@ class DecoderLM:
             with jax.named_scope("mlp"):
                 h = h + L.apply_mlp(cfg, p["mlp"],
                                     L.apply_norm(cfg, p["ln2"], h))
-            if ring:
-                return h, (pk, pv, rk, rv)
-            return h, (pk, pv)
+            return h, (pk, pv, rk, rv)
 
+        x, new_cache = self._scan_paged(self_body, x, params, cache)
         if ring:
-            x, (pks, pvs, rks, rvs) = self._scan(
-                self_body, x,
-                (params["blocks"], cache["pages_k"], cache["pages_v"],
-                 cache["ring_k"], cache["ring_v"]),
-            )
             with jax.named_scope("kv_write"):
-                new_cache = PG.ring_commit(
-                    dict(cache, pages_k=pks, pages_v=pvs, ring_k=rks,
-                         ring_v=rvs),
-                    start, unload_mask,
-                )
-        else:
-            x, (pks, pvs) = self._scan(
-                self_body, x,
-                (params["blocks"], cache["pages_k"], cache["pages_v"]),
-            )
-            new_cache = dict(cache, pages_k=pks, pages_v=pvs)
+                new_cache = PG.ring_commit(new_cache, start, unload_mask)
 
         with jax.named_scope("head"):
             if all_logits:

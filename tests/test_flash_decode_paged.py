@@ -67,19 +67,26 @@ def _assert_ulp_close(actual, desired):
                                atol=2e-6, rtol=1e-4)
 
 
+# every kernel case reads a stacked pool of this many layers; reading the
+# last layer as well as the first proves the index map selects the layer
+N_LAYERS = 3
+LAYERS = [0, N_LAYERS - 1]
+
+
 def _rand_inputs(rng, b, c, hq, hkv, d, nb, ps, p, r):
     q = jnp.asarray(rng.randn(b, c, hq, d), jnp.float32)
-    pk = jnp.asarray(rng.randn(nb, ps, hkv, d), jnp.float32)
-    pv = jnp.asarray(rng.randn(nb, ps, hkv, d), jnp.float32)
+    pk = jnp.asarray(rng.randn(N_LAYERS, nb, ps, hkv, d), jnp.float32)
+    pv = jnp.asarray(rng.randn(N_LAYERS, nb, ps, hkv, d), jnp.float32)
     blocks = jnp.asarray(rng.randint(0, nb, (b, p)), jnp.int32)
     view_ok = jnp.asarray(rng.rand(b, c, p * ps) > 0.35)
     ring = None
     if r:
-        ring = (jnp.asarray(rng.randn(b, r, hkv, d), jnp.float32),
-                jnp.asarray(rng.randn(b, r, hkv, d), jnp.float32))
+        ring = (jnp.asarray(rng.randn(N_LAYERS, b, r, hkv, d), jnp.float32),
+                jnp.asarray(rng.randn(N_LAYERS, b, r, hkv, d), jnp.float32))
     return q, pk, pv, blocks, view_ok, ring
 
 
+@pytest.mark.parametrize("layer", LAYERS)
 @pytest.mark.parametrize("b,c,hq,hkv,d,nb,ps,p,r", [
     (2, 1, 4, 4, 16, 8, 4, 4, 0),     # step decode, MHA, no ring
     (2, 1, 4, 2, 16, 8, 4, 4, 8),     # step decode, GQA group 2 + ring
@@ -88,7 +95,7 @@ def _rand_inputs(rng, b, c, hq, hkv, d, nb, ps, p, r):
     (2, 8, 4, 4, 8, 10, 4, 5, 2),     # chunk C=8, small ring
     (1, 3, 6, 3, 16, 9, 2, 6, 6),     # odd page size / group 2
 ])
-def test_kernel_matches_oracle(b, c, hq, hkv, d, nb, ps, p, r):
+def test_kernel_matches_oracle(b, c, hq, hkv, d, nb, ps, p, r, layer):
     rng = np.random.RandomState(b * 100 + c * 10 + hq)
     q, pk, pv, blocks, view_ok, ring = _rand_inputs(
         rng, b, c, hq, hkv, d, nb, ps, p, r)
@@ -97,9 +104,10 @@ def test_kernel_matches_oracle(b, c, hq, hkv, d, nb, ps, p, r):
         args = (*ring, ring_ok)
     else:
         args = (None, None, None)
-    out = flash_decode_paged(q, pk, pv, blocks, view_ok, *args,
+    out = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, *args,
                              interpret=True)
-    expected = ref.flash_decode_paged_ref(q, pk, pv, blocks, view_ok, *args)
+    expected = ref.flash_decode_paged_ref(q, pk, pv, layer, blocks, view_ok,
+                                          *args)
     _assert_ulp_close(out, expected)
 
 
@@ -125,14 +133,16 @@ def test_kernel_ring_states(state, c):
     q, pk, pv, blocks, view_ok, ring = _rand_inputs(
         rng, b, c, hq, hkv, d, nb, ps, p, r)
     ring_ok = jnp.asarray(RING_STATES[state](b, r, rng))
-    out = flash_decode_paged(q, pk, pv, blocks, view_ok, *ring, ring_ok,
-                             interpret=True)
-    expected = ref.flash_decode_paged_ref(q, pk, pv, blocks, view_ok,
+    layer = N_LAYERS - 1
+    out = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, *ring,
+                             ring_ok, interpret=True)
+    expected = ref.flash_decode_paged_ref(q, pk, pv, layer, blocks, view_ok,
                                           *ring, ring_ok)
     _assert_ulp_close(out, expected)
 
 
-def test_kernel_dead_slot_and_unallocated_pages():
+@pytest.mark.parametrize("layer", LAYERS)
+def test_kernel_dead_slot_and_unallocated_pages(layer):
     """Fully-masked rows (retired slots) and clamped unallocated pages:
     the kernel walks block 0's garbage exactly like the clamped reference
     gather, so even degenerate outputs agree."""
@@ -145,9 +155,9 @@ def test_kernel_dead_slot_and_unallocated_pages():
         np.stack([np.ones((c, p * ps), bool), np.zeros((c, p * ps), bool)]))
     ring_ok = jnp.asarray([[True, False, True, False],
                            [False, False, False, False]])
-    out = flash_decode_paged(q, pk, pv, blocks, view_ok, *ring, ring_ok,
-                             interpret=True)
-    expected = ref.flash_decode_paged_ref(q, pk, pv, blocks, view_ok,
+    out = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, *ring,
+                             ring_ok, interpret=True)
+    expected = ref.flash_decode_paged_ref(q, pk, pv, layer, blocks, view_ok,
                                           *ring, ring_ok)
     _assert_ulp_close(out, expected)
 
@@ -163,13 +173,16 @@ def test_oracle_matches_reference_core_bitwise():
     q, pk, pv, blocks, view_ok, ring = _rand_inputs(
         rng, b, c, hq, hkv, d, nb, ps, p, r)
     ring_ok = jnp.asarray(rng.rand(b, r) > 0.4)
+    layer = 1
 
     rows = (np.asarray(blocks)[:, :, None] * ps
             + np.arange(ps)[None, None]).reshape(b, -1)
     k = jnp.concatenate(
-        [PG.gather_view(pk, jnp.asarray(rows, jnp.int32)), ring[0]], axis=1)
+        [PG.gather_view(pk[layer], jnp.asarray(rows, jnp.int32)),
+         ring[0][layer]], axis=1)
     v = jnp.concatenate(
-        [PG.gather_view(pv, jnp.asarray(rows, jnp.int32)), ring[1]], axis=1)
+        [PG.gather_view(pv[layer], jnp.asarray(rows, jnp.int32)),
+         ring[1][layer]], axis=1)
     mask = jnp.concatenate(
         [view_ok, jnp.broadcast_to(ring_ok[:, None], (b, c, r))], axis=2)
     reps = hq // hkv
@@ -182,10 +195,10 @@ def test_oracle_matches_reference_core_bitwise():
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     core = jnp.einsum("bhst,bthk->bshk", probs, vf)
 
-    oracle = ref.flash_decode_paged_ref(q, pk, pv, blocks, view_ok,
+    oracle = ref.flash_decode_paged_ref(q, pk, pv, layer, blocks, view_ok,
                                         *ring, ring_ok)
-    kernel = flash_decode_paged(q, pk, pv, blocks, view_ok, *ring, ring_ok,
-                                interpret=True)
+    kernel = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, *ring,
+                                ring_ok, interpret=True)
     np.testing.assert_array_equal(np.asarray(core), np.asarray(oracle))
     _assert_ulp_close(kernel, core)
 

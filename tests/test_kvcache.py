@@ -119,8 +119,7 @@ def test_paged_pool_insert_gather_roundtrip():
     for t in range(10):
         k = jnp.asarray(rng.randn(3, 2, 8), jnp.float32)
         dest = logical_to_physical(cache, jnp.full((3,), t, jnp.int32))
-        cache["pages_k"] = cache["pages_k"].at[0].set(
-            scatter_token(cache["pages_k"][0], dest, k))
+        cache["pages_k"] = scatter_token(cache["pages_k"], 0, dest, k)
         ref[:, t] = np.asarray(k)
     vm = view_mask(cache, jnp.full((3,), 9, jnp.int32))
     assert vm.tolist()[0] == [True] * 10 + [False] * 2 + [False] * 4
@@ -145,6 +144,6 @@ def test_paged_destination_mapping_and_write_masking():
     dead = logical_to_physical(cache, jnp.asarray([-1, 4], jnp.int32))
     assert dead.tolist() == [pool_rows(cache)] * 2
     before = np.asarray(cache["pages_k"][0])
-    cache["pages_k"] = cache["pages_k"].at[0].set(scatter_token(
-        cache["pages_k"][0], dead, jnp.ones((2, 1, 4), jnp.float32)))
+    cache["pages_k"] = scatter_token(cache["pages_k"], 0, dead,
+                                     jnp.ones((2, 1, 4), jnp.float32))
     np.testing.assert_array_equal(np.asarray(cache["pages_k"][0]), before)

@@ -308,24 +308,25 @@ def test_flash_decode_paged_sharded_matches_unsharded():
     tp = min(2, jax.device_count())
     mesh = ParallelConfig.tensor(tp).build_mesh()
     rng = np.random.default_rng(0)
-    b, c, hq, hkv, d, nb, ps, npg, r = 2, 1, 4, 2, 8, 6, 4, 3, 4
+    nl, b, c, hq, hkv, d, nb, ps, npg, r = 3, 2, 1, 4, 2, 8, 6, 4, 3, 4
     q = rng.standard_normal((b, c, hq, d)).astype(np.float32)
-    pk = rng.standard_normal((nb, ps, hkv, d)).astype(np.float32)
-    pv = rng.standard_normal((nb, ps, hkv, d)).astype(np.float32)
+    pk = rng.standard_normal((nl, nb, ps, hkv, d)).astype(np.float32)
+    pv = rng.standard_normal((nl, nb, ps, hkv, d)).astype(np.float32)
     blocks = rng.integers(0, nb, (b, npg)).astype(np.int32)
     ok = rng.random((b, c, npg * ps)) < 0.7
-    rk = rng.standard_normal((b, r, hkv, d)).astype(np.float32)
-    rv = rng.standard_normal((b, r, hkv, d)).astype(np.float32)
+    rk = rng.standard_normal((nl, b, r, hkv, d)).astype(np.float32)
+    rv = rng.standard_normal((nl, b, r, hkv, d)).astype(np.float32)
     rok = rng.random((b, r)) < 0.5
     ok[:, :, 0] = True                         # softmax needs >= 1 source
-    ref = flash_decode_paged(q, pk, pv, blocks, ok, rk, rv, rok,
+    layer = nl - 1
+    ref = flash_decode_paged(q, pk, pv, layer, blocks, ok, rk, rv, rok,
                              interpret=True)
-    got = flash_decode_paged_sharded(mesh, q, pk, pv, blocks, ok, rk, rv,
-                                     rok, interpret=True)
+    got = flash_decode_paged_sharded(mesh, q, pk, pv, layer, blocks, ok, rk,
+                                     rv, rok, interpret=True)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
     with pytest.raises(ValueError, match="divide"):
         flash_decode_paged_sharded(
-            mesh, q[:, :, :3], pk, pv, blocks, ok, rk, rv, rok,
+            mesh, q[:, :, :3], pk, pv, layer, blocks, ok, rk, rv, rok,
             interpret=True)
 
 

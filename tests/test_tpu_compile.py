@@ -61,15 +61,18 @@ def _shape(shape, dtype, sharding):
 
 
 def _paged_args(c, ring, shard_of):
+    """Operands of the read kernel as serving passes them: the whole
+    stacked pool and ring (every layer) and the layer index to read."""
     bf16 = jnp.bfloat16
     args = [_shape((B, c, HQ, D), bf16, shard_of("heads4")),
-            _shape((N_BLOCKS, PAGE, HKV, D), bf16, shard_of("heads4")),
-            _shape((N_BLOCKS, PAGE, HKV, D), bf16, shard_of("heads4")),
+            _shape((LAYERS, N_BLOCKS, PAGE, HKV, D), bf16, shard_of("heads5")),
+            _shape((LAYERS, N_BLOCKS, PAGE, HKV, D), bf16, shard_of("heads5")),
+            _shape((), jnp.int32, shard_of("rep0")),
             _shape((B, PAGES), jnp.int32, shard_of("rep2")),
             _shape((B, c, PAGES * PAGE), jnp.bool_, shard_of("rep3"))]
     if ring:
-        args += [_shape((B, RING, HKV, D), bf16, shard_of("heads4")),
-                 _shape((B, RING, HKV, D), bf16, shard_of("heads4")),
+        args += [_shape((LAYERS, B, RING, HKV, D), bf16, shard_of("heads5")),
+                 _shape((LAYERS, B, RING, HKV, D), bf16, shard_of("heads5")),
                  _shape((B, RING), jnp.bool_, shard_of("rep2"))]
     return args
 
@@ -94,8 +97,9 @@ def test_flash_decode_paged_compiles_for_v5e(one_chip, c, ring):
 def test_flash_decode_paged_sharded_compiles_for_v5e_2x2(topo):
     """Head-sharded pool on four chips: one kernel per shard."""
     mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("data", "model"))
-    specs = {"heads4": P(None, None, "model", None), "rep2": P(None, None),
-             "rep3": P(None, None, None)}
+    specs = {"heads4": P(None, None, "model", None),
+             "heads5": P(None, None, None, "model", None), "rep0": P(),
+             "rep2": P(None, None), "rep3": P(None, None, None)}
     args = _paged_args(1, True, lambda k: NamedSharding(mesh, specs[k]))
     _assert_kernel_compiles(
         lambda *a: flash_decode_paged_sharded(mesh, *a, interpret=False),
