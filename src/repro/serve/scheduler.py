@@ -86,6 +86,10 @@ from .trace import SegmentLog, phase
 PHASE_PREFILL = 0
 PHASE_DECODE = 1
 
+# a segment's device counters, read back once: direct and staged KV rows,
+# drains, prefill rows, then the read's walk (``read_walk``)
+N_STATS = 6
+
 
 def paged_capable(model) -> bool:
     """Can this model serve from the paged pool? Linear-addressed dense
@@ -94,6 +98,18 @@ def paged_capable(model) -> bool:
     return (isinstance(model, DecoderLM)
             and not model.is_vlm
             and not model.cfg.sliding_window)
+
+
+def read_walk(context: jnp.ndarray, active: jnp.ndarray, page_size: int,
+              max_pages: int) -> jnp.ndarray:
+    """One step's read of the paged pool, in one layer: [pages the read's
+    grid visits, the pages among them holding context of an active query].
+    The grid (``flash_decode_paged``, and the reference view alike) walks
+    every page of every slot's table; ``context`` [n_slots] is the rows a
+    slot's queries attend to this step."""
+    live = jnp.minimum((context + page_size - 1) // page_size, max_pages)
+    return jnp.stack([jnp.int32(active.shape[0] * max_pages),
+                      jnp.sum(jnp.where(active, live, 0))])
 
 
 class SlotState(NamedTuple):
@@ -381,6 +397,9 @@ class BatchedServeEngine:
             "prefix_hit_rows": 0, "cow_copies": 0,
             "host_unloads": 0, "host_pageins": 0,
             "preemptions": 0, "preempt_resumes": 0,
+            # the paged read's walk, per layer, summed over steps: pages
+            # its grid visits, and those holding an active query's context
+            "read_pages_walked": 0, "read_pages_live": 0,
             # speculative-decoding telemetry (SpecStats, core.types):
             # committed/rounds is the acceptance-weighted committed
             # tokens per target verify step — the headline BENCH metric
@@ -526,6 +545,8 @@ class BatchedServeEngine:
         def step(params, enabled, plan, carry, _):
             cache, st, mon, stats, swrites = carry
             active = ~st.done & enabled
+            walk = (read_walk(st.pos + 1, active, ps, mp)
+                    if paged else jnp.zeros((2,), jnp.int32))
             if paged:
                 dest = PG.logical_to_physical(
                     cache, jnp.where(active, st.pos, -1))
@@ -576,12 +597,12 @@ class BatchedServeEngine:
                 remaining=remaining,
                 key=key,
             )
-            stats = stats + jnp.stack([
+            stats = stats + jnp.concatenate([jnp.stack([
                 jnp.sum(active.astype(jnp.int32)) - n_u,
                 n_u,
                 drained.astype(jnp.int32),
                 jnp.zeros((), jnp.int32),
-            ])
+            ]), walk])
             swrites = swrites + jnp.stack([
                 (active & ~unload).astype(jnp.int32),
                 unload.astype(jnp.int32),
@@ -595,7 +616,7 @@ class BatchedServeEngine:
             # host-side, between segments): derive them ONCE here, outside
             # the scan, instead of once per step per layer
             plan = PG.step_plan(cache) if paged else None
-            stats0 = jnp.zeros((4,), jnp.int32)
+            stats0 = jnp.zeros((N_STATS,), jnp.int32)
             sw0 = jnp.zeros((cfg.n_slots, 3), jnp.int32)
             (cache, st, mon, stats, swrites), (emits, acts) = lax.scan(
                 lambda c, x: step(params, enabled, plan, c, x),
@@ -633,6 +654,7 @@ class BatchedServeEngine:
         model, cfg = self.model, self.cfg
         ring = self.uses_ring
         ps, nb, c = cfg.page_size, self.n_blocks, cfg.chunk_size
+        mp = self.max_pages
         decision = self.decision
         attn = self.attention
         dk = self._drain_kernel
@@ -671,6 +693,7 @@ class BatchedServeEngine:
             n_u = jnp.sum(unload.astype(jnp.int32))
             n_dec = jnp.sum((active & ~is_pf).astype(jnp.int32))
             n_pf = jnp.sum((qvalid & is_pf[:, None]).astype(jnp.int32))
+            walk = read_walk(st.pos + n_valid, active, ps, mp)
             drained = jnp.zeros((), jnp.bool_)
             if ring:
                 cache, drained = PG.maybe_drain(
@@ -709,8 +732,8 @@ class BatchedServeEngine:
                 remaining=remaining,
                 key=key,
             )
-            stats = stats + jnp.stack(
-                [n_dec - n_u, n_u, drained.astype(jnp.int32), n_pf])
+            stats = stats + jnp.concatenate([jnp.stack(
+                [n_dec - n_u, n_u, drained.astype(jnp.int32), n_pf]), walk])
             swrites = swrites + jnp.stack([
                 (dec & ~unload).astype(jnp.int32),
                 unload.astype(jnp.int32),
@@ -722,7 +745,7 @@ class BatchedServeEngine:
         def segment_mixed(params, cache, st, mon, prompts, enabled):
             # per-segment hoist of page-table products (see _build_segment)
             plan = PG.step_plan(cache)
-            stats0 = jnp.zeros((4,), jnp.int32)
+            stats0 = jnp.zeros((N_STATS,), jnp.int32)
             sw0 = jnp.zeros((cfg.n_slots, 3), jnp.int32)
             (cache, st, mon, stats, swrites), (emits, ems) = lax.scan(
                 lambda cry, x: step(params, prompts, enabled, plan, cry, x),
@@ -764,7 +787,7 @@ class BatchedServeEngine:
         k = self.spec.k
         dmodel = self.draft_model
         positional = self._draft_positional
-        ps, nb = cfg.page_size, self.n_blocks
+        ps, nb, mp = cfg.page_size, self.n_blocks, self.max_pages
         decision = self.decision
         attn = self.attention
         mesh, cache_shardings = self.mesh, self._cache_shardings
@@ -806,6 +829,7 @@ class BatchedServeEngine:
             chunk = jnp.concatenate([st.token[:, None], d_tokens], axis=1)
             n_valid = jnp.where(
                 active, jnp.minimum(k + 1, st.remaining), 0)
+            walk = read_walk(st.pos + n_valid, active, ps, mp)
             offs = jnp.arange(k + 1, dtype=jnp.int32)[None, :]
             qvalid = offs < n_valid[:, None]
             rows = st.pos[:, None] + offs
@@ -861,7 +885,8 @@ class BatchedServeEngine:
             )
             n_active = jnp.sum(active.astype(jnp.int32))
             z = jnp.zeros((), jnp.int32)
-            stats = stats + jnp.stack([jnp.sum(n_valid), z, z, z])
+            stats = stats + jnp.concatenate([
+                jnp.stack([jnp.sum(n_valid), z, z, z]), walk])
             sstats = sstats + jnp.stack([
                 k * n_active,
                 jnp.sum(jnp.minimum(jnp.where(active, n_acc, 0), n_emit)),
@@ -877,7 +902,7 @@ class BatchedServeEngine:
 
         def segment_spec(params, dpar, cache, dcache, st, mon, enabled):
             plan = PG.step_plan(cache)
-            stats0 = jnp.zeros((4,), jnp.int32)
+            stats0 = jnp.zeros((N_STATS,), jnp.int32)
             sst0 = jnp.zeros((4,), jnp.int32)
             sw0 = jnp.zeros((cfg.n_slots, 3), jnp.int32)
             ((cache, dcache, st, mon, stats, sstats, swrites),
@@ -1745,11 +1770,13 @@ class BatchedServeEngine:
         spec_ran = kind == "spec"
         emits, acts = np.asarray(emits), np.asarray(acts)
         swrites = np.asarray(swrites)
-        d, s, dr, pf = (int(x) for x in stats)
+        d, s, dr, pf, walked, live = (int(x) for x in stats)
         self.stats["direct_writes"] += d
         self.stats["staged_writes"] += s
         self.stats["drains"] += dr
         self.stats["prefill_writes"] += pf
+        self.stats["read_pages_walked"] += walked
+        self.stats["read_pages_live"] += live
         self.stats["segments"] += 1
         if spec_ran:
             sp, sa, sc, sr = (int(x) for x in sstats)
