@@ -55,7 +55,8 @@ def _bench(fn, args, repeats: int) -> float:
 def _operands(rng, b, c, hq, hkv, d, nb, ps, p, r):
     """Serving-shaped operands: a warm two-layer pool read at its last
     layer, a partially-filled ring, and a block table with the allocation
-    raggedness real slots have."""
+    raggedness real slots have, each slot's walk bounded at its last
+    allocated page as serving bounds it."""
     n_layers = 2
     q = jnp.asarray(rng.randn(b, c, hq, d), jnp.float32)
     pk = jnp.asarray(rng.randn(n_layers, nb, ps, hkv, d), jnp.float32)
@@ -72,10 +73,11 @@ def _operands(rng, b, c, hq, hkv, d, nb, ps, p, r):
     view_ok = jnp.asarray(
         np.repeat(table >= 0, ps, axis=1)[:, None, :]
         & (rng.rand(b, c, p * ps) > 0.1))
+    bound = jnp.asarray((table >= 0).sum(axis=1), jnp.int32)
     ring_k = jnp.asarray(rng.randn(n_layers, b, r, hkv, d), jnp.float32)
     ring_v = jnp.asarray(rng.randn(n_layers, b, r, hkv, d), jnp.float32)
     ring_ok = jnp.asarray(np.arange(r)[None, :] < rng.randint(1, r + 1, (b, 1)))
-    return q, pk, pv, layer, blocks, view_ok, ring_k, ring_v, ring_ok
+    return q, pk, pv, layer, blocks, view_ok, bound, ring_k, ring_v, ring_ok
 
 
 def bench_decode_kernel(repeats: int = 20) -> dict:

@@ -172,10 +172,11 @@ def kernel_checks(cfg, mesh=None) -> None:
         ref,
         staged_scatter,
     )
+    from repro.kvcache import paged as PG
 
     hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     n_blocks, pages = N_SLOTS * MAX_SEQ // PAGE, MAX_SEQ // PAGE
-    keys = jax.random.split(jax.random.key(SEED + 1), 8)
+    keys = jax.random.split(jax.random.key(SEED + 1), 9)
     bf16 = jnp.bfloat16
     if mesh is None:
         read, where = flash_decode_paged, ""
@@ -198,23 +199,32 @@ def kernel_checks(cfg, mesh=None) -> None:
     pool_v = jax.random.normal(keys[1], (n_layers, n_blocks, PAGE, hkv, d),
                                bf16)
     blocks = jax.random.randint(keys[2], (N_SLOTS, pages), 0, n_blocks)
+    # each slot's context, from one row to the whole table, bounds its
+    # walk as serving bounds it
+    context = (jax.random.randint(keys[8], (N_SLOTS,), 1, MAX_SEQ + 1)
+               .at[0].set(1).at[1].set(MAX_SEQ))
+    bound = PG.page_bound(context, context > 0, PAGE, pages)
+    rows = jnp.arange(pages * PAGE)
     for c, ring in ((1, True), (CHUNK, False)):
         q = jax.random.normal(keys[3], (N_SLOTS, c, hq, d), bf16)
-        view_ok = jax.random.bernoulli(keys[4], 0.7,
-                                       (N_SLOTS, c, pages * PAGE))
+        view_ok = (jax.random.bernoulli(keys[4], 0.7,
+                                        (N_SLOTS, c, pages * PAGE))
+                   & (rows < context[:, None, None])).at[:, :, 0].set(True)
         extra = (None, None, None)
         if ring:
             ring_shape = (n_layers, N_SLOTS, RING, hkv, d)
             extra = (jax.random.normal(keys[5], ring_shape, bf16),
                      jax.random.normal(keys[6], ring_shape, bf16),
                      jax.random.bernoulli(keys[7], 0.5, (N_SLOTS, RING)))
-        heads = (True, True, True, False, False, False, True, True, False)
+        heads = (True, True, True, False, False, False, False, True, True,
+                 False)
         lay = jnp.asarray(layer, jnp.int32)
         args = [x if x is None else put(x, h) for x, h in
-                zip((q, pool_k, pool_v, lay, blocks, view_ok) + extra, heads)]
+                zip((q, pool_k, pool_v, lay, blocks, view_ok, bound) + extra,
+                    heads)]
         got = read(*args)
         want = jax.jit(ref.flash_decode_paged_ref)(
-            q, pool_k, pool_v, lay, blocks, view_ok, *extra)
+            q, pool_k, pool_v, lay, blocks, view_ok, bound, *extra)
         got = np.asarray(got, np.float32)
         want = np.asarray(want, np.float32)
         err = float(np.max(np.abs(got - want)))

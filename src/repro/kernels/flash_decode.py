@@ -71,6 +71,7 @@ def _paged_kernel(
     # scalar prefetch
     tab_ref,               # int32 [B, P] physical block ids (clamped >= 0)
     layer_ref,             # int32 [1] the layer of the pool to read
+    bound_ref,             # int32 [B] pages that can hold each slot's context
     # inputs
     q_ref,                 # [1, Hkv, G*C, D] queries, grouped by KV head
     k_ref,                 # [1, ps, Hkv, D] one physical KV block, all heads
@@ -89,6 +90,12 @@ def _paged_kernel(
     staging-ring lanes join at the last page as a second KV source; the
     output is the weighted sum over the denominator. Nothing in VMEM grows
     with the context length.
+
+    Pages at or past a slot's bound hold none of its context: their steps
+    fold nothing (and their index maps repeat the last page's block, so
+    no DMA is issued). Once a query has seen a valid row a fully masked
+    page is an exact no-op of the online softmax, so stopping there
+    gives the full walk's bits. A slot with bound 0 still folds page 0.
     """
     if ring:
         rk_ref, rv_ref, rm_ref, o_ref, mx_ref, l_ref, acc_ref = rest
@@ -118,7 +125,9 @@ def _paged_kernel(
             preferred_element_type=jnp.float32)
         mx_ref[...] = m_new
 
-    fold(k_ref[0], v_ref[0], m_ref[0, 0] != 0)
+    @pl.when(j < jnp.maximum(bound_ref[pl.program_id(0)], 1))
+    def _page():
+        fold(k_ref[0], v_ref[0], m_ref[0, 0] != 0)
 
     @pl.when(j == n_pages - 1)
     def _finalize():
@@ -134,6 +143,7 @@ def flash_decode_paged(
     layer: jnp.ndarray,     # int32 scalar: the layer of pool and ring to read
     blocks: jnp.ndarray,    # int32 [B, P] per-slot physical block ids (>= 0)
     view_ok: jnp.ndarray,   # bool [B, C, P*ps] paged-view validity mask
+    bound: jnp.ndarray,     # int32 [B] pages to walk per slot
     ring_k: jnp.ndarray | None = None,   # [L, B, R, Hkv, D] staging ring
     ring_v: jnp.ndarray | None = None,
     ring_ok: jnp.ndarray | None = None,  # bool [B, R] lane validity
@@ -150,6 +160,11 @@ def flash_decode_paged(
     same softmax as a second source. Nothing is gathered or overlaid in
     HBM first — the read-side twin of ``staged_scatter``. Returns
     [B, C, Hq, D].
+
+    ``bound`` (``kvcache.paged.page_bound``) is each slot's number of
+    pages that can hold context: a third scalar-prefetch operand that
+    stops the slot's walk there (a bound of ``P`` walks the whole table).
+    Every row ``view_ok`` marks valid for a query must lie below it.
 
     TPU tiling: every block's last two dims are whole array dims (heads x
     head_dim, query rows x page rows), so any page size, head count and
@@ -175,23 +190,32 @@ def flash_decode_paged(
             .astype(jnp.int32))
     mask = jnp.tile(mask, (1, 1, group, 1))            # [B, P, G*C, ps]
 
+    def page(b_, j, bnd):
+        # steps past the slot's last page repeat its block: no DMA
+        return jnp.minimum(j, jnp.maximum(bnd[b_], 1) - 1)
+
     kv_spec = pl.BlockSpec(
         (pl.Squeezed(), 1, ps, hkv, d),
-        lambda b_, j, tab, lay: (lay[0], tab[b_, j], 0, 0, 0))
+        lambda b_, j, tab, lay, bnd: (lay[0], tab[b_, page(b_, j, bnd)],
+                                      0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, hkv, gc, d), lambda b_, j, tab, lay: (b_, 0, 0, 0)),
+        pl.BlockSpec((1, hkv, gc, d),
+                     lambda b_, j, tab, lay, bnd: (b_, 0, 0, 0)),
         kv_spec,
         kv_spec,
-        pl.BlockSpec((1, 1, gc, ps), lambda b_, j, tab, lay: (b_, j, 0, 0)),
+        pl.BlockSpec((1, 1, gc, ps),
+                     lambda b_, j, tab, lay, bnd: (b_, page(b_, j, bnd),
+                                                   0, 0)),
     ]
     args = [qg, pages_k, pages_v, mask]
     if ring:
         lane_spec = pl.BlockSpec(
             (pl.Squeezed(), 1, r, hkv, d),
-            lambda b_, j, tab, lay: (lay[0], b_, 0, 0, 0))
+            lambda b_, j, tab, lay, bnd: (lay[0], b_, 0, 0, 0))
         in_specs += [
             lane_spec, lane_spec,
-            pl.BlockSpec((1, 1, r), lambda b_, j, tab, lay: (b_, 0, 0)),
+            pl.BlockSpec((1, 1, r),
+                         lambda b_, j, tab, lay, bnd: (b_, 0, 0)),
         ]
         args += [ring_k, ring_v, ring_ok.astype(jnp.int32).reshape(b, 1, r)]
 
@@ -200,11 +224,11 @@ def flash_decode_paged(
             _paged_kernel, n_pages=n_pages, scale=d ** -0.5, ring=ring,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, n_pages),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (1, hkv, gc, d), lambda b_, j, tab, lay: (b_, 0, 0, 0)),
+                (1, hkv, gc, d), lambda b_, j, tab, lay, bnd: (b_, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((hkv, gc, 1), jnp.float32),   # running max
                 pltpu.VMEM((hkv, gc, 1), jnp.float32),   # denominator
@@ -215,7 +239,8 @@ def flash_decode_paged(
         interpret=interpret,
         name="flash_decode_paged",
     )
-    out = fn(blocks, jnp.reshape(layer, (1,)).astype(jnp.int32), *args)
+    out = fn(blocks, jnp.reshape(layer, (1,)).astype(jnp.int32),
+             bound.astype(jnp.int32), *args)
     return (out.reshape(b, hkv, group, c, d).transpose(0, 3, 1, 2, 4)
             .reshape(b, c, hq, d))
 
@@ -228,6 +253,7 @@ def flash_decode_paged_sharded(
     layer: jnp.ndarray,     # int32 scalar              (replicated)
     blocks: jnp.ndarray,    # int32 [B, P]              (replicated)
     view_ok: jnp.ndarray,   # bool [B, C, P*ps]         (replicated)
+    bound: jnp.ndarray,     # int32 [B]                 (replicated)
     ring_k: jnp.ndarray | None = None,   # [L, B, R, Hkv, D]
     ring_v: jnp.ndarray | None = None,
     ring_ok: jnp.ndarray | None = None,  # bool [B, R]   (replicated)
@@ -243,8 +269,8 @@ def flash_decode_paged_sharded(
     Both Hq and Hkv must divide the ``axis`` size so every shard holds
     whole GQA groups — softmax is per-q-head, so no cross-shard combine
     is needed and the result is bitwise the unsharded kernel's. Routing
-    inputs (``layer``, ``blocks``, ``view_ok``, ``ring_ok``) are
-    replicated; KV-carrying tensors split on their head axis (axis 2 of
+    inputs (``layer``, ``blocks``, ``view_ok``, ``bound``, ``ring_ok``)
+    are replicated; KV-carrying tensors split on their head axis (axis 2 of
     the queries, axis 3 of the stacked pool and ring).
     """
     from jax.sharding import PartitionSpec as P
@@ -252,7 +278,7 @@ def flash_decode_paged_sharded(
     tp = mesh.shape[axis]
     if tp == 1:
         return flash_decode_paged(q, pages_k, pages_v, layer, blocks,
-                                  view_ok, ring_k, ring_v, ring_ok,
+                                  view_ok, bound, ring_k, ring_v, ring_ok,
                                   interpret=interpret)
     hq, hkv = q.shape[2], pages_k.shape[3]
     if hq % tp or hkv % tp:
@@ -263,9 +289,9 @@ def flash_decode_paged_sharded(
     q_spec = P(None, None, axis, None)
     kv_spec = P(None, None, None, axis, None)
     in_specs = [q_spec, kv_spec, kv_spec, P(), P(None, None),
-                P(None, None, None)]
+                P(None, None, None), P(None)]
     args = [q, pages_k, pages_v, jnp.asarray(layer, jnp.int32), blocks,
-            view_ok]
+            view_ok, bound]
     ring = ring_k is not None
     if ring:
         in_specs += [kv_spec, kv_spec, P(None, None)]

@@ -94,11 +94,12 @@ def flash_decode(q, k, v, kv_mask, impl: str = "auto", block_k: int = 512):
 
 
 @partial(jax.jit, static_argnames=("impl",))
-def flash_decode_paged(q, pages_k, pages_v, layer, blocks, view_ok,
+def flash_decode_paged(q, pages_k, pages_v, layer, blocks, view_ok, bound,
                        ring_k=None, ring_v=None, ring_ok=None,
                        impl: str = "auto"):
     """Fused paged decode: page-table walk + staging-ring overlay + SDPA,
-    reading layer ``layer`` of the stacked pool and ring.
+    reading layer ``layer`` of the stacked pool and ring, each slot's walk
+    stopped at its ``bound`` (``kvcache.paged.page_bound``).
 
     Unlike ``flash_decode``, ``auto`` does NOT silently fall back to the
     oracle on CPU: which implementation serves decode is a negotiated
@@ -108,20 +109,20 @@ def flash_decode_paged(q, pages_k, pages_v, layer, blocks, view_ok,
     """
     if impl == "ref":
         return ref.flash_decode_paged_ref(
-            q, pages_k, pages_v, layer, blocks, view_ok, ring_k, ring_v,
-            ring_ok)
+            q, pages_k, pages_v, layer, blocks, view_ok, bound, ring_k,
+            ring_v, ring_ok)
     return _flash_decode_paged_kernel(
-        q, pages_k, pages_v, layer, blocks, view_ok, ring_k, ring_v, ring_ok,
-        interpret=_on_cpu())
+        q, pages_k, pages_v, layer, blocks, view_ok, bound, ring_k, ring_v,
+        ring_ok, interpret=_on_cpu())
 
 
 def flash_decode_paged_sharded(mesh, q, pages_k, pages_v, layer, blocks,
-                               view_ok, ring_k=None, ring_v=None,
+                               view_ok, bound, ring_k=None, ring_v=None,
                                ring_ok=None, axis: str = "model"):
     """Head-sharded ``flash_decode_paged``: one kernel instance per mesh
     shard over its own head slice (``shard_map``); routing inputs
     replicated. Requires whole GQA groups per shard — see the kernel
     module's wrapper for the divisibility contract."""
     return _flash_decode_paged_sharded_kernel(
-        mesh, q, pages_k, pages_v, layer, blocks, view_ok, ring_k, ring_v,
-        ring_ok, axis=axis, interpret=_on_cpu())
+        mesh, q, pages_k, pages_v, layer, blocks, view_ok, bound, ring_k,
+        ring_v, ring_ok, axis=axis, interpret=_on_cpu())

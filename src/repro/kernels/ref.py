@@ -109,6 +109,7 @@ def flash_decode_paged_ref(
     layer: jnp.ndarray,    # int32 scalar: the layer to read
     blocks: jnp.ndarray,   # int32 [B, P] physical block ids (clamped >= 0)
     view_ok: jnp.ndarray,  # bool [B, C, P*ps]
+    bound: jnp.ndarray,    # int32 [B] the kernel's walk per slot, unused
     ring_k: jnp.ndarray | None = None,   # [L, B, R, Hkv, D]
     ring_v: jnp.ndarray | None = None,
     ring_ok: jnp.ndarray | None = None,  # bool [B, R]
@@ -119,7 +120,10 @@ def flash_decode_paged_ref(
     exact ``layers._sdpa_once`` op order (fp32 logits -> mask -> softmax ->
     dtype cast -> weighted sum) so the kernel can be held to ulp-level
     fp32 equality (same op order; XLA's shape-dependent GEMM tiling keeps
-    the two graphs ~1e-7 apart — DESIGN.md §7)."""
+    the two graphs ~1e-7 apart — DESIGN.md §7). It gathers the whole
+    table and ignores the kernel's per-slot page ``bound``: pages past it
+    hold only masked rows."""
+    del bound
     b, c, hq, d = q.shape
     pages_k, pages_v = pages_k[layer], pages_v[layer]
     if ring_k is not None:

@@ -460,6 +460,18 @@ def kernel_blocks(cache: PagedKV) -> jnp.ndarray:
     return jnp.maximum(cache["page_table"], 0).astype(jnp.int32)
 
 
+def page_bound(context: jnp.ndarray, active: jnp.ndarray, page_size: int,
+               max_pages: int) -> jnp.ndarray:
+    """int32 [n_slots]: pages of each slot's table that can hold context
+    this step — ``ceil(context / page_size)``, at most ``max_pages``, and 0
+    for an inactive slot. ``context`` [n_slots] is the rows a slot's
+    queries attend to. The fused read's grid folds pages below the bound
+    only (at least one), and the engine's read-walk counters count the
+    same bound, so the two cannot disagree."""
+    pages = jnp.minimum((context + page_size - 1) // page_size, max_pages)
+    return jnp.where(active, pages, 0).astype(jnp.int32)
+
+
 @jax.named_scope("kv_view")
 def step_plan(cache: PagedKV) -> StepPlan:
     """Build the per-segment :class:`StepPlan` (see its docstring)."""

@@ -374,6 +374,7 @@ def fused_paged_attention(
     layer: jnp.ndarray,      # int32 scalar: the layer of pool and ring read
     blocks: jnp.ndarray,     # int32 [B, P] clamped physical block ids
     view_ok: jnp.ndarray,    # bool [B, C, P*ps]
+    bound: jnp.ndarray,      # int32 [B] pages per slot's walk
     ring_k: Optional[jnp.ndarray] = None,   # [L, B, R, Hkv, Dh] staging ring
     ring_v: Optional[jnp.ndarray] = None,
     ring_ok: Optional[jnp.ndarray] = None,  # bool [B, R]
@@ -387,7 +388,8 @@ def fused_paged_attention(
     over a paged pool: the kernel walks the page table and overlays the
     staging ring inside one softmax, so no gathered view is materialized.
     Pool and ring come whole (every layer) and the kernel indexes
-    ``layer`` itself, so no per-layer plane is sliced out of them.
+    ``layer`` itself, so no per-layer plane is sliced out of them; each
+    slot's walk stops at its ``bound`` (``kvcache.paged.page_bound``).
     Projections (``project_q``) and the output einsum are shared with the
     jnp cores — fused and reference differ ONLY in the attention core,
     which the kernel holds to ulp-level fp32 parity (identical greedy
@@ -401,11 +403,11 @@ def fused_paged_attention(
     q = project_q(cfg, p, x, positions, use_rope)   # [B, C, Hq, Dh]
     if mesh is not None:
         out = flash_decode_paged_sharded(mesh, q, pages_k, pages_v, layer,
-                                         blocks, view_ok, ring_k, ring_v,
-                                         ring_ok)
+                                         blocks, view_ok, bound, ring_k,
+                                         ring_v, ring_ok)
     else:
         out = flash_decode_paged(q, pages_k, pages_v, layer, blocks, view_ok,
-                                 ring_k, ring_v, ring_ok, impl=impl)
+                                 bound, ring_k, ring_v, ring_ok, impl=impl)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dtype))
 
 

@@ -463,16 +463,19 @@ class DecoderLM:
         per-slot view from the pool and concatenates the ring in jnp;
         ``"fused"`` hands the whole stacked pool and ring planes, the
         layer index and the scalar-prefetch block table to
-        ``flash_decode_paged``, which walks that layer's page table and
-        merges both sources inside one softmax — no gathered view ever
-        materializes. Pool and ring ride the layer scan's carry and each
-        layer writes its rows in place (``_scan_paged``). The two share
-        one op order and agree to fp32 ulp precision with identical
-        greedy tokens (the reference is the kernel's oracle; DESIGN.md §7
-        has the parity contract). ``plan`` threads per-segment
-        hoisted page-table products (``PG.step_plan``); when None it is
-        derived here. ``mesh`` is the serving mesh of a head-sharded pool
-        (the fused kernel then runs per shard).
+        ``flash_decode_paged``, which walks that layer's page table up to
+        each slot's last page of context (``PG.page_bound``; a slot that
+        does not write walks page 0 alone, so its discarded output is the
+        reference's over a one-page table) and merges both sources inside
+        one softmax — no gathered view ever materializes. Pool and ring
+        ride the layer scan's carry and each layer writes its rows in
+        place (``_scan_paged``). The two share one op order and agree to
+        fp32 ulp precision with identical greedy tokens (the reference is
+        the kernel's oracle; DESIGN.md §7 has the parity contract).
+        ``plan`` threads per-segment hoisted page-table products
+        (``PG.step_plan``); when None it is derived here. ``mesh`` is the
+        serving mesh of a head-sharded pool (the fused kernel then runs
+        per shard).
 
         The per-slot attention view is gathered from the pool through the
         page table each step — values are identical to the dense cache
@@ -496,6 +499,9 @@ class DecoderLM:
                 plan = PG.step_plan(cache)
             vmask = PG.view_mask_from(plan.allocated, pos)
             view_ids = plan.view_ids
+            bound = PG.page_bound(pos + 1, write_mask,
+                                  cache["pages_k"].shape[2],
+                                  plan.blocks.shape[1])
             if ring:
                 if unload_mask is None:
                     unload_mask = jnp.ones_like(write_mask)
@@ -528,8 +534,8 @@ class DecoderLM:
                 with jax.named_scope("attention"):
                     a = L.fused_paged_attention(
                         cfg, p["attn"], hn, pos[:, None], pk, pv, l,
-                        plan.blocks, view_ok[:, None, :], rk, rv, ring_ok,
-                        mesh=mesh)
+                        plan.blocks, view_ok[:, None, :], bound, rk, rv,
+                        ring_ok, mesh=mesh)
             else:
                 with jax.named_scope("kv_view"):
                     ak, av = _reference_view(pk, pv, rk, rv, l, view_ids)
@@ -581,7 +587,10 @@ class DecoderLM:
         EVERY column: column ``j`` is the target distribution for
         position ``start + j + 1``, which is exactly what the
         speculative k-token verify consumes (a verify IS a chunked step
-        that keeps all its sampling positions).
+        that keeps all its sampling positions). The fused read stops each
+        slot's walk at ``PG.page_bound(start + n_valid)``: the columns at
+        or past ``n_valid`` see no more than that, and no token is taken
+        from their outputs.
 
         Bit-parity: chunk rows land in the pool before the per-slot view is
         gathered, and every projection/reduction matches the whole-prompt
@@ -623,6 +632,9 @@ class DecoderLM:
                 ring_lane_ok = None
                 direct = wvalid
             view_ids = plan.view_ids
+            bound = PG.page_bound(start + n_valid, n_valid > 0,
+                                  cache["pages_k"].shape[2],
+                                  plan.blocks.shape[1])
         with jax.named_scope("kv_write"):
             dest = PG.logical_to_physical_many(
                 cache, jnp.where(direct, positions, -1))
@@ -642,7 +654,7 @@ class DecoderLM:
                 with jax.named_scope("attention"):
                     a = L.fused_paged_attention(
                         cfg, p["attn"], hn, positions, pk, pv, l,
-                        plan.blocks, view_ok, rk, rv, ring_lane_ok,
+                        plan.blocks, view_ok, bound, rk, rv, ring_lane_ok,
                         mesh=mesh)
             else:
                 with jax.named_scope("kv_view"):

@@ -87,7 +87,8 @@ PHASE_PREFILL = 0
 PHASE_DECODE = 1
 
 # a segment's device counters, read back once: direct and staged KV rows,
-# drains, prefill rows, then the read's walk (``read_walk``)
+# drains, prefill rows, then the pages the read's grid folds and the live
+# ones among them (``read_walk``)
 N_STATS = 6
 
 
@@ -103,13 +104,13 @@ def paged_capable(model) -> bool:
 def read_walk(context: jnp.ndarray, active: jnp.ndarray, page_size: int,
               max_pages: int) -> jnp.ndarray:
     """One step's read of the paged pool, in one layer: [pages the read's
-    grid visits, the pages among them holding context of an active query].
-    The grid (``flash_decode_paged``, and the reference view alike) walks
-    every page of every slot's table; ``context`` [n_slots] is the rows a
-    slot's queries attend to this step."""
-    live = jnp.minimum((context + page_size - 1) // page_size, max_pages)
-    return jnp.stack([jnp.int32(active.shape[0] * max_pages),
-                      jnp.sum(jnp.where(active, live, 0))])
+    grid folds, the pages among them holding context of an active query].
+    ``context`` [n_slots] is the rows a slot's queries attend to this
+    step. The fused read (``flash_decode_paged``) folds each slot's pages
+    up to its ``PG.page_bound``, and at least one: an inactive slot folds
+    page 0 alone."""
+    bound = PG.page_bound(context, active, page_size, max_pages)
+    return jnp.stack([jnp.sum(jnp.maximum(bound, 1)), jnp.sum(bound)])
 
 
 class SlotState(NamedTuple):
@@ -398,7 +399,7 @@ class BatchedServeEngine:
             "host_unloads": 0, "host_pageins": 0,
             "preemptions": 0, "preempt_resumes": 0,
             # the paged read's walk, per layer, summed over steps: pages
-            # its grid visits, and those holding an active query's context
+            # its grid folds, and those holding an active query's context
             "read_pages_walked": 0, "read_pages_live": 0,
             # speculative-decoding telemetry (SpecStats, core.types):
             # committed/rounds is the acceptance-weighted committed

@@ -86,6 +86,16 @@ def _rand_inputs(rng, b, c, hq, hkv, d, nb, ps, p, r):
     return q, pk, pv, blocks, view_ok, ring
 
 
+def _mask_bound(view_ok, ps):
+    """The kernel's per-slot page bound (``PG.page_bound``) for a mask:
+    the pages up to each slot's last valid row."""
+    ok = np.asarray(view_ok).any(axis=1)                  # [B, P*ps]
+    context = np.where(ok.any(-1), ok.shape[-1] - np.argmax(ok[:, ::-1], -1),
+                       0)
+    return PG.page_bound(jnp.asarray(context), jnp.asarray(context > 0), ps,
+                         ok.shape[-1] // ps)
+
+
 @pytest.mark.parametrize("layer", LAYERS)
 @pytest.mark.parametrize("b,c,hq,hkv,d,nb,ps,p,r", [
     (2, 1, 4, 4, 16, 8, 4, 4, 0),     # step decode, MHA, no ring
@@ -104,10 +114,11 @@ def test_kernel_matches_oracle(b, c, hq, hkv, d, nb, ps, p, r, layer):
         args = (*ring, ring_ok)
     else:
         args = (None, None, None)
-    out = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, *args,
+    bound = _mask_bound(view_ok, ps)
+    out = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, bound, *args,
                              interpret=True)
     expected = ref.flash_decode_paged_ref(q, pk, pv, layer, blocks, view_ok,
-                                          *args)
+                                          bound, *args)
     _assert_ulp_close(out, expected)
 
 
@@ -134,18 +145,31 @@ def test_kernel_ring_states(state, c):
         rng, b, c, hq, hkv, d, nb, ps, p, r)
     ring_ok = jnp.asarray(RING_STATES[state](b, r, rng))
     layer = N_LAYERS - 1
-    out = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, *ring,
+    bound = _mask_bound(view_ok, ps)
+    out = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, bound, *ring,
                              ring_ok, interpret=True)
     expected = ref.flash_decode_paged_ref(q, pk, pv, layer, blocks, view_ok,
-                                          *ring, ring_ok)
+                                          bound, *ring, ring_ok)
     _assert_ulp_close(out, expected)
 
 
 @pytest.mark.parametrize("layer", LAYERS)
 def test_kernel_dead_slot_and_unallocated_pages(layer):
     """Fully-masked rows (retired slots) and clamped unallocated pages:
-    the kernel walks block 0's garbage exactly like the clamped reference
-    gather, so even degenerate outputs agree."""
+    walking the whole table (a bound of ``P``), the kernel walks block 0's
+    garbage exactly like the clamped reference gather, so even degenerate
+    outputs agree."""
+    q, pk, pv, blocks, view_ok, ring, ring_ok = _dead_slot_inputs()
+    p = blocks.shape[1]
+    bound = jnp.full((2,), p, jnp.int32)
+    out = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, bound, *ring,
+                             ring_ok, interpret=True)
+    expected = ref.flash_decode_paged_ref(q, pk, pv, layer, blocks, view_ok,
+                                          bound, *ring, ring_ok)
+    _assert_ulp_close(out, expected)
+
+
+def _dead_slot_inputs():
     b, c, hq, hkv, d, nb, ps, p, r = 2, 1, 4, 2, 16, 8, 4, 4, 4
     rng = np.random.RandomState(0)
     q, pk, pv, _, _, ring = _rand_inputs(rng, b, c, hq, hkv, d, nb, ps, p, r)
@@ -155,11 +179,73 @@ def test_kernel_dead_slot_and_unallocated_pages(layer):
         np.stack([np.ones((c, p * ps), bool), np.zeros((c, p * ps), bool)]))
     ring_ok = jnp.asarray([[True, False, True, False],
                            [False, False, False, False]])
-    out = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, *ring,
+    return q, pk, pv, blocks, view_ok, ring, ring_ok
+
+
+@pytest.mark.parametrize("layer", LAYERS)
+def test_kernel_dead_slot_walks_page_zero(layer):
+    """Serving's bound (``PG.page_bound``) for the same two slots: the live
+    slot walks its whole table, and the dead one (bound 0) walks page 0
+    alone, so its degenerate output is the oracle's over a one-page
+    table (block 0's garbage and the ring lanes, all masked)."""
+    q, pk, pv, blocks, view_ok, ring, ring_ok = _dead_slot_inputs()
+    ps = pk.shape[2]
+    bound = _mask_bound(view_ok, ps)
+    np.testing.assert_array_equal(bound, [blocks.shape[1], 0])
+    out = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, bound, *ring,
                              ring_ok, interpret=True)
-    expected = ref.flash_decode_paged_ref(q, pk, pv, layer, blocks, view_ok,
-                                          *ring, ring_ok)
-    _assert_ulp_close(out, expected)
+    whole = ref.flash_decode_paged_ref(q, pk, pv, layer, blocks, view_ok,
+                                       bound, *ring, ring_ok)
+    page0 = ref.flash_decode_paged_ref(q, pk, pv, layer, blocks[:, :1],
+                                       view_ok[:, :, :ps], bound, *ring,
+                                       ring_ok)
+    _assert_ulp_close(out[0], whole[0])
+    _assert_ulp_close(out[1], page0[1])
+
+
+@pytest.mark.parametrize("ring", [False, True])
+@pytest.mark.parametrize("group", [1, 7])
+@pytest.mark.parametrize("c", [1, 4])
+def test_kernel_stops_at_each_slots_bound(c, group, ring):
+    """Per-slot page bounds of 0, 1, part of the table and all of it
+    (``PG.page_bound``): every page at or past a slot's bound is NaN in K
+    and V, and the live slots' outputs are still finite, ulp-close to the
+    oracle (run on the same inputs with those pages finite: it gathers
+    them) and bitwise the full walk's on finite pages. A walk that folded
+    a masked NaN page would poison its sum (0 x NaN)."""
+    b, hkv, d, ps, p, r = 4, 2, 16, 4, 5, 6
+    hq = group * hkv
+    bound = np.array([0, 1, 3, p], np.int32)
+    live = bound > 0
+    rng = np.random.RandomState(100 * c + 10 * group + ring)
+    q, pk, pv, _, _, lanes = _rand_inputs(rng, b, c, hq, hkv, d, b * p, ps,
+                                          p, r)
+    # every page of every slot on a block of its own, so a block past one
+    # slot's bound is never live in another's
+    blocks = rng.permutation(b * p).reshape(b, p).astype(np.int32)
+    context = np.where(live, (bound - 1) * ps + 1 + rng.randint(ps, size=b),
+                       0)
+    rows = np.arange(p * ps)[None, None, :]
+    view_ok = (rows < context[:, None, None]) & (rng.rand(b, c, p * ps) > 0.35)
+    view_ok[live, :, 0] = True       # row 0 of page 0: a live slot's first
+    dead = blocks[np.arange(p)[None, :] >= bound[:, None]]
+    poisoned = [x.at[:, dead].set(jnp.nan) for x in (pk, pv)]
+    args = (jnp.asarray(blocks), jnp.asarray(view_ok))
+    ring_args = ((*lanes, jnp.asarray(rng.rand(b, r) > 0.5)) if ring
+                 else (None, None, None))
+    layer = N_LAYERS - 1
+
+    out = flash_decode_paged(q, *poisoned, layer, *args, jnp.asarray(bound),
+                             *ring_args, interpret=True)
+    full = flash_decode_paged(q, pk, pv, layer, *args,
+                              jnp.full((b,), p, jnp.int32), *ring_args,
+                              interpret=True)
+    expected = ref.flash_decode_paged_ref(q, pk, pv, layer, *args,
+                                          jnp.asarray(bound), *ring_args)
+    out, full = np.asarray(out)[live], np.asarray(full)[live]
+    assert np.isfinite(out).all()
+    _assert_ulp_close(out, np.asarray(expected)[live])
+    np.testing.assert_array_equal(out, full)
 
 
 def test_oracle_matches_reference_core_bitwise():
@@ -195,10 +281,11 @@ def test_oracle_matches_reference_core_bitwise():
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     core = jnp.einsum("bhst,bthk->bshk", probs, vf)
 
+    bound = _mask_bound(view_ok, ps)
     oracle = ref.flash_decode_paged_ref(q, pk, pv, layer, blocks, view_ok,
-                                        *ring, ring_ok)
-    kernel = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, *ring,
-                                ring_ok, interpret=True)
+                                        bound, *ring, ring_ok)
+    kernel = flash_decode_paged(q, pk, pv, layer, blocks, view_ok, bound,
+                                *ring, ring_ok, interpret=True)
     np.testing.assert_array_equal(np.asarray(core), np.asarray(oracle))
     _assert_ulp_close(kernel, core)
 
@@ -232,7 +319,9 @@ def test_model_fused_matches_reference(variant):
     """decode_step_paged / decode_chunk_paged under attention='fused' vs
     'reference': identical argmax tokens, allclose logits, allclose cache
     (cross-graph XLA fusion of the k/v projections carries ~1 ulp — see
-    module docstring)."""
+    module docstring). The dead slot (no write, bound 0) walks page 0
+    alone (``PG.page_bound``), so its row is held to the reference over
+    the view that bound defines: the same cache with one page per table."""
     cfg = get_config("stablelm-1.6b").reduced()
     model = build_model(cfg)
     params = model.init(jax.random.key(0), MAX_SEQ)
@@ -241,30 +330,40 @@ def test_model_fused_matches_reference(variant):
     wm = jnp.asarray([True, True, True, False])
     um = jnp.asarray([True, False, True, False])
     toks = jnp.asarray(rng.randint(0, cfg.vocab, (4, 4)), jnp.int32)
+    page0 = dict(cache, page_table=cache["page_table"][:, :1])
     outs = {}
-    for attention in ("reference", "fused"):
+    for attention, kv in (("reference", cache), ("fused", cache),
+                          ("page0", page0)):
+        impl = "reference" if attention == "page0" else attention
         if variant == "step":
             tok = jnp.asarray([5, 9, 3, 0], jnp.int32)
             pos = jnp.asarray([10, 6, 9, 0], jnp.int32)
             outs[attention] = model.decode_step_paged(
-                params, dict(cache), tok, pos, wm, unload_mask=um,
-                attention=attention)
+                params, dict(kv), tok, pos, wm, unload_mask=um,
+                attention=impl)
         else:
             start = jnp.asarray([10, 6, 9, 0], jnp.int32)
             nv = jnp.asarray([4, 1, 2, 0], jnp.int32)
             outs[attention] = model.decode_chunk_paged(
-                params, dict(cache), toks, start, nv, wm,
-                unload_mask=(nv == 1) & wm, attention=attention)
-    lr, cr = outs["reference"]
-    lf, cf = outs["fused"]
-    np.testing.assert_array_equal(np.asarray(jnp.argmax(lr, -1)),
-                                  np.asarray(jnp.argmax(lf, -1)))
-    np.testing.assert_allclose(np.asarray(lr), np.asarray(lf),
-                               atol=1e-5, rtol=1e-5)
+                params, dict(kv), toks, start, nv, wm,
+                unload_mask=(nv == 1) & wm, attention=impl)
+    live = np.asarray(wm)
+    (lr, cr), (lf, cf), (l0, c0) = (
+        (np.asarray(lg), {k: np.asarray(v, np.float32) for k, v in c.items()})
+        for lg, c in (outs["reference"], outs["fused"], outs["page0"]))
+    # the reference each slot's walk defines: the whole table where it
+    # writes, page 0 where it does not
+    lr = np.where(live.reshape((-1,) + (1,) * (lr.ndim - 1)), lr, l0)
+    for key in ("ring_k", "ring_v"):
+        cr[key] = np.where(live[None, :, None, None, None], cr[key],
+                           c0[key])
+    np.testing.assert_array_equal(np.argmax(lr, -1), np.argmax(lf, -1))
+    np.testing.assert_allclose(lr, lf, atol=1e-5, rtol=1e-5)
     for key in cr:
-        np.testing.assert_allclose(
-            np.asarray(cr[key], np.float32), np.asarray(cf[key], np.float32),
-            atol=1e-5, rtol=1e-5, err_msg=key)
+        np.testing.assert_allclose(cr[key], cf[key], atol=1e-5, rtol=1e-5,
+                                   err_msg=key)
+    # the dead slot's staged tile sits at a lane it never reads
+    assert (cf["ring_pos"][~live] < 0).all()
 
 
 # ---------------------------------------------------------------------------

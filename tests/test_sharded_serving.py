@@ -317,17 +317,19 @@ def test_flash_decode_paged_sharded_matches_unsharded():
     rk = rng.standard_normal((nl, b, r, hkv, d)).astype(np.float32)
     rv = rng.standard_normal((nl, b, r, hkv, d)).astype(np.float32)
     rok = rng.random((b, r)) < 0.5
+    bound = np.asarray([1, npg], np.int32)     # each slot's walk, replicated
+    ok &= np.arange(npg * ps) < (bound * ps)[:, None, None]
     ok[:, :, 0] = True                         # softmax needs >= 1 source
     layer = nl - 1
-    ref = flash_decode_paged(q, pk, pv, layer, blocks, ok, rk, rv, rok,
-                             interpret=True)
-    got = flash_decode_paged_sharded(mesh, q, pk, pv, layer, blocks, ok, rk,
-                                     rv, rok, interpret=True)
+    ref = flash_decode_paged(q, pk, pv, layer, blocks, ok, bound, rk, rv,
+                             rok, interpret=True)
+    got = flash_decode_paged_sharded(mesh, q, pk, pv, layer, blocks, ok,
+                                     bound, rk, rv, rok, interpret=True)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
     with pytest.raises(ValueError, match="divide"):
         flash_decode_paged_sharded(
-            mesh, q[:, :, :3], pk, pv, layer, blocks, ok, rk, rv, rok,
-            interpret=True)
+            mesh, q[:, :, :3], pk, pv, layer, blocks, ok, bound, rk, rv,
+            rok, interpret=True)
 
 
 # ---------------------------------------------------------------------------

@@ -84,7 +84,8 @@ def _shape(shape, dtype, sharding):
 
 def _paged_args(g, c, ring, shard_of):
     """Operands of the read kernel as serving passes them: the whole
-    stacked pool and ring (every layer) and the layer index to read."""
+    stacked pool and ring (every layer), the layer index to read, and
+    each slot's page bound after the table and mask."""
     bf16 = jnp.bfloat16
     pool = (g.layers, g.n_blocks, PAGE, g.hkv, g.d)
     args = [_shape((g.b, c, g.hq, g.d), bf16, shard_of("heads4")),
@@ -92,7 +93,8 @@ def _paged_args(g, c, ring, shard_of):
             _shape(pool, bf16, shard_of("heads5")),
             _shape((), jnp.int32, shard_of("rep0")),
             _shape((g.b, g.pages), jnp.int32, shard_of("rep2")),
-            _shape((g.b, c, g.pages * PAGE), jnp.bool_, shard_of("rep3"))]
+            _shape((g.b, c, g.pages * PAGE), jnp.bool_, shard_of("rep3")),
+            _shape((g.b,), jnp.int32, shard_of("rep1"))]
     if ring:
         lanes = (g.layers, g.b, RING, g.hkv, g.d)
         args += [_shape(lanes, bf16, shard_of("heads5")),
@@ -107,30 +109,59 @@ def _assert_kernel_compiles(fn, args):
     return compiled
 
 
+def _scalar_prefetch(fn, args):
+    """Scalar-prefetch operands of each Pallas call ``fn`` traces to,
+    searched through nested jaxprs (``shard_map``)."""
+    found = []
+
+    def walk(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn.params["grid_mapping"].num_index_operands)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
 @pytest.mark.parametrize("geometry", list(GEOMETRIES))
 @pytest.mark.parametrize("c,ring", [
     (1, True),        # step decode over pool + staging ring
     (CHUNK, False),   # chunked prefill
+    (CHUNK, True),    # mixed step: prefill chunks beside staged decodes
     (9, True),        # speculative verify, k = 8
 ])
 def test_flash_decode_paged_compiles_for_v5e(one_chip, geometry, c, ring):
+    """The page table, the layer and each slot's page bound are the
+    kernel's three scalar-prefetch operands."""
     args = _paged_args(GEOMETRIES[geometry], c, ring, lambda _: one_chip)
-    _assert_kernel_compiles(
-        lambda *a: flash_decode_paged(*a, interpret=False), args)
+
+    def fn(*a):
+        return flash_decode_paged(*a, interpret=False)
+
+    assert _scalar_prefetch(fn, args) == [3]
+    _assert_kernel_compiles(fn, args)
 
 
 @pytest.mark.parametrize("geometry", list(GEOMETRIES))
-def test_flash_decode_paged_sharded_compiles_for_v5e_2x2(topo, geometry):
-    """Head-sharded pool on four chips: one kernel per shard."""
+@pytest.mark.parametrize("c", [1, CHUNK])
+def test_flash_decode_paged_sharded_compiles_for_v5e_2x2(topo, geometry, c):
+    """Head-sharded pool on four chips: one kernel per shard, the page
+    bound replicated beside the table."""
     mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("data", "model"))
     specs = {"heads4": P(None, None, "model", None),
              "heads5": P(None, None, None, "model", None), "rep0": P(),
-             "rep2": P(None, None), "rep3": P(None, None, None)}
-    args = _paged_args(GEOMETRIES[geometry], 1, True,
+             "rep1": P(None), "rep2": P(None, None),
+             "rep3": P(None, None, None)}
+    args = _paged_args(GEOMETRIES[geometry], c, True,
                        lambda k: NamedSharding(mesh, specs[k]))
-    _assert_kernel_compiles(
-        lambda *a: flash_decode_paged_sharded(mesh, *a, interpret=False),
-        args)
+
+    def fn(*a):
+        return flash_decode_paged_sharded(mesh, *a, interpret=False)
+
+    assert _scalar_prefetch(fn, args) == [3]
+    _assert_kernel_compiles(fn, args)
 
 
 @pytest.mark.parametrize("geometry", list(GEOMETRIES))
