@@ -15,7 +15,6 @@ from .paged import (
     scatter_token,
     unload_blocks,
     view_len,
-    view_mask,
     view_rows,
 )
 from .staged import (
@@ -38,7 +37,7 @@ __all__ = [
     "BlockPool", "copy_block", "drain_ring_paged", "gather_view",
     "load_blocks", "logical_to_physical", "make_paged_kv",
     "maybe_drain_paged", "pool_rows", "prefix_page_hashes",
-    "scatter_token", "unload_blocks", "view_len", "view_mask", "view_rows",
+    "scatter_token", "unload_blocks", "view_len", "view_rows",
     "add_ring", "drain_ring", "maybe_drain", "overlay_kv", "overlay_masks",
     "overlay_step", "ring_commit", "ring_conflicts", "ring_full",
     "ring_state", "ring_validity", "stage_tile", "strip_ring",

@@ -394,30 +394,18 @@ def view_len(cache: PagedKV) -> int:
 
 
 def logical_to_physical(cache: PagedKV, rows: jnp.ndarray) -> jnp.ndarray:
-    """Per-slot logical row -> physical pool row. ``rows`` int32 [n_slots].
+    """Per-slot logical rows -> physical pool rows. ``rows`` int32
+    [n_slots] or [n_slots, C]: every row of slot ``b`` resolves through
+    slot ``b``'s page table.
 
-    Rows on unallocated pages (or negative sentinels) map to the
-    out-of-range sentinel ``pool_rows`` so downstream scatters DROP them —
-    a retired or empty slot can never write."""
+    Rows on unallocated pages, out of the view, or negative sentinels map
+    to the out-of-range sentinel ``pool_rows`` so downstream scatters DROP
+    them — a retired or empty slot can never write."""
     ps = cache["pages_k"].shape[2]
     n_slots = cache["page_table"].shape[0]
+    slot = jnp.arange(n_slots).reshape((n_slots,) + (1,) * (rows.ndim - 1))
     safe = jnp.clip(rows, 0, view_len(cache) - 1)
-    block = cache["page_table"][jnp.arange(n_slots), safe // ps]
-    phys = block * ps + safe % ps
-    ok = (rows >= 0) & (rows < view_len(cache)) & (block >= 0)
-    return jnp.where(ok, phys, pool_rows(cache)).astype(jnp.int32)
-
-
-def logical_to_physical_many(cache: PagedKV, rows: jnp.ndarray) -> jnp.ndarray:
-    """Per-slot logical rows -> physical pool rows, ``rows`` int32
-    [n_slots, C] (the chunk generalization of :func:`logical_to_physical`;
-    column ``j`` of slot ``b`` resolves through slot ``b``'s page table).
-    Invalid rows (negative sentinel, out of view, unallocated page) map to
-    the out-of-range sentinel ``pool_rows`` so scatters DROP them."""
-    ps = cache["pages_k"].shape[2]
-    n_slots = cache["page_table"].shape[0]
-    safe = jnp.clip(rows, 0, view_len(cache) - 1)
-    block = cache["page_table"][jnp.arange(n_slots)[:, None], safe // ps]
+    block = cache["page_table"][slot, safe // ps]
     phys = block * ps + safe % ps
     ok = (rows >= 0) & (rows < view_len(cache)) & (block >= 0)
     return jnp.where(ok, phys, pool_rows(cache)).astype(jnp.int32)
@@ -425,7 +413,7 @@ def logical_to_physical_many(cache: PagedKV, rows: jnp.ndarray) -> jnp.ndarray:
 
 def view_rows(cache: PagedKV) -> jnp.ndarray:
     """int32 [n_slots, V]: physical pool row backing every logical row
-    (clamped to 0 where unallocated — mask with :func:`view_mask`)."""
+    (clamped to 0 where unallocated — mask with :func:`view_chunk_mask`)."""
     ps = cache["pages_k"].shape[2]
     table = cache["page_table"]
     base = jnp.maximum(table, 0) * ps  # [n_slots, max_pages]
@@ -483,27 +471,12 @@ def step_plan(cache: PagedKV) -> StepPlan:
     )
 
 
-def view_mask_from(allocated: jnp.ndarray, pos: jnp.ndarray) -> jnp.ndarray:
-    """:func:`view_mask` from a hoisted ``StepPlan.allocated``."""
-    logical = jnp.arange(allocated.shape[1])[None, :]
-    return (logical <= pos[:, None]) & allocated
-
-
-def view_mask(cache: PagedKV, pos: jnp.ndarray) -> jnp.ndarray:
-    """bool [n_slots, V]: logical rows holding live KV once row ``pos``
-    is written this step (linear addressing: rows 0..pos on allocated
-    pages)."""
-    ps = cache["pages_k"].shape[2]
-    allocated = jnp.repeat(cache["page_table"] >= 0, ps, axis=1)
-    return view_mask_from(allocated, pos)
-
-
 def gather_view(pages_l: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
     """One layer's per-slot contiguous KV view.
 
     pages_l [n_blocks, ps, H, Dh], rows int32 [n_slots, V] ->
     [n_slots, V, H, Dh]. Rows of unallocated pages gather block 0 garbage;
-    the attention mask (:func:`view_mask`) excludes them."""
+    the attention mask (:func:`view_chunk_mask`) excludes them."""
     flat = pages_l.reshape((-1,) + pages_l.shape[2:])
     return flat[rows]
 
@@ -511,31 +484,19 @@ def gather_view(pages_l: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
 def scatter_token(
     pages: jnp.ndarray,     # [L, n_blocks, ps, H, Dh] the whole pool plane
     layer: jnp.ndarray,     # int32 scalar: the layer written
-    dest: jnp.ndarray,      # int32 [n_slots] physical rows (sentinel drops)
-    tile: jnp.ndarray,      # [n_slots, H, Dh]
+    dest: jnp.ndarray,      # int32 [n_slots] or [n_slots, C] physical rows
+    tile: jnp.ndarray,      # [*dest.shape, H, Dh]
 ) -> jnp.ndarray:
-    """Direct (offload-path) write of one decode step's tiles at
-    ``[layer, dest]``. Only those rows change: no layer plane is sliced
-    out and put back, so a pool carried through the layer scan is updated
-    in place."""
+    """Direct (offload-path) write of one paged step's tiles at
+    ``[layer, dest]`` (out-of-range sentinel rows drop). Only those rows
+    change: no layer plane is sliced out and put back, so a pool carried
+    through the layer scan is updated in place. Destinations are unique
+    across slots (block ownership) and within a chunk (consecutive logical
+    rows), so the scatter never collides."""
     flat = pages.reshape(pages.shape[:1] + (-1,) + pages.shape[3:])
-    flat = flat.at[layer, dest].set(tile.astype(flat.dtype), mode="drop")
+    flat = flat.at[layer, dest.reshape(-1)].set(
+        tile.reshape((-1,) + tile.shape[-2:]).astype(flat.dtype), mode="drop")
     return flat.reshape(pages.shape)
-
-
-def scatter_chunk(
-    pages: jnp.ndarray,     # [L, n_blocks, ps, H, Dh] the whole pool plane
-    layer: jnp.ndarray,     # int32 scalar: the layer written
-    dest: jnp.ndarray,      # int32 [n_slots, C] physical rows (sentinel drops)
-    tiles: jnp.ndarray,     # [n_slots, C, H, Dh]
-) -> jnp.ndarray:
-    """Direct (offload-path) bulk write of one mixed-phase step's tiles —
-    the prefill-chunk analogue of :func:`scatter_token`, in place at
-    ``[layer, dest]``. Destinations are unique across slots (block
-    ownership) and within a chunk (consecutive logical rows), so the
-    scatter never collides."""
-    return scatter_token(pages, layer, dest.reshape(-1),
-                         tiles.reshape((-1,) + tiles.shape[2:]))
 
 
 # ---------------------------------------------------------------------------
@@ -584,54 +545,6 @@ def ring_commit(cache: PagedKV, pos: jnp.ndarray,
     return cache
 
 
-def overlay_step_parts(
-    cache: PagedKV,
-    vmask: jnp.ndarray,        # bool [n_slots, V] view validity after write
-    pos: jnp.ndarray,          # int32 [n_slots] this step's logical rows
-    unload_mask: jnp.ndarray,  # bool [n_slots] True = stage
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Per-step overlay bookkeeping, kept as SEPARATE sources.
-
-    Returns (view_ok [n_slots, V] pool-view validity with staged rows
-    shadowed out, ring_ok [n_slots, R] ring-lane validity including this
-    step's append, cur — the ring column this step appends to). The fused
-    kernel consumes the two masks directly (pool walk + ring lanes as a
-    second softmax source); the reference path concatenates them
-    (:func:`overlay_step`) — same booleans either way, so mask parity
-    between the implementations is by construction.
-    """
-    b, v = vmask.shape
-    r = cache["ring_pos"].shape[1]
-    cur = cache["ring_fill"]
-    ring_valid = ring_validity(cache) | (
-        (jnp.arange(r)[None, :] == cur) & unload_mask[:, None]
-    )
-    shadowed = R.shadow_mask(
-        ring_validity(cache), cache["ring_pos"], v,
-        extra_rows=jnp.where(unload_mask, pos, v),
-    )
-    return vmask & ~shadowed, ring_valid, cur
-
-
-def overlay_step(
-    cache: PagedKV,
-    vmask: jnp.ndarray,        # bool [n_slots, V] view validity after write
-    pos: jnp.ndarray,          # int32 [n_slots] this step's logical rows
-    unload_mask: jnp.ndarray,  # bool [n_slots] True = stage
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-step overlay bookkeeping for ``decode_step_paged``.
-
-    Returns (full_mask [n_slots, V+R] attention validity over view ∪ ring,
-    cur — the ring column this step appends to). The authoritative value
-    for a staged entry lives in the RING until drained, so its logical row
-    is shadowed out of the view mask.
-    """
-    view_ok, ring_valid, cur = overlay_step_parts(cache, vmask, pos,
-                                                  unload_mask)
-    full_mask = jnp.concatenate([view_ok, ring_valid], axis=1)
-    return full_mask, cur
-
-
 def view_chunk_mask(cache: PagedKV, positions: jnp.ndarray) -> jnp.ndarray:
     """bool [n_slots, C, V]: per-query view validity for a mixed-phase
     chunk step. ``positions`` int32 [n_slots, C] — query ``j`` of slot
@@ -649,22 +562,34 @@ def view_chunk_mask_from(allocated: jnp.ndarray,
                          positions: jnp.ndarray) -> jnp.ndarray:
     """:func:`view_chunk_mask` from a hoisted ``StepPlan.allocated``."""
     rows = jnp.arange(allocated.shape[1])[None, None, :]
-    return (rows <= positions[:, :, None]) & allocated[:, None, :]
+    b, v = allocated.shape
+    return (rows <= positions[:, :, None]) & allocated.reshape(b, 1, v)
 
 
 def overlay_chunk_parts(
     cache: PagedKV,
     positions: jnp.ndarray,    # int32 [n_slots, C] per-query logical rows
     unload_mask: jnp.ndarray,  # bool [n_slots] True = column-0 write stages
-    allocated: Optional[jnp.ndarray] = None,  # hoisted StepPlan.allocated
+    allocated: jnp.ndarray,    # hoisted StepPlan.allocated
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Chunked analogue of :func:`overlay_step_parts`.
+    """Per-step overlay bookkeeping of the paged step, kept as SEPARATE
+    sources.
 
-    Returns (view_ok [n_slots, C, V], ring_ok [n_slots, R] — per-lane, NOT
-    broadcast over C: a slot's pending ring entries always hold rows
-    strictly below its current position (conflict-forced drains), so ring
-    validity needs no per-query causal term — and cur, the ring column this
-    step appends to).
+    Returns (view_ok [n_slots, C, V] pool-view validity with staged rows
+    shadowed out, ring_ok [n_slots, R] ring-lane validity including this
+    step's append, and cur — the ring column this step appends to). The
+    authoritative value of a staged entry lives in the RING until drained,
+    so its logical row is shadowed out of the view. Only the scattered
+    column-0 (decode-phase) write may stage; prefill chunks are
+    bulk/direct, and a prefilling slot's ring lane is empty (lanes drain
+    at every segment boundary, before the slot could have been admitted).
+    ``ring_ok`` is per-lane, NOT broadcast over C: a slot's pending ring
+    entries always hold rows strictly below its current position
+    (conflict-forced drains), so ring validity needs no per-query causal
+    term. The fused kernel consumes the two masks directly (pool walk +
+    ring lanes as a second softmax source); the reference path
+    concatenates them — same booleans either way, so mask parity between
+    the implementations is by construction.
     """
     r = cache["ring_pos"].shape[1]
     cur = cache["ring_fill"]
@@ -677,33 +602,8 @@ def overlay_chunk_parts(
         live, cache["ring_pos"], v,
         extra_rows=jnp.where(unload_mask, positions[:, 0], v),
     )
-    if allocated is None:
-        vmask = view_chunk_mask(cache, positions)
-    else:
-        vmask = view_chunk_mask_from(allocated, positions)
+    vmask = view_chunk_mask_from(allocated, positions)
     return vmask & ~shadowed[:, None, :], ring_valid, cur
-
-
-def overlay_chunk(
-    cache: PagedKV,
-    positions: jnp.ndarray,    # int32 [n_slots, C] per-query logical rows
-    unload_mask: jnp.ndarray,  # bool [n_slots] True = column-0 write stages
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Mixed-phase generalization of :func:`overlay_step`.
-
-    Returns (full_mask bool [n_slots, C, V+R] attention validity over
-    view ∪ ring, cur — the ring column this step appends to). Only the
-    scattered column-0 (decode-phase) write may stage; prefill chunks are
-    bulk/direct, and a prefilling slot's ring lane is empty (lanes drain at
-    every segment boundary, before the slot could have been admitted).
-    """
-    view_ok, ring_valid, cur = overlay_chunk_parts(cache, positions,
-                                                   unload_mask)
-    c = positions.shape[1]
-    r = ring_valid.shape[1]
-    ring_ok = jnp.broadcast_to(ring_valid[:, None, :],
-                               (positions.shape[0], c, r))
-    return jnp.concatenate([view_ok, ring_ok], axis=2), cur
 
 
 @jax.named_scope("kv_drain")

@@ -436,129 +436,7 @@ class DecoderLM:
         return x, dict(cache, **{k: v for k, v in zip(keys, planes)
                                  if v is not None})
 
-    def decode_step_paged(
-        self,
-        params: Params,
-        cache: Params,
-        tokens: jnp.ndarray,
-        pos: jnp.ndarray,
-        write_mask: jnp.ndarray,
-        unload_mask: Optional[jnp.ndarray] = None,
-        attention: str = "reference",
-        plan: Optional[PG.StepPlan] = None,
-        mesh=None,
-    ) -> Tuple[jnp.ndarray, Params]:
-        """One decode step against a PAGED KV pool (``repro.kvcache.paged``).
-
-        tokens [B], pos [B] (logical positions, per-slot) -> logits [B, V'],
-        new cache. ``write_mask`` [B]: False suppresses every KV write for
-        that slot (retired / empty serve slots — their physical destination
-        resolves to the drop sentinel, so a dead slot can never touch the
-        pool). ``unload_mask`` [B] routes live writes: True = stage into
-        the ring overlay (unload path), False/None = direct scatter to the
-        slot's physical row (offload path).
-
-        ``attention`` picks the read implementation (negotiate it through
-        ``core.paths.resolve_attention``): ``"reference"`` gathers the
-        per-slot view from the pool and concatenates the ring in jnp;
-        ``"fused"`` hands the whole stacked pool and ring planes, the
-        layer index and the scalar-prefetch block table to
-        ``flash_decode_paged``, which walks that layer's page table up to
-        each slot's last page of context (``PG.page_bound``; a slot that
-        does not write walks page 0 alone, so its discarded output is the
-        reference's over a one-page table) and merges both sources inside
-        one softmax — no gathered view ever materializes. Pool and ring
-        ride the layer scan's carry and each layer writes its rows in
-        place (``_scan_paged``). The two share one op order and agree to
-        fp32 ulp precision with identical greedy tokens (the reference is
-        the kernel's oracle; DESIGN.md §7 has the parity contract).
-        ``plan`` threads per-segment hoisted page-table products
-        (``PG.step_plan``); when None it is derived here. ``mesh`` is the
-        serving mesh of a head-sharded pool (the fused kernel then runs
-        per shard).
-
-        The per-slot attention view is gathered from the pool through the
-        page table each step — values are identical to the dense cache
-        layout, so paged decode is bit-compatible with ``decode_step``.
-        Linear addressing only: SWA ring addressing and the VLM family
-        stay on the dense-lane path (see DESIGN.md §Arch-applicability).
-        """
-        cfg = self.cfg
-        if self.is_vlm or cfg.sliding_window:
-            raise NotImplementedError(
-                "paged KV decode covers linear-addressed dense caches; "
-                "SWA/VLM serve from dense lanes (DESIGN.md §Arch-applicability)"
-            )
-        fused = attention == "fused"
-        dtype = jnp.dtype(cfg.dtype)
-        with jax.named_scope("head"):
-            x = L.embed_tokens(cfg, params["embed"], tokens[:, None], dtype)
-        ring = PG.has_ring(cache)
-        with jax.named_scope("kv_view"):
-            if plan is None:
-                plan = PG.step_plan(cache)
-            vmask = PG.view_mask_from(plan.allocated, pos)
-            view_ids = plan.view_ids
-            bound = PG.page_bound(pos + 1, write_mask,
-                                  cache["pages_k"].shape[2],
-                                  plan.blocks.shape[1])
-            if ring:
-                if unload_mask is None:
-                    unload_mask = jnp.ones_like(write_mask)
-                unload_mask = unload_mask & write_mask
-                view_ok, ring_ok, cur = PG.overlay_step_parts(
-                    cache, vmask, pos, unload_mask)
-                full_mask = jnp.concatenate([view_ok, ring_ok], axis=1)
-                direct = write_mask & ~unload_mask
-            else:
-                view_ok = full_mask = vmask
-                ring_ok = None
-                direct = write_mask
-        # physical destination for the direct subset; sentinel (-1 logical
-        # -> out-of-range physical) DROPS staged and dead slots
-        with jax.named_scope("kv_write"):
-            dest = PG.logical_to_physical(cache, jnp.where(direct, pos, -1))
-
-        def self_body(h, planes, p, l):
-            pk, pv, rk, rv = planes
-            with jax.named_scope("attention"):
-                hn = L.apply_norm(cfg, p["ln1"], h)
-                k_new, v_new = L.project_kv(cfg, p["attn"], hn, pos[:, None])
-            with jax.named_scope("kv_write"):
-                pk = PG.scatter_token(pk, l, dest, k_new[:, 0])
-                pv = PG.scatter_token(pv, l, dest, v_new[:, 0])
-                if ring:
-                    rk = PG.stage_tile(rk, l, k_new[:, 0], cur)
-                    rv = PG.stage_tile(rv, l, v_new[:, 0], cur)
-            if fused:
-                with jax.named_scope("attention"):
-                    a = L.fused_paged_attention(
-                        cfg, p["attn"], hn, pos[:, None], pk, pv, l,
-                        plan.blocks, view_ok[:, None, :], bound, rk, rv,
-                        ring_ok, mesh=mesh)
-            else:
-                with jax.named_scope("kv_view"):
-                    ak, av = _reference_view(pk, pv, rk, rv, l, view_ids)
-                with jax.named_scope("attention"):
-                    a = L.decode_attention(cfg, p["attn"], hn, pos, ak, av,
-                                           full_mask)
-            h = h + a
-            with jax.named_scope("mlp"):
-                h = h + L.apply_mlp(cfg, p["mlp"],
-                                    L.apply_norm(cfg, p["ln2"], h))
-            return h, (pk, pv, rk, rv)
-
-        x, new_cache = self._scan_paged(self_body, x, params, cache)
-        if ring:
-            with jax.named_scope("kv_write"):
-                new_cache = PG.ring_commit(new_cache, pos, unload_mask)
-
-        with jax.named_scope("head"):
-            x = L.apply_norm(cfg, params["ln_f"], x)
-            logits = L.lm_logits(cfg, params["embed"], x)[:, 0]
-        return logits, new_cache
-
-    # -- mixed-phase chunk step (paged pool) --------------------------------
+    # -- the paged step (decode, prefill chunk, verify) ---------------------
     def decode_chunk_paged(
         self,
         params: Params,
@@ -573,29 +451,58 @@ class DecoderLM:
         all_logits: bool = False,
         mesh=None,
     ) -> Tuple[jnp.ndarray, Params]:
-        """One MIXED-PHASE step against the paged pool: each slot processes
-        a [C]-token slab — a prefill chunk (``n_valid`` prompt tokens from
-        its chunk cursor), a single decode token (``n_valid == 1``, column
-        0), or nothing (``n_valid == 0``, retired/stalled). Column ``j`` of
-        slot ``b`` sits at logical row/position ``start[b] + j``.
+        """One step against the PAGED KV pool (``repro.kvcache.paged``), the
+        only one: each slot processes a [C]-token slab — a prefill chunk
+        (``n_valid`` prompt tokens from its chunk cursor), a single decode
+        token (``n_valid == 1``, column 0), or nothing (``n_valid == 0``,
+        retired/stalled). Column ``j`` of slot ``b`` sits at logical
+        row/position ``start[b] + j``. At C = 1 this IS the decode step:
+        ``tokens = token[:, None]``, ``start = pos``, ``n_valid =
+        write_mask`` as int32.
 
-        Chunk KV writes are dense consecutive rows — the bulk/offload path
-        (``unload_mask`` may stage only the scattered column-0 decode
-        write). Returns (logits [B, V'] taken at each slot's LAST valid
-        column — the sampling position for both phases — and the new
-        cache). ``all_logits=True`` instead returns logits [B, C, V'] at
-        EVERY column: column ``j`` is the target distribution for
-        position ``start + j + 1``, which is exactly what the
-        speculative k-token verify consumes (a verify IS a chunked step
-        that keeps all its sampling positions). The fused read stops each
-        slot's walk at ``PG.page_bound(start + n_valid)``: the columns at
-        or past ``n_valid`` see no more than that, and no token is taken
-        from their outputs.
+        ``write_mask`` False suppresses every KV write of that slot (its
+        physical destination resolves to the drop sentinel, so a dead slot
+        can never touch the pool). Chunk KV writes are dense consecutive
+        rows — the bulk/offload path; ``unload_mask`` [B] (None = none)
+        routes only the scattered column-0 write: True = stage it into the
+        ring overlay (unload path), False = direct scatter to the slot's
+        physical row (offload path).
+
+        Returns (logits [B, V'] taken at each slot's LAST valid column —
+        the sampling position for both phases — and the new cache).
+        ``all_logits=True`` instead returns logits [B, C, V'] at EVERY
+        column: column ``j`` is the target distribution for position
+        ``start + j + 1``, which is exactly what the speculative k-token
+        verify consumes (a verify IS a chunked step that keeps all its
+        sampling positions).
+
+        ``attention`` picks the read implementation (negotiate it through
+        ``core.paths.resolve_attention``): ``"reference"`` gathers the
+        per-slot view from the pool and concatenates the ring in jnp;
+        ``"fused"`` hands the whole stacked pool and ring planes, the
+        layer index and the scalar-prefetch block table to
+        ``flash_decode_paged``, which walks that layer's page table up to
+        each slot's ``PG.page_bound(start + n_valid)`` (a slot with no
+        valid column walks page 0 alone, so its discarded output is the
+        reference's over a one-page table) and merges both sources inside
+        one softmax — no gathered view ever materializes. The columns at
+        or past ``n_valid`` see no more than that bound, and no token is
+        taken from their outputs. Pool and ring ride the layer scan's
+        carry and each layer writes its rows in place (``_scan_paged``).
+        The two implementations share one op order and agree to fp32 ulp
+        precision with identical greedy tokens (the reference is the
+        kernel's oracle; DESIGN.md §7 has the parity contract). ``plan``
+        threads per-segment hoisted page-table products
+        (``PG.step_plan``); when None it is derived here. ``mesh`` is the
+        serving mesh of a head-sharded pool (the fused kernel then runs
+        per shard).
 
         Bit-parity: chunk rows land in the pool before the per-slot view is
         gathered, and every projection/reduction matches the whole-prompt
-        ``prefill`` + ``decode_step_paged`` pair, so a prompt prefilled in
+        ``prefill`` followed by C = 1 steps, so a prompt prefilled in
         chunks decodes the same token stream as one prefilled whole.
+        Linear addressing only: SWA ring addressing and the VLM family
+        stay on the dense-lane path (see DESIGN.md §Arch-applicability).
         """
         cfg = self.cfg
         if self.is_vlm or cfg.sliding_window:
@@ -636,8 +543,10 @@ class DecoderLM:
                                   cache["pages_k"].shape[2],
                                   plan.blocks.shape[1])
         with jax.named_scope("kv_write"):
-            dest = PG.logical_to_physical_many(
-                cache, jnp.where(direct, positions, -1))
+            rows = jnp.where(direct, positions, -1)
+            # C = 1: one row per slot, mapped as [B] (a [B, 1] gather
+            # compiles to more ops)
+            dest = PG.logical_to_physical(cache, rows[:, 0] if c == 1 else rows)
 
         def self_body(h, planes, p, l):
             pk, pv, rk, rv = planes
@@ -645,8 +554,8 @@ class DecoderLM:
                 hn = L.apply_norm(cfg, p["ln1"], h)
                 k_new, v_new = L.project_kv(cfg, p["attn"], hn, positions)
             with jax.named_scope("kv_write"):
-                pk = PG.scatter_chunk(pk, l, dest, k_new)
-                pv = PG.scatter_chunk(pv, l, dest, v_new)
+                pk = PG.scatter_token(pk, l, dest, k_new)
+                pv = PG.scatter_token(pv, l, dest, v_new)
                 if ring:
                     rk = PG.stage_tile(rk, l, k_new[:, 0], cur)
                     rv = PG.stage_tile(rv, l, v_new[:, 0], cur)
@@ -679,9 +588,10 @@ class DecoderLM:
                 return L.lm_logits(cfg, params["embed"], x), new_cache
             # logits at each slot's last valid column: the final prompt
             # token (prefill, phase-flip sampling) or the decode token
-            # (column 0)
-            sel = jnp.clip(n_valid - 1, 0)[:, None, None]
-            x = jnp.take_along_axis(x, sel, axis=1)
+            # (column 0, the only one at C = 1)
+            if c > 1:
+                sel = jnp.clip(n_valid - 1, 0)[:, None, None]
+                x = jnp.take_along_axis(x, sel, axis=1)
             x = L.apply_norm(cfg, params["ln_f"], x)
             logits = L.lm_logits(cfg, params["embed"], x)[:, 0]
         return logits, new_cache

@@ -92,6 +92,12 @@ PHASE_DECODE = 1
 N_STATS = 6
 
 
+def _no_aux(extra):
+    """A segment kind's ``extra`` operand as scan constants, with no carry
+    of its own (decode and mixed)."""
+    return extra, None, None
+
+
 def paged_capable(model) -> bool:
     """Can this model serve from the paged pool? Linear-addressed dense
     ``DecoderLM`` only: SWA's ring addressing IS its window bound, and the
@@ -407,15 +413,10 @@ class BatchedServeEngine:
             "spec_proposed": 0, "spec_accepted": 0,
             "spec_committed": 0, "spec_rounds": 0,
         }
-        # compiled segment variants keyed by STATIC sampler mode
-        # (greedy/sampled/filtered — repro.models.sampling); _segment_fn /
-        # _mixed_fn hold the last-used variant
-        self._segment_fns: Dict[str, Callable] = {}
-        self._mixed_fns: Dict[str, Callable] = {}
-        self._spec_fns: Dict[str, Callable] = {}
-        self._segment_fn: Optional[Callable] = None
-        self._mixed_fn: Optional[Callable] = None
-        self._spec_fn: Optional[Callable] = None
+        # compiled segment programs keyed by (kind, STATIC sampler mode):
+        # kind decode / mixed / spec, mode greedy / sampled / filtered
+        # (repro.models.sampling)
+        self._segments: Dict[tuple, Callable] = {}
         self._prefill_fns: Dict[Any, Callable] = {}
         self._draft_prefill_fns: Dict[int, Callable] = {}
         # mesh-sharded serving (DESIGN.md §9): place params + the paged
@@ -529,11 +530,71 @@ class BatchedServeEngine:
     # ------------------------------------------------------------------
     # segments: the jitted inner loops
     # ------------------------------------------------------------------
-    def _build_segment(self, mode: str) -> Callable:
-        """Pure-decode segment: every live slot samples one token per step
-        (the steady state; also the only segment the non-chunked engine
-        runs). ``mode`` statically specializes the sampler to the live
-        slots' params (a pure-greedy batch pays exactly the argmax step)."""
+    def _build_segment(self, kind: str, mode: str) -> Callable:
+        """One segment program: ``segment_len`` steps of ``kind``'s scan
+        step (``decode``, ``mixed`` or ``spec``) inside the frame all three
+        share. ``mode`` statically specializes the sampler to the live
+        slots' params (a pure-greedy batch pays exactly the argmax step).
+
+        The program is ``(params, cache, slots, monitor, enabled, extra) ->
+        (cache, slots, monitor, stats, per-slot writes, emits, emission
+        mask, (state, counters))``. ``extra`` is the kind's own operand
+        (None for decode, the prompt buffer for mixed, (draft params, draft
+        cache) for spec); ``state`` and ``counters`` its own carry (the
+        draft cache and the spec stats for spec, None otherwise). Its
+        lowered module is ``jit_segment_<kind>``. ``_<kind>_step(mode)``
+        returns the kind's scan step ``step(params, enabled, plan, consts,
+        carry)`` over the carry ``(cache, state, slots, monitor, stats,
+        counters, per-slot writes)`` and ``init(extra) -> (consts, state,
+        counters)``."""
+        step, init = {"decode": self._decode_step, "mixed": self._mixed_step,
+                      "spec": self._spec_step}[kind](mode)
+        cfg = self.cfg
+        paged = self.layout == "paged"
+        # a spec segment leaves its staged writes for the next drain
+        drain = paged and self.uses_ring and kind != "spec"
+        dk = self._drain_kernel
+        mesh, cache_shardings = self.mesh, self._cache_shardings
+
+        def segment(params, cache, st, mon, enabled, extra):
+            consts, state, counters = init(extra)
+            # page-table products are segment-invariant (allocation is
+            # host-side, between segments): derive them ONCE here, outside
+            # the scan, instead of once per step per layer
+            plan = PG.step_plan(cache) if paged else None
+            stats0 = jnp.zeros((N_STATS,), jnp.int32)
+            sw0 = jnp.zeros((cfg.n_slots, 3), jnp.int32)
+            # the kind's own carry sits second and sixth: in another order
+            # the compiler places the spec step's async copies differently
+            carry = (cache, state, st, mon, stats0, counters, sw0)
+            (cache, state, st, mon, stats, counters, swrites), (emits, acts) = (
+                lax.scan(lambda c, _: step(params, enabled, plan, consts, c),
+                         carry, None, length=cfg.segment_len))
+            if drain:
+                # segment boundary: the host may retire slots and free
+                # their blocks next — the ring must not hold entries that
+                # would later drain into reallocated blocks
+                cache = PG.drain_ring(cache, use_kernel=dk,
+                                      shardings=cache_shardings)
+            if mesh is not None:
+                # pin the pool/ring back to their head shards and the
+                # telemetry carries to replicated — the segment boundary
+                # is the readback point (DESIGN.md §9)
+                cache = PG.constrain(cache, cache_shardings)
+                st, mon, stats, swrites, emits, acts, counters = (
+                    replicate_for_readback(
+                        (st, mon, stats, swrites, emits, acts, counters),
+                        mesh))
+            return (cache, st, mon, stats, swrites, emits, acts,
+                    (state, counters))
+
+        segment.__name__ = f"segment_{kind}"
+        return jax.jit(segment)
+
+    def _decode_step(self, mode: str):
+        """Pure-decode scan step: every live slot samples one token per
+        step (the steady state; also the only segment the non-chunked
+        engine runs)."""
         model, cfg = self.model, self.cfg
         paged = self.layout == "paged"
         ring = paged and self.uses_ring
@@ -543,10 +604,13 @@ class BatchedServeEngine:
         dk = self._drain_kernel
         mesh, cache_shardings = self.mesh, self._cache_shardings
 
-        def step(params, enabled, plan, carry, _):
-            cache, st, mon, stats, swrites = carry
+        def step(params, enabled, plan, _, carry):
+            cache, state, st, mon, stats, counters, swrites = carry
             active = ~st.done & enabled
-            walk = (read_walk(st.pos + 1, active, ps, mp)
+            # one valid column where active; the walk counts the bound the
+            # paged step computes from the same operands (one expression)
+            n_valid = active.astype(jnp.int32)
+            walk = (read_walk(st.pos + n_valid, n_valid > 0, ps, mp)
                     if paged else jnp.zeros((2,), jnp.int32))
             if paged:
                 dest = PG.logical_to_physical(
@@ -564,14 +628,12 @@ class BatchedServeEngine:
                     cache, use_kernel=dk,
                     incoming_pos=jnp.where(active, st.pos, -1),
                     shardings=cache_shardings)
-                logits, cache = model.decode_step_paged(
-                    params, cache, st.token, st.pos, active,
-                    unload_mask=unload, attention=attn, plan=plan,
+            if paged:
+                # the paged step at C = 1
+                logits, cache = model.decode_chunk_paged(
+                    params, cache, st.token[:, None], st.pos, n_valid,
+                    active, unload_mask=unload, attention=attn, plan=plan,
                     mesh=mesh)
-            elif paged:
-                logits, cache = model.decode_step_paged(
-                    params, cache, st.token, st.pos, active,
-                    attention=attn, plan=plan, mesh=mesh)
             else:
                 # retired slots never write: redirect their scatter rows
                 # to the out-of-range drop sentinel (SSM recurrent state
@@ -610,41 +672,13 @@ class BatchedServeEngine:
                 jnp.zeros_like(st.pos),
             ], axis=1)
             emit = jnp.where(active, nxt, -1)
-            return (cache, st, mon, stats, swrites), (emit, active)
+            return ((cache, state, st, mon, stats, counters, swrites),
+                    (emit, active))
 
-        def segment_decode(params, cache, st, mon, enabled):
-            # page-table products are segment-invariant (allocation is
-            # host-side, between segments): derive them ONCE here, outside
-            # the scan, instead of once per step per layer
-            plan = PG.step_plan(cache) if paged else None
-            stats0 = jnp.zeros((N_STATS,), jnp.int32)
-            sw0 = jnp.zeros((cfg.n_slots, 3), jnp.int32)
-            (cache, st, mon, stats, swrites), (emits, acts) = lax.scan(
-                lambda c, x: step(params, enabled, plan, c, x),
-                (cache, st, mon, stats0, sw0),
-                None,
-                length=cfg.segment_len,
-            )
-            if ring:
-                # segment boundary: the host may retire slots and free
-                # their blocks next — the ring must not hold entries that
-                # would later drain into reallocated blocks
-                cache = PG.drain_ring(cache, use_kernel=dk,
-                                      shardings=cache_shardings)
-            if mesh is not None:
-                # pin the pool/ring back to their head shards and the
-                # telemetry carries to replicated — the segment boundary
-                # is the readback point (DESIGN.md §9)
-                cache = PG.constrain(cache, cache_shardings)
-                st, mon, stats, swrites, emits, acts = (
-                    replicate_for_readback(
-                        (st, mon, stats, swrites, emits, acts), mesh))
-            return cache, st, mon, stats, swrites, emits, acts
+        return step, _no_aux
 
-        return jax.jit(segment_decode)
-
-    def _build_mixed_segment(self, mode: str) -> Callable:
-        """Mixed-phase segment (chunked, paged layout): each step every
+    def _mixed_step(self, mode: str):
+        """Mixed-phase scan step (chunked, paged layout): each step every
         live slot processes a [chunk_size]-token slab — the next prompt
         chunk (PREFILL) or its one decode token (DECODE, column 0) — and a
         slot flips PREFILL→DECODE in-scan when its cursor crosses plen,
@@ -661,8 +695,8 @@ class BatchedServeEngine:
         dk = self._drain_kernel
         mesh, cache_shardings = self.mesh, self._cache_shardings
 
-        def step(params, prompts, enabled, plan, carry, _):
-            cache, st, mon, stats, swrites = carry
+        def step(params, enabled, plan, prompts, carry):
+            cache, state, st, mon, stats, counters, swrites = carry
             active = ~st.done & enabled
             is_pf = active & (st.phase == PHASE_PREFILL)
             # token slab: prefill slots read the device prompt buffer at
@@ -679,7 +713,7 @@ class BatchedServeEngine:
             rows = st.pos[:, None] + offs
             # decision plane: ONE flattened phase-tagged batch per step —
             # bulk prefill rows are pinned offload, decode rows adaptive
-            dest_all = PG.logical_to_physical_many(
+            dest_all = PG.logical_to_physical(
                 cache, jnp.where(qvalid, rows, -1))
             region = jnp.minimum(dest_all // ps, nb - 1)
             phase_tag = jnp.where(
@@ -701,14 +735,9 @@ class BatchedServeEngine:
                     cache, use_kernel=dk,
                     incoming_pos=jnp.where(active & ~is_pf, st.pos, -1),
                     shardings=cache_shardings)
-                logits, cache = model.decode_chunk_paged(
-                    params, cache, tokens, st.pos, n_valid, active,
-                    unload_mask=unload, attention=attn, plan=plan,
-                    mesh=mesh)
-            else:
-                logits, cache = model.decode_chunk_paged(
-                    params, cache, tokens, st.pos, n_valid, active,
-                    attention=attn, plan=plan, mesh=mesh)
+            logits, cache = model.decode_chunk_paged(
+                params, cache, tokens, st.pos, n_valid, active,
+                unload_mask=unload, attention=attn, plan=plan, mesh=mesh)
             finishing = is_pf & (st.pos + n_valid >= st.plen)
             emitting = (active & ~is_pf) | finishing
             # the first token after the prompt is the prefill ARGMAX in
@@ -741,35 +770,15 @@ class BatchedServeEngine:
                 jnp.where(is_pf, n_valid, 0),
             ], axis=1)
             emit = jnp.where(emitting, nxt, -1)
-            return (cache, st, mon, stats, swrites), (emit, emitting)
+            return ((cache, state, st, mon, stats, counters, swrites),
+                    (emit, emitting))
 
-        def segment_mixed(params, cache, st, mon, prompts, enabled):
-            # per-segment hoist of page-table products (see _build_segment)
-            plan = PG.step_plan(cache)
-            stats0 = jnp.zeros((N_STATS,), jnp.int32)
-            sw0 = jnp.zeros((cfg.n_slots, 3), jnp.int32)
-            (cache, st, mon, stats, swrites), (emits, ems) = lax.scan(
-                lambda cry, x: step(params, prompts, enabled, plan, cry, x),
-                (cache, st, mon, stats0, sw0),
-                None,
-                length=cfg.segment_len,
-            )
-            if ring:
-                cache = PG.drain_ring(cache, use_kernel=dk,
-                                      shardings=cache_shardings)
-            if mesh is not None:
-                cache = PG.constrain(cache, cache_shardings)
-                st, mon, stats, swrites, emits, ems = (
-                    replicate_for_readback(
-                        (st, mon, stats, swrites, emits, ems), mesh))
-            return cache, st, mon, stats, swrites, emits, ems
+        return step, _no_aux
 
-        return jax.jit(segment_mixed)
-
-    def _build_spec_segment(self, mode: str) -> Callable:
-        """Speculative segment (the third path, DESIGN.md §11): per scan
-        step every live slot's DRAFT proposes k tokens off its own dense
-        lanes cache, then the target verifies all k in ONE
+    def _spec_step(self, mode: str):
+        """Speculative scan step (the third path, DESIGN.md §11): every
+        live slot's DRAFT proposes k tokens off its own dense lanes cache,
+        then the target verifies all k in ONE
         ``decode_chunk_paged`` call — a k-token verify IS a chunked step
         with logits at every column. ``spec_verify`` accepts the longest
         draft prefix the target agrees with (greedy: exact argmax match,
@@ -791,9 +800,9 @@ class BatchedServeEngine:
         ps, nb, mp = cfg.page_size, self.n_blocks, self.max_pages
         decision = self.decision
         attn = self.attention
-        mesh, cache_shardings = self.mesh, self._cache_shardings
+        mesh = self.mesh
 
-        def step(params, dpar, enabled, plan, carry, _):
+        def step(params, enabled, plan, dpar, carry):
             cache, dcache, st, mon, stats, sstats, swrites = carry
             active = ~st.done & enabled
 
@@ -834,7 +843,7 @@ class BatchedServeEngine:
             offs = jnp.arange(k + 1, dtype=jnp.int32)[None, :]
             qvalid = offs < n_valid[:, None]
             rows = st.pos[:, None] + offs
-            dest = PG.logical_to_physical_many(
+            dest = PG.logical_to_physical(
                 cache, jnp.where(qvalid, rows, -1))
             region = jnp.minimum(dest // ps, nb - 1)
             # bulk-tagged contiguous verify rows: the decision plane pins
@@ -901,28 +910,11 @@ class BatchedServeEngine:
             return ((cache, dc, st, mon, stats, sstats, swrites),
                     (emit, emit_mask))
 
-        def segment_spec(params, dpar, cache, dcache, st, mon, enabled):
-            plan = PG.step_plan(cache)
-            stats0 = jnp.zeros((N_STATS,), jnp.int32)
-            sst0 = jnp.zeros((4,), jnp.int32)
-            sw0 = jnp.zeros((cfg.n_slots, 3), jnp.int32)
-            ((cache, dcache, st, mon, stats, sstats, swrites),
-             (emits, ems)) = lax.scan(
-                lambda c, x: step(params, dpar, enabled, plan, c, x),
-                (cache, dcache, st, mon, stats0, sst0, sw0),
-                None,
-                length=cfg.segment_len,
-            )
-            if mesh is not None:
-                cache = PG.constrain(cache, cache_shardings)
-                st, mon, stats, sstats, swrites, emits, ems = (
-                    replicate_for_readback(
-                        (st, mon, stats, sstats, swrites, emits, ems),
-                        mesh))
-            return (cache, dcache, st, mon, stats, sstats, swrites,
-                    emits, ems)
+        def init(extra):
+            dpar, dcache = extra
+            return dpar, dcache, jnp.zeros((4,), jnp.int32)
 
-        return jax.jit(segment_spec)
+        return step, init
 
     # ------------------------------------------------------------------
     # admission / retirement / allocation (host, between segments)
@@ -1731,38 +1723,24 @@ class BatchedServeEngine:
             [self.req_params[self._slot_req[s]]
              for s in range(self.cfg.n_slots) if self._occupied[s]])
         if self._mixed_phase_pending():
-            self._mixed_fn = self._mixed_fns.get(mode)
-            if self._mixed_fn is None:
-                self._mixed_fn = self._build_mixed_segment(mode)
-                self._mixed_fns[mode] = self._mixed_fn
-            (self.cache, self.slots, self.mon_state, stats, swrites,
-             emits, acts) = (
-                self._mixed_fn(self.params, self.cache, self.slots,
-                               self.mon_state, self.prompts, enabled_j))
-            return "mixed", (stats, None, swrites, emits, acts)
-        if self._spec_enabled:
+            kind, extra = "mixed", self.prompts
+        elif self._spec_enabled:
             # the third path: draft-then-verify rounds need every live
             # slot in DECODE phase and the draft lanes in sync
             self._sync_draft()
-            self._spec_fn = self._spec_fns.get(mode)
-            if self._spec_fn is None:
-                self._spec_fn = self._build_spec_segment(mode)
-                self._spec_fns[mode] = self._spec_fn
-            (self.cache, self.draft_cache, self.slots, self.mon_state,
-             stats, sstats, swrites, emits, acts) = (
-                self._spec_fn(self.params, self.draft_params, self.cache,
-                              self.draft_cache, self.slots,
-                              self.mon_state, enabled_j))
-            return "spec", (stats, sstats, swrites, emits, acts)
-        self._segment_fn = self._segment_fns.get(mode)
-        if self._segment_fn is None:
-            self._segment_fn = self._build_segment(mode)
-            self._segment_fns[mode] = self._segment_fn
-        (self.cache, self.slots, self.mon_state, stats, swrites,
-         emits, acts) = (
-            self._segment_fn(self.params, self.cache, self.slots,
-                             self.mon_state, enabled_j))
-        return "decode", (stats, None, swrites, emits, acts)
+            kind, extra = "spec", (self.draft_params, self.draft_cache)
+        else:
+            kind, extra = "decode", None
+        # built on first use, kept across reset()
+        if (kind, mode) not in self._segments:
+            self._segments[kind, mode] = self._build_segment(kind, mode)
+        (self.cache, self.slots, self.mon_state, stats, swrites, emits, acts,
+         (state, counters)) = self._segments[kind, mode](
+            self.params, self.cache, self.slots, self.mon_state, enabled_j,
+            extra)
+        if kind == "spec":
+            self.draft_cache = state
+        return kind, (stats, counters, swrites, emits, acts)
 
     def _readback(self, kind: str, stats, sstats, swrites, emits,
                   acts) -> np.ndarray:

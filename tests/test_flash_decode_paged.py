@@ -316,12 +316,13 @@ def _paged_cache_with_ring(model, rng, n_slots=4, nb=16, ps=4, mp=8, rs=4):
 
 @pytest.mark.parametrize("variant", ["step", "chunk"])
 def test_model_fused_matches_reference(variant):
-    """decode_step_paged / decode_chunk_paged under attention='fused' vs
-    'reference': identical argmax tokens, allclose logits, allclose cache
-    (cross-graph XLA fusion of the k/v projections carries ~1 ulp — see
-    module docstring). The dead slot (no write, bound 0) walks page 0
-    alone (``PG.page_bound``), so its row is held to the reference over
-    the view that bound defines: the same cache with one page per table."""
+    """decode_chunk_paged at C = 1 (the decode step) and at C = 4 under
+    attention='fused' vs 'reference': identical argmax tokens, allclose
+    logits, allclose cache (cross-graph XLA fusion of the k/v projections
+    carries ~1 ulp — see module docstring). The dead slot (no write, bound
+    0) walks page 0 alone (``PG.page_bound``), so its row is held to the
+    reference over the view that bound defines: the same cache with one
+    page per table."""
     cfg = get_config("stablelm-1.6b").reduced()
     model = build_model(cfg)
     params = model.init(jax.random.key(0), MAX_SEQ)
@@ -338,9 +339,9 @@ def test_model_fused_matches_reference(variant):
         if variant == "step":
             tok = jnp.asarray([5, 9, 3, 0], jnp.int32)
             pos = jnp.asarray([10, 6, 9, 0], jnp.int32)
-            outs[attention] = model.decode_step_paged(
-                params, dict(kv), tok, pos, wm, unload_mask=um,
-                attention=impl)
+            outs[attention] = model.decode_chunk_paged(
+                params, dict(kv), tok[:, None], pos, wm.astype(jnp.int32),
+                wm, unload_mask=um, attention=impl)
         else:
             start = jnp.asarray([10, 6, 9, 0], jnp.int32)
             nv = jnp.asarray([4, 1, 2, 0], jnp.int32)

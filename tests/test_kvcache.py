@@ -17,9 +17,9 @@ from repro.kvcache import (
     pool_rows,
     scatter_token,
     strip_ring,
-    view_mask,
     view_rows,
 )
+from repro.kvcache.paged import view_chunk_mask
 from repro.models import build_model
 
 
@@ -121,8 +121,8 @@ def test_paged_pool_insert_gather_roundtrip():
         dest = logical_to_physical(cache, jnp.full((3,), t, jnp.int32))
         cache["pages_k"] = scatter_token(cache["pages_k"], 0, dest, k)
         ref[:, t] = np.asarray(k)
-    vm = view_mask(cache, jnp.full((3,), 9, jnp.int32))
-    assert vm.tolist()[0] == [True] * 10 + [False] * 2 + [False] * 4
+    vm = view_chunk_mask(cache, jnp.full((3,), 9, jnp.int32)[:, None])
+    assert vm[:, 0].tolist()[0] == [True] * 10 + [False] * 2 + [False] * 4
     kk = gather_view(cache["pages_k"][0], view_rows(cache))
     for b in range(3):
         np.testing.assert_allclose(np.asarray(kk[b, :10]), ref[b, :10],

@@ -1,10 +1,11 @@
 """The paged pool stays in place through the layer scan.
 
-``decode_step_paged`` and ``decode_chunk_paged`` carry the stacked pool
-(and the staging ring) in the layer scan's carry and write each layer's
-rows at ``[layer, row]``. Were the pool a scan ``xs``/``ys`` instead, every
-layer of every step would slice its plane out of the stack and restack it:
-a whole-pool copy per step that the compiled program pays on the chip.
+``decode_chunk_paged``, at C = 1 (the decode step) and at C > 1, carries
+the stacked pool (and the staging ring) in the layer scan's carry and
+writes each layer's rows at ``[layer, row]``. Were the pool a scan
+``xs``/``ys`` instead, every layer of every step would slice its plane out
+of the stack and restack it: a whole-pool copy per step that the compiled
+program pays on the chip.
 These tests read the traced program, so they guard the layout on the CPU.
 """
 import jax
@@ -47,8 +48,10 @@ def _trace(variant, ring, attention):
     idx = jnp.zeros((N_SLOTS,), jnp.int32)
     if variant == "step":
         def fn(params, cache):
-            return model.decode_step_paged(params, cache, idx, idx, live,
-                                           attention=attention)
+            return model.decode_chunk_paged(params, cache, idx[:, None], idx,
+                                            live.astype(jnp.int32), live,
+                                            unload_mask=live,
+                                            attention=attention)
     else:
         toks = jnp.zeros((N_SLOTS, CHUNK), jnp.int32)
 

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.core.paths import SpecConfig
 from repro.data import synthetic_requests
 from repro.models import build_model
 from repro.serve import BatchConfig, BatchedServeEngine, ServeConfig, ServeEngine
@@ -113,12 +114,19 @@ def test_max_new_one_needs_no_decode_step():
     assert all(out[r].shape == (1,) for r in out)
     assert eng.stats["direct_writes"] == 0  # prefill-only
 
-def test_segment_fn_compiles_once():
+@pytest.mark.parametrize("kind", ["decode", "mixed", "spec"])
+def test_segment_fn_compiles_once(kind):
+    """Each (kind, mode) segment program is built once and survives
+    ``reset()``."""
     cfg, model, params = _model()
-    eng = _engine(model, params, "adaptive", n_slots=2)
+    kw = {"decode": {}, "mixed": dict(chunked=True, chunk_size=4),
+          "spec": dict(spec=SpecConfig(enabled=True, k=2))}[kind]
+    eng = _engine(model, params, "adaptive", n_slots=2, **kw)
     eng.serve(_queue(cfg))
-    fn = eng._segment_fn
-    assert fn is not None
+    built = dict(eng._segments)
+    assert kind in {k for k, _ in built}
     eng.reset()
     eng.serve(_queue(cfg))
-    assert eng._segment_fn is fn  # reset keeps the compiled loop
+    # reset keeps the compiled loops, and serving again builds none
+    assert eng._segments.keys() == built.keys()
+    assert all(eng._segments[key] is fn for key, fn in built.items())

@@ -19,8 +19,6 @@ from repro.serve import trace as T
 PLENS = (10, 5, 7, 12)
 SPANS = {"engine.retire", "engine.admit", "engine.topup", "engine.dispatch",
          "engine.readback", "engine.emit"}
-BUILDERS = {"mixed": "_build_mixed_segment", "decode": "_build_segment",
-            "spec": "_build_spec_segment"}
 
 
 class _Spans:
@@ -58,16 +56,16 @@ def _serve(spec: bool, monkeypatch):
         model, params)
     sch = eng.scheduler
     ran = []
-    for kind, name in BUILDERS.items():
-        def spy(mode, build=getattr(sch, name), kind=kind):
-            fn = build(mode)
 
-            def call(*args):
-                ran.append(kind)
-                return fn(*args)
-            call.lower = fn.lower
-            return call
-        monkeypatch.setattr(sch, name, spy)
+    def spy(kind, mode, build=sch._build_segment):
+        fn = build(kind, mode)
+
+        def call(*args):
+            ran.append(kind)
+            return fn(*args)
+        call.lower = fn.lower
+        return call
+    monkeypatch.setattr(sch, "_build_segment", spy)
     spans = _Spans()
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", spans)
     q = RequestQueue()
@@ -111,15 +109,12 @@ def test_segment_programs_are_named_and_scoped(served):
     spec, eng, _, _, _ = served
     sch = eng.scheduler
     on = jax.numpy.ones((sch.cfg.n_slots,), bool)
-    lowered = {"mixed": next(iter(sch._mixed_fns.values())).lower(
-        sch.params, sch.cache, sch.slots, sch.mon_state, sch.prompts, on)}
-    if spec:
-        lowered["spec"] = next(iter(sch._spec_fns.values())).lower(
-            sch.params, sch.draft_params, sch.cache, sch.draft_cache,
-            sch.slots, sch.mon_state, on)
-    else:
-        lowered["decode"] = next(iter(sch._segment_fns.values())).lower(
-            sch.params, sch.cache, sch.slots, sch.mon_state, on)
+    extra = {"mixed": sch.prompts, "decode": None,
+             "spec": (sch.draft_params, sch.draft_cache)}
+    lowered = {kind: fn.lower(sch.params, sch.cache, sch.slots,
+                              sch.mon_state, on, extra[kind])
+               for (kind, _), fn in sch._segments.items()}
+    assert set(lowered) == {"mixed", "spec" if spec else "decode"}
     for kind, low in lowered.items():
         assert low.as_text().startswith(f"module @jit_segment_{kind} ")
         text = low.as_text(debug_info=True)
